@@ -201,9 +201,6 @@ _KNOB_LIST = [
     _k("HYDRAGNN_TELEMETRY_SYNC", "Telemetry.sync_steps", "0",
        "hydragnn_tpu/telemetry/logger.py",
        "block per step for true device step times"),
-    _k("HYDRAGNN_PEAK_FLOPS", "", "197e12 (v5e bf16)",
-       "hydragnn_tpu/telemetry/flops.py",
-       "MFU peak-flops basis override"),
     _k("HYDRAGNN_TRACE", "Telemetry.trace", "0",
        "hydragnn_tpu/telemetry/trace.py",
        "flight recorder: record request/train-phase spans (JSONL "

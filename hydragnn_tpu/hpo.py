@@ -228,6 +228,10 @@ def run_hpo_async(
 
     Node subsets are managed by a queue: a finishing trial returns its nodes
     so a queued trial can start — true async scheduling, not batched waves.
+    Every trial is its own process and opens the accelerator itself; a chip
+    belongs to one process at a time, so on a workstation with ONE chip use
+    ``n_concurrent=1`` (a second concurrent trial fails or hangs at backend
+    start).
     Each trial passes its sampled params as ``--hpo key=value`` args that the
     trial script applies to its config.
     """

@@ -558,7 +558,7 @@ class InteractionPPBlock(nn.Module):
             # first) was measured 12 ms/step SLOWER on the v5e sweep
             # config.  The rbf->triplet gather in spherical_basis keeps the
             # perm: its backward only runs under pos-grad (force training),
-            # where the dense path halves the cost (tools/profile_dimenet*.py).
+            # where the dense path halves the cost (tools/profile_dimenet2.py).
             msg = x_kj[idx_kj] * sbf_emb * triplet_mask[:, None]
             # build_triplets emits idx_ji in nondecreasing order (outer
             # loop over edge ids) — the dense-schedule sorted scatter

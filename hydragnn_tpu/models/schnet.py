@@ -142,6 +142,9 @@ class SCFConv(nn.Module):
             # + gather + multiply + segment-sum with no [E, F] HBM streams
             from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
 
+            # tallied only when taken: below the width floor the CFConv
+            # rides gather_mul (its own tally entry), by design
+            segment._count("scf", True)
             cm = cut * g.edge_mask
             # em: schedule-skip validity (kernel never visits masked-edge
             # blocks — ~half the edge slots at flagship padding ratios)

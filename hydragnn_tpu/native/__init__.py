@@ -1,28 +1,57 @@
 """ctypes bindings for the native runtime (native/hydrastore.cpp).
 
-The shared library is compiled on demand with g++ (cached next to the
-source, rebuilt when the source is newer).
+The shared library is compiled on demand with g++ and cached next to the
+source.  The cache is keyed on a HASH of the source stored beside the
+library, not on mtimes: a copied tree (the chip tool's, a CI checkout)
+does not preserve them, and must load what the tracked source builds and
+nothing older.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
 
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
+from hydragnn_tpu.utils.runtime import checkout_root
+
+_REPO_ROOT = checkout_root()
 _SRC = os.path.join(_REPO_ROOT, "native", "hydrastore.cpp")
 _LIB = os.path.join(_REPO_ROOT, "native", "libhydrastore.so")
+_STAMP = _LIB + ".srchash"
 
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stale() -> bool:
+    """True unless the library exists AND its stamp names this source."""
+    if not os.path.exists(_LIB):
+        return True
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() != _src_hash()
+    except FileNotFoundError:
+        return True
+
+
 def _build() -> None:
+    # build aside and rename into place: concurrent processes (test
+    # workers, fleet children) never load a half-written library
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
     cmd = ["g++", "-O3", "-fPIC", "-shared", "-pthread", "-std=c++17",
-           _SRC, "-o", _LIB]
+           _SRC, "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True, text=True)
+    os.replace(tmp, _LIB)
+    with open(tmp, "w") as f:
+        f.write(_src_hash() + "\n")
+    os.replace(tmp, _STAMP)
 
 
 def load_library() -> ctypes.CDLL:
@@ -30,8 +59,7 @@ def load_library() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    if (not os.path.exists(_LIB)
-            or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
+    if _stale():
         _build()
     lib = ctypes.CDLL(_LIB)
 

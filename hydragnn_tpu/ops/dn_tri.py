@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from hydragnn_tpu.ops import fused_block as _fb
 from hydragnn_tpu.ops.aggregate import _round_up
-from hydragnn_tpu.ops.fused_block import _dense_schedule
+from hydragnn_tpu.ops.fused_block import _dense_schedule, _dot
 from hydragnn_tpu.ops.fused_block import _window_maps as _win_maps
 
 _EB = 128      # edge block (output rows / window unit)
@@ -82,15 +82,7 @@ def _gather_w(idx_ref, win_refs, base_block, bn, dt):
     onehot = (loc == jax.lax.broadcasted_iota(
         jnp.int32, (be, w * bn), 1)).astype(dt)
     cat = jnp.concatenate([r[:] for r in win_refs], axis=0)
-    return jax.lax.dot_general(
-        onehot, cat.astype(dt), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32), onehot
-
-
-def _dot(a, b, dims, dt):
-    return jax.lax.dot_general(
-        a.astype(dt), b.astype(dt), (dims, ((), ())),
-        preferred_element_type=jnp.float32)
+    return _dot(onehot, cat, ((1,), (0,)), dt), onehot
 
 
 def _fwd_kernel(si_ref, se_ref, av_ref, fi_ref,

@@ -154,9 +154,19 @@ def _dot(a, b, dims, dt):
     it makes the operand dtype explicit and lets the constant weight
     blocks and one-hots live in bf16 VMEM (per-step-produced f32
     operands still pay one downcast; accumulation and every elementwise
-    stays f32)."""
+    stays f32).
+
+    bf16 operands pin the contraction to DEFAULT precision: a bf16
+    product is exact in the f32 accumulator, so a higher contract
+    precision has nothing to add — and Mosaic (libtpu 0.0.34) REJECTS
+    bf16 operands under an fp32 contract precision ("Bad lhs type"),
+    which an ambient ``jax.default_matmul_precision("highest")`` would
+    otherwise request for every dot traced into the kernel.  f32
+    operands keep following the ambient precision."""
     return jax.lax.dot_general(
         a.astype(dt), b.astype(dt), (dims, ((), ())),
+        precision=(jax.lax.Precision.DEFAULT if dt == jnp.bfloat16
+                   else None),
         preferred_element_type=jnp.float32)
 
 

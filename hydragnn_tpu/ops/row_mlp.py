@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from hydragnn_tpu.ops.aggregate import _round_up
+from hydragnn_tpu.ops.fused_block import _dot
 
 _RB = 512   # rows per grid step
 _HP = 128   # padded feature lanes
@@ -48,12 +49,6 @@ def _silu(z):
 def _dsilu(z):
     s = jax.nn.sigmoid(z)
     return s * (1.0 + z * (1.0 - s))
-
-
-def _dot(a, b, dims, dt):
-    return jax.lax.dot_general(
-        a.astype(dt), b.astype(dt), (dims, ((), ())),
-        preferred_element_type=jnp.float32)
 
 
 def _chain_fwd(tri, x_ji, x_edge, w_ref, b_ref, n_before, n_after, dt):

@@ -26,57 +26,6 @@ def process_index() -> int:
     return jax.process_index()
 
 
-# Fallback switch for runtimes whose DEVICE backend cannot execute
-# multiprocess computations (jax 0.4.x CPU: process_allgather routes host
-# arrays through a multiprocess jit and raises INVALID_ARGUMENT).  Host
-# collectives then ride the distributed runtime's key-value store instead —
-# the control plane jax.distributed.initialize already stood up.  Sticky:
-# the backend capability cannot change mid-run.
-_kv_fallback = [False]
-_kv_seq = [0]
-
-
-def _kv_allgather(arr: np.ndarray) -> np.ndarray:
-    """process_allgather via the coordination-service KV store.  Every rank
-    publishes its (npy-serialized) array under a sequence-numbered key and
-    blocking-reads every other rank's — the sequence counter stays in step
-    because collectives are called in the same order on all ranks (the
-    usual collective contract)."""
-    import base64
-    import io
-
-    import jax
-    from jax._src import distributed
-
-    client = distributed.global_state.client
-    if client is None:
-        raise RuntimeError(
-            "host collective before jax.distributed.initialize")
-    seq = _kv_seq[0]
-    _kv_seq[0] += 1
-    buf = io.BytesIO()
-    np.save(buf, np.ascontiguousarray(arr), allow_pickle=False)
-    client.key_value_set(
-        f"hydragnn/ag/{seq}/{jax.process_index()}",
-        base64.b64encode(buf.getvalue()).decode("ascii"))
-    parts = []
-    for r in range(jax.process_count()):
-        val = client.blocking_key_value_get(
-            f"hydragnn/ag/{seq}/{r}", 120_000)
-        parts.append(np.load(io.BytesIO(base64.b64decode(val)),
-                             allow_pickle=False))
-    # reclaim the round's keys or a long run grows the coordinator's store
-    # without bound: a barrier guarantees every rank has read every key
-    # before any rank deletes its own (best-effort — leaked keys only cost
-    # coordinator memory, never correctness)
-    try:
-        client.wait_at_barrier(f"hydragnn/ag/{seq}/done", 120_000)
-        client.key_value_delete(f"hydragnn/ag/{seq}/{jax.process_index()}")
-    except Exception:  # graftlint: disable=ROB001 (cleanup barrier; leaked keys cost coordinator memory, never correctness)
-        pass
-    return np.stack(parts)
-
-
 def host_allreduce(arr: np.ndarray, op: str = "sum") -> np.ndarray:
     """All-reduce a small numpy array across hosts (min/max/sum)."""
     import jax
@@ -99,17 +48,9 @@ def host_allgather(arr: np.ndarray) -> np.ndarray:
 
     if jax.process_count() == 1:
         return np.asarray(arr)[None]
-    if not _kv_fallback[0]:
-        from jax.experimental import multihost_utils
+    from jax.experimental import multihost_utils
 
-        try:
-            return np.asarray(
-                multihost_utils.process_allgather(np.asarray(arr)))
-        except Exception as e:  # noqa: BLE001 — backend capability probe
-            if "Multiprocess computations" not in str(e):
-                raise
-            _kv_fallback[0] = True
-    return _kv_allgather(np.asarray(arr))
+    return np.asarray(multihost_utils.process_allgather(np.asarray(arr)))
 
 
 def host_broadcast_scalar(value: float, root: int = 0) -> float:

@@ -35,20 +35,10 @@ from hydragnn_tpu.train.optimizer import OptimizerSpec
 from hydragnn_tpu.train.trainer import TrainState, _force_head_indices, _loss_and_metrics
 
 def _shard_map(fn, mesh, in_specs, out_specs):
-    """Version-portable shard_map: newer jax exports it top-level with a
-    ``check_vma`` kwarg; 0.4.x has ``jax.experimental.shard_map`` with
-    ``check_rep``.  Replication checking stays off either way (the metric
+    """``jax.shard_map`` with varying-manual-axes checking off (the metric
     dicts are replicated by construction via psum/pmean)."""
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except TypeError:  # pre-check_vma signature
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 DATA_AXIS = "data"
@@ -679,14 +669,15 @@ def make_halo_train_step(
         # psum (not pmean) assembles the global gradient.  loss, per-head
         # losses and BN statistics came back GLOBAL already (the
         # halo-context psums ran inside the trace).  One wrinkle: taking
-        # jax.grad INSIDE shard_map (replication checking off) scales the
-        # per-shard cotangent of every in-trace psum by a semantics-
-        # dependent factor T — D on jax 0.4.x (transpose(psum) == psum of
-        # the replicated seed), 1 under replication-tracked transposes —
-        # uniformly across leaves.  Measure T with a one-op probe and
-        # divide it out (T is a power of two: the division is exact), so
-        # the psum below is the exact global gradient under either
-        # convention; the parity tests pin this leaf-for-leaf.
+        # jax.grad INSIDE shard_map with check_vma=False scales the
+        # per-shard cotangent of every in-trace psum by a factor T,
+        # uniformly across leaves: jax 0.9.0 transposes psum to a psum of
+        # the (replicated) seed, so T == D (measured: 4.0 on 4 devices);
+        # a replication-tracked transpose (check_vma=True) would give 1.
+        # Measure T with a one-op probe and divide it out, so the psum
+        # below is the exact global gradient under either convention; the
+        # parity tests pin this leaf-for-leaf (ROADMAP D4: drop the probe
+        # when the step builders move to check_vma=True).
         cal = jax.grad(lambda s: jax.lax.psum(s, axes[0]))(
             jnp.asarray(1.0, jnp.float32))
         with comm_region("comm.dp_psum", comm_probe):

@@ -39,8 +39,10 @@ def _(config: dict, logs_dir: str = "./logs/", seed: int = 0):
     # same launcher-env bootstrap as run_training (no-op when already
     # initialized or single-process)
     from hydragnn_tpu.parallel.mesh import setup_distributed
+    from hydragnn_tpu.utils.runtime import setup_compile_cache
 
     setup_distributed()
+    setup_compile_cache()
 
     from hydragnn_tpu.parallel.comm import num_processes, process_index
     import jax

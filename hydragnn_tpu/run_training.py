@@ -83,8 +83,10 @@ def _run_training_dict(config: dict, logs_dir: str, seed: int):
     # pass straight through (parity: reference setup_ddp is called inside
     # its run_training, hydragnn/run_training.py:77).
     from hydragnn_tpu.parallel.mesh import setup_distributed
+    from hydragnn_tpu.utils.runtime import setup_compile_cache
 
     setup_distributed()
+    setup_compile_cache()
 
     from hydragnn_tpu.parallel.comm import num_processes, process_index
 

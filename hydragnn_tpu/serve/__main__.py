@@ -16,7 +16,9 @@ event log viewable with tools/teleview.py.
 replicas behind the failover router instead of one server: each replica
 is a child ``python -m hydragnn_tpu.serve`` process on an ephemeral
 loopback port (``--fleet-inprocess`` keeps them as threads sharing one
-compile cache — the CPU/dev topology), crashed replicas restart with
+compile cache and one device — the ONLY fleet spelling that starts on a
+single chip, because every subprocess child would open the chip itself
+and a chip belongs to one process), crashed replicas restart with
 exponential backoff, and ``POST /reload`` becomes a rolling
 one-replica-at-a-time fleet update (docs/SERVING.md "Replica fleet").
 ``--reload-watch`` applies to single-server mode only.
@@ -51,8 +53,8 @@ def main(argv=None) -> int:
                          "0 = single server)")
     ap.add_argument("--fleet-inprocess", action="store_true",
                     help="fleet replicas as in-process threads sharing "
-                         "one compile cache (CPU/dev) instead of "
-                         "subprocesses")
+                         "one compile cache and device (required on a "
+                         "single chip) instead of subprocesses")
     args = ap.parse_args(argv)
 
     with open(args.config) as f:
@@ -79,7 +81,16 @@ def main(argv=None) -> int:
         serving.fleet_replicas = max(0, int(args.fleet))
     if args.fleet_inprocess:
         serving.fleet_inprocess = True
-    telemetry = MetricsLogger.from_env(run_name="serve")
+    # the subprocess fleet's router parent must stay off JAX (a parent that
+    # has touched JAX holds the chip its children need): its telemetry
+    # names no device and it sets up no compile cache — the children do
+    router_only = serving.fleet_replicas > 0 and not serving.fleet_inprocess
+    telemetry = MetricsLogger.from_env(run_name="serve",
+                                       names_device=not router_only)
+    if not router_only:
+        from hydragnn_tpu.utils.runtime import setup_compile_cache
+
+        setup_compile_cache()
 
     if serving.fleet_replicas > 0:
         from hydragnn_tpu.resilience import FleetChaos

@@ -15,7 +15,7 @@ tools/teleview.py for the JSONL summarizer.
 """
 
 from hydragnn_tpu.telemetry.flops import (  # noqa: F401
-    MXU_PEAK_FLOPS,
+    DEVICE_PEAKS,
     mfu_pct,
     peak_flops,
     step_cost_flops,

@@ -9,21 +9,45 @@ the bench harness and the training run disagree about what "100%" means).
 
 from __future__ import annotations
 
-import os
+from typing import Any, Dict, Optional
 
-# v5e bf16 systolic peak.  Also the right basis for JAX default-precision
-# f32: the default matmul precision runs f32 dots through the MXU as bf16
-# (measured 56.7 TF/s on an 8192^3 f32 matmul on this chip, above the
-# 49 TF/s "f32 peak", so 49e12 would be the wrong denominator — see
-# bench.py's module docstring for the full rationale).
-MXU_PEAK_FLOPS = 197e12
+# Published per-chip peaks, keyed by the ``device_kind`` JAX reports.  A
+# device that is not here has NO peak: telemetry then emits no
+# ``mfu_est_pct`` and bench.py refuses to compute one — a utilization
+# against somebody else's roofline is not a measurement.  The bf16 figure
+# is also the right basis for JAX default-precision f32 (the default
+# matmul precision runs f32 dots through the MXU as bf16 passes).
+DEVICE_PEAKS: Dict[str, Dict[str, Any]] = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+}
 
 
-def peak_flops() -> float:
-    """Peak flops basis for MFU.  HYDRAGNN_PEAK_FLOPS overrides the built-in
-    v5e constant for other parts (e.g. a CPU smoke run where the MXU peak is
-    a nominal reference, or a v4/v5p deployment)."""
-    return float(os.environ.get("HYDRAGNN_PEAK_FLOPS", "") or MXU_PEAK_FLOPS)
+def peak_flops(device_kind: str) -> Optional[float]:
+    """bf16 MXU peak of ``device_kind`` — the MFU basis — or None for a
+    device outside :data:`DEVICE_PEAKS`."""
+    row = DEVICE_PEAKS.get(device_kind)
+    return None if row is None else float(row["bf16_flops"])
+
+
+def require_peak_flops() -> float:
+    """:func:`peak_flops` of this process's device for the measurement
+    tools (bench.py, tools/mfu_attribution.py): a device outside the table
+    is an error there, not a default."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    peak = peak_flops(kind)
+    if peak is None:
+        raise RuntimeError(
+            f"no published peak for device_kind {kind!r} "
+            "(hydragnn_tpu/telemetry/flops.py:DEVICE_PEAKS) — MFU is "
+            "undefined on this device")
+    return peak
 
 
 def step_cost_flops(step_fn, *args) -> float:
@@ -42,16 +66,16 @@ def step_cost_flops(step_fn, *args) -> float:
     import jax
 
     compiled = jax.jit(step_fn).lower(*args).compile()
-    ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
-    return float(ca.get("flops", 0.0))
+    return float(compiled.cost_analysis().get("flops", 0.0))
 
 
-def mfu_pct(flops_per_step: float, step_s: float, peak: float = None) -> float:
-    """Model-flops-utilization percent for one step."""
+def mfu_pct(flops_per_step: float, step_s: float, peak: float) -> float:
+    """Model-flops-utilization percent for one step against ``peak``
+    (a :func:`peak_flops` value — callers decide what an unknown device
+    means; this function never invents a denominator)."""
     if step_s <= 0.0 or flops_per_step <= 0.0:
         return 0.0
-    return flops_per_step / step_s / (peak or peak_flops()) * 100.0
+    return flops_per_step / step_s / peak * 100.0
 
 
 def shape_struct_tree(tree):

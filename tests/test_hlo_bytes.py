@@ -85,7 +85,6 @@ def test_train_step_bytes_far_below_cost_model():
                     "fusion-boundary accounting is vacuous here")
     total, _ = entry_fusion_boundary_bytes(txt)
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     cm = float(ca.get("bytes accessed", 0.0))
     if cm > 0:
         assert total <= cm * 1.05, (total, cm)

@@ -11,29 +11,6 @@ import sys
 import pytest
 
 
-def _cpu_multiprocess_unsupported() -> bool:
-    """jax 0.4.x's CPU backend refuses ANY cross-process device
-    computation ("Multiprocess computations aren't implemented on the
-    CPU backend") — a pre-existing ENVIRONMENT limit, not a regression
-    (these 4 tests fail identically at seed; memory/TEST_MATRIX.md).
-    Guarded so the suite still runs on newer jax and on real multi-chip
-    backends, where the limitation does not exist."""
-    import jax
-
-    try:
-        major, minor = (int(x) for x in jax.__version__.split(".")[:2])
-    except ValueError:  # dev builds: assume new enough
-        return False
-    return (major, minor) < (0, 5) and jax.default_backend() == "cpu"
-
-
-pytestmark = pytest.mark.skipif(
-    _cpu_multiprocess_unsupported(),
-    reason="jax 0.4.x CPU backend refuses multiprocess computations "
-           "(environment limit, pre-existing since seed — "
-           "memory/TEST_MATRIX.md); runs on non-CPU backends")
-
-
 def _free_port():
     s = socket.socket()
     s.bind(("127.0.0.1", 0))

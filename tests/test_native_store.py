@@ -32,6 +32,27 @@ def test_native_library_builds():
     assert available(), "native hydrastore library failed to build"
 
 
+def test_native_cache_keyed_on_source_hash(tmp_path, monkeypatch):
+    """The cached library is trusted by a hash of the tracked source kept
+    beside it, never by mtime (a copied tree does not preserve mtimes): a
+    library with no stamp, or a stamp naming another source, is stale."""
+    from hydragnn_tpu import native
+
+    lib = tmp_path / "libhydrastore.so"
+    monkeypatch.setattr(native, "_LIB", str(lib))
+    monkeypatch.setattr(native, "_STAMP", str(lib) + ".srchash")
+    assert native._stale()                      # nothing built
+    lib.write_bytes(b"an older build the copy brought along")
+    assert native._stale()                      # library without a stamp
+    (tmp_path / "libhydrastore.so.srchash").write_text("0" * 64 + "\n")
+    assert native._stale()                      # stamp of another source
+    native._build()
+    assert not native._stale()
+    assert (tmp_path / "libhydrastore.so.srchash").read_text().strip() \
+        == native._src_hash()
+    ctypes.CDLL(str(lib))                       # and it is a real library
+
+
 @pytest.mark.parametrize("use_native", [True, False])
 def test_gpack_roundtrip(tmp_path, use_native):
     from hydragnn_tpu.data.gpack import GpackDataset, GpackWriter

@@ -232,10 +232,17 @@ def test_bench_uses_shared_flops_helper():
     want = step_cost_flops(f, a, a)
     got = bench._cost_flops(f, a, a)
     assert got == want and want > 0
-    # and bench's MFU peak is the telemetry constant
-    from hydragnn_tpu.telemetry.flops import MXU_PEAK_FLOPS
+    # and bench's MFU peak comes from the telemetry table, keyed by the
+    # device JAX reports: the v5e has its published row, a device outside
+    # the table (this CPU) has no peak — telemetry then emits no
+    # mfu_est_pct and bench refuses to compute one
+    from hydragnn_tpu.telemetry.flops import DEVICE_PEAKS, peak_flops
 
-    assert bench._mxu_peak() == MXU_PEAK_FLOPS
+    assert peak_flops("TPU v5 lite") == 197e12
+    assert DEVICE_PEAKS["TPU v5 lite"]["source"]
+    assert peak_flops(jax.devices()[0].device_kind) is None
+    with pytest.raises(RuntimeError, match="no published peak"):
+        bench._mxu_peak()
 
 
 def test_step_cost_flops_accepts_avals():
@@ -286,7 +293,10 @@ def test_training_smoke_emits_full_jsonl(tmp_path, capsys):
         assert {"loss", "tasks", "grad_norm", "step_time_s", "padding",
                 "run_id", "rank", "epoch", "step"} <= set(r)
         assert "nodes_waste_pct" in r["padding"]
-        assert "mfu_est_pct" in r  # CPU cost model supplies flops too
+        # the CPU cost model supplies flops, but a CPU has no row in
+        # DEVICE_PEAKS: a count is recorded, a utilization is not
+        assert r["flops_per_dispatch"] > 0
+        assert "mfu_est_pct" not in r
         assert r["tasks"], "per-head losses missing"
     # manifest folds the TimerTracer summaries in
     assert "train" in manifests[-1]["timers"]
@@ -299,6 +309,16 @@ def test_training_smoke_emits_full_jsonl(tmp_path, capsys):
     assert manifests[-1]["aggr_dispatch_summary"] == "scatter"
     run_starts = [r for r in recs if r["event"] == "run_start"]
     assert run_starts[-1]["aggr_backend"] == "scatter"
+    # both bracket records name the device the process got, the three
+    # versions, and its peak (none here)
+    dev = jax.devices()[0]
+    for r in (run_starts[-1], manifests[-1]):
+        assert r["platform"] == dev.platform == "cpu"
+        assert r["device_kind"] == dev.device_kind
+        assert r["device_count"] == len(jax.devices())
+        assert r["jax"] == jax.__version__ and r["jaxlib"]
+        assert "libtpu" in r
+        assert r["peak_flops_basis"] is None
     # epoch record carries loader padding + pipeline accounting
     assert "padding_waste_pct" in epochs[0]
 

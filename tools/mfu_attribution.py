@@ -34,7 +34,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from hydragnn_tpu.resilience.ckpt_io import atomic_write_json  # noqa: E402
 
-MXU_PEAK = 197e12
 MEASURED_GBPS = 585.0  # docs/PERF.md round-3 marginal bandwidth
 
 
@@ -59,9 +58,11 @@ def attribute(step, state, batch, step_s):
     from hydragnn_tpu.utils.hlo_bytes import (
         entry_fusion_boundary_bytes, shape_bytes)
 
+    from hydragnn_tpu.telemetry.flops import require_peak_flops
+
+    mxu_peak = require_peak_flops()  # unknown device: refuse, no default
     compiled = jax.jit(step).lower(state, batch).compile()
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, (list, tuple)) else ca
     flops = float(ca.get("flops", 0.0))
     ma = compiled.memory_analysis()
     ba_bytes = (ma.argument_size_in_bytes + ma.output_size_in_bytes
@@ -91,7 +92,7 @@ def attribute(step, state, batch, step_s):
             "instructions": cnt,
             "bandwidth_bound_ms": round(b / (MEASURED_GBPS * 1e9) * 1e3, 3),
         }
-    mm_flops_ms = flops / MXU_PEAK * 1e3
+    mm_flops_ms = flops / mxu_peak * 1e3
     bound = max(mm_flops_ms,
                 bucket_out.get("matmul", {}).get("bandwidth_bound_ms", 0.0))
     lower_bound_ms = bound + sum(
@@ -101,7 +102,7 @@ def attribute(step, state, batch, step_s):
         "step_ms": round(step_s * 1e3, 3),
         "flops_per_step": int(flops),
         "achieved_tflops": round(flops / step_s / 1e12, 3),
-        "mfu_pct": round(flops / step_s / MXU_PEAK * 100, 2),
+        "mfu_pct": round(flops / step_s / mxu_peak * 100, 2),
         "hbm_bytes_per_step_buffer_assignment": int(ba_bytes),
         "hbm_gbps": round(ba_bytes / step_s / 1e9, 1),
         "per_class": bucket_out,

@@ -1,0 +1,108 @@
+#!/usr/bin/env python3
+"""Does Mosaic accept the fused kernels?  Answered WITHOUT a chip.
+
+libtpu ships the TPU compiler, and ``jax.experimental.topologies`` can
+hand JAX a compile-only v5e topology on a machine that has no TPU.  This
+tool AOT-compiles one full fused train step (forward, backward, AdamW) per
+arch against it, so a layout Mosaic rejects or a kernel that overruns
+scoped VMEM shows up here, in the sandbox, as the compiler's own message —
+not as a spent chip call.  It proves COMPILATION only: whether the numbers
+are right, and every time or rate, still needs the chip
+(``python chip_smoke.py`` through the chip tool).
+
+    python tools/mosaic_aot.py                       # every arch h128 f32
+    python tools/mosaic_aot.py SchNet:1024:bfloat16  # ARCH:HIDDEN:DTYPE
+    python tools/mosaic_aot.py PNA:512:float32:highest   # + matmul precision
+
+Exit code 0 only if every target compiled.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import os
+import sys
+import time
+
+# compile-only: the process runs on the CPU backend and never opens a chip
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+os.environ["HYDRAGNN_AGGR_BACKEND"] = "fused"
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+TOPOLOGY = "v5e:2x2"
+
+
+def compile_target(arch: str, hidden: int, dtype: str, precision, sharding):
+    """Lower + compile ``arch``'s fused train step on bench.py's synthetic
+    batch; returns (tpu_custom_call count, compile seconds)."""
+    import jax
+    import numpy as np
+
+    import bench
+    from hydragnn_tpu.models.create import create_model
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.trainer import (
+        create_train_state, make_train_step)
+
+    _, batch, _, cfg, _, _ = bench._build(
+        arch, hidden=hidden, dtype=dtype, batch_size=256, trace_only=True)
+    model = create_model(cfg)
+    opt_spec = select_optimizer(bench.BENCH_OPTIMIZER)
+    state = jax.eval_shape(
+        lambda b: create_train_state(model, b, opt_spec), batch)
+
+    def on_tpu(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                           sharding=sharding), tree)
+
+    ctx = (jax.default_matmul_precision(precision) if precision
+           else contextlib.nullcontext())
+    with ctx:
+        lowered = jax.jit(make_train_step(model, cfg, opt_spec)).lower(
+            on_tpu(state), on_tpu(batch))
+        calls = lowered.as_text().count("tpu_custom_call")
+        t0 = time.perf_counter()
+        lowered.compile()
+    return calls, time.perf_counter() - t0
+
+
+def main(argv) -> int:
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from hydragnn_tpu.models.create import ALL_ARCHS
+
+    topo = topologies.get_topology_desc(topology_name=TOPOLOGY,
+                                        platform="tpu")
+    dev = topo.devices[0]
+    print(f"compile-only topology {TOPOLOGY}: {dev.device_kind} "
+          f"(jax {jax.__version__}; no device is opened)", flush=True)
+    # the ops pick Pallas interpret mode from jax.default_backend(); the
+    # process backend is the CPU, so say "tpu" while tracing for the chip
+    jax.default_backend = lambda: "tpu"
+
+    targets = argv or ([f"{a}:128:float32" for a in ALL_ARCHS]
+                       + ["SchNet:1024:bfloat16"])
+    failed = 0
+    for t in targets:
+        arch, hidden, dtype, *rest = t.split(":")
+        try:
+            calls, secs = compile_target(
+                arch, int(hidden), dtype, rest[0] if rest else None,
+                SingleDeviceSharding(dev))
+        except Exception as e:  # noqa: BLE001 — the compiler's message IS the result; reported and counted
+            failed += 1
+            print(f"FAIL {t}: {type(e).__name__}: {str(e)[:4000]}",
+                  flush=True)
+            continue
+        print(f"OK   {t}: tpu_custom_calls={calls} compile={secs:.1f}s",
+              flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

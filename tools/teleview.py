@@ -611,9 +611,12 @@ def main(argv=None) -> int:
         print(serve_bucket_section(serve_steps))
     if manifests:
         m = manifests[-1]
+        peak = m.get("peak_flops_basis")
         print(f"\nmanifest: run {m.get('run_id')}  "
               f"steps {m.get('total_steps')}  "
-              f"peak basis {m.get('peak_flops_basis', 0) / 1e12:.0f} TF/s")
+              f"device {m.get('device_kind', '?')} x{m.get('device_count', '?')}  "
+              + (f"peak basis {peak / 1e12:.0f} TF/s" if peak
+                 else "peak basis: none (device not in DEVICE_PEAKS)"))
         agg = (m.get("ring_summary") or {}).get("mfu_est_pct")
         if agg:
             print(f"  mfu_est_pct (ring window): avg {agg['avg']:.3g}  "
