@@ -1,0 +1,13 @@
+"""Everything after the gradient, milliseconds of a train step: the ops
+under ``step.optimizer`` (update and apply), ``step.metrics`` (telemetry
+norms and slot counts) and ``step.guard`` (non-finite select); computed
+like ``step_fwd_ms``."""
+
+import sys
+
+
+def read(facts):
+    run = sys.modules.get("benchmark_run") or sys.modules["__main__"]
+    scopes = (sys.modules.get("benchmark_trace_scopes")
+              or run.load_module("", "trace_scopes"))
+    return scopes.read(facts, 'phase_ms', ('optimizer', 'metrics', 'guard'))

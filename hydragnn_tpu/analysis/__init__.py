@@ -30,7 +30,14 @@ from .runner import (  # noqa: F401
     run_project,
     write_baseline,
 )
-from .registry import HEALTH_KINDS, KNOBS, emit_knob_docs  # noqa: F401
+from .registry import (  # noqa: F401
+    HEALTH_KINDS,
+    KNOBS,
+    TRACE_DOC_BEGIN,
+    TRACE_DOC_END,
+    emit_knob_docs,
+    emit_trace_docs,
+)
 
 # importing the rule modules registers every rule
 from .rules import lock_coverage  # noqa: F401

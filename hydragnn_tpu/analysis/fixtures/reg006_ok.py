@@ -1,5 +1,7 @@
-"""Fixture: declared span names only; unrelated ``.span()`` spellings
-(re.Match.span) stay out of the rule's reach (REG006 quiet)."""
+"""Fixture: declared names only — spans, tracer regions, device scopes,
+kernel names; unrelated ``.span()`` / ``.start()`` spellings
+(re.Match.span, Thread.start) stay out of the rule's reach (REG006
+quiet)."""
 
 import re
 
@@ -10,9 +12,23 @@ class Traced:
         with tr.span("serve.predict"):
             pass
 
-    def comm(self, comm_region, probe):
-        with comm_region("comm.dp_psum", probe):
+    def regions(self, tr, tracer, thread, which):
+        tr.start("train.dispatch")
+        tr.stop("train.dispatch")
+        with tracer.timer("data.collate"):
             pass
+        tr.start(which)  # literal-only, like span
+        thread.start("not a region")
+
+    def scopes(self, comm_region, phase):
+        with comm_region("comm.dp_psum"), phase("step.loss"):
+            pass
+
+    def kernels(self, pl, body, spec, kernel_name="gather_mul_seg_fwd"):
+        pl.pallas_call(body, name="scf_fwd")
+        pl.pallas_call(body, name=f"{spec.name}_bwd_p")
+        pl.pallas_call(body, name=kernel_name)
+        self.kernels(pl, body, spec, kernel_name="gather_mul_seg_bwd")
 
     def offsets(self, text):
         m = re.match(r"\d+", text)

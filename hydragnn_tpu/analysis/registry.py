@@ -12,11 +12,15 @@ docs against:
   package must name a kind declared in :data:`HEALTH_KINDS` (REG003),
   and every declared kind must be emitted somewhere and documented in
   docs/TELEMETRY.md (REG004);
-- every literal span name passed to the trace API (``span``,
-  ``record_interval``, ``comm_region`` calls) must name a span declared
-  in :data:`SPAN_NAMES` (REG006) — the flight recorder's waterfall and
-  percentile views group by these names, so an undeclared ad-hoc name is
-  a span nobody's dashboards will ever aggregate.
+- every literal name passed to the trace API must be declared (REG006):
+  spans and host regions (``span``, ``record_interval``, ``utils/tracer``
+  ``start``/``stop``/``timer``/``profile``) in :data:`SPAN_NAMES`,
+  device scopes (``phase``, ``comm_region``) in :data:`SCOPE_NAMES`,
+  kernels (``pallas_call(name=...)``) in :data:`KERNEL_NAMES` — the
+  flight recorder's percentile views and the benchmark's trace readers
+  group by these names, so an undeclared ad-hoc name is one nobody's
+  tables will ever aggregate.  The three tables of docs/TELEMETRY.md
+  "Tracing" are GENERATED from them (``--emit-docs``).
 
 Adding a knob, health kind, or span therefore means: declare it here,
 use it, document it — the lint gate fails on any one of the three
@@ -604,24 +608,177 @@ _SPAN_LIST = [
        "bucket collation/padding inside a flush"),
     _s("serve.predict", "hydragnn_tpu/serve/engine.py",
        "device execution inside a flush (blocked-on-ready)"),
-    # train-step phases (trace mode only)
+    # host regions of the trainer (utils/tracer start/stop: the timer
+    # summary, the profiler's host plane, and spans while tracing is on)
+    _s("train", "hydragnn_tpu/train/trainer.py",
+       "one epoch's train dispatches (its opening is where an epoch "
+       "begins)"),
+    _s("validate", "hydragnn_tpu/train/trainer.py",
+       "one epoch's validation dispatches"),
+    _s("test", "hydragnn_tpu/train/trainer.py",
+       "one epoch's test dispatches"),
+    _s("metrics_fetch", "hydragnn_tpu/train/trainer.py",
+       "the per-epoch sync: epoch.fetch + telemetry.flush"),
     _s("train.data_wait", "hydragnn_tpu/train/trainer.py",
-       "blocking loader next() before a train dispatch"),
-    _s("train.h2d", "hydragnn_tpu/train/trainer.py",
-       "jit arg ingest: synchronous host->device batch transfer"),
-    _s("train.step", "hydragnn_tpu/train/trainer.py",
-       "on-device step execution (compute + collectives; split via the "
-       "comms probe)"),
-    # collective regions (HLO metadata names under comm_probe=True)
-    _s("comm.dp_psum", "hydragnn_tpu/parallel/mesh.py",
-       "gradient/metric psum-pmean over the DP axes"),
-    _s("comm.zero_all_gather", "hydragnn_tpu/parallel/mesh.py",
-       "ZeRO stage-2 param all_gather before the forward"),
-    _s("comm.halo_exchange", "hydragnn_tpu/parallel/mesh.py",
-       "halo-row exchange assembling the extended graph shard"),
+       "blocking train-loader next() before a train dispatch"),
+    _s("train.dispatch", "hydragnn_tpu/train/trainer.py",
+       "host time of one train step call: argument ingest and enqueue, "
+       "never the device's execution"),
+    _s("eval.data_wait", "hydragnn_tpu/train/trainer.py",
+       "blocking val/test-loader next() before an eval dispatch"),
+    _s("eval.dispatch", "hydragnn_tpu/train/trainer.py",
+       "host time of one eval step call"),
+    _s("epoch.fetch", "hydragnn_tpu/train/trainer.py",
+       "the device_get that drains the epoch's dispatch queue"),
+    _s("telemetry.flush", "hydragnn_tpu/train/trainer.py",
+       "flush_steps: fetch and write the buffered step records"),
+    _s("epoch.tail", "hydragnn_tpu/train/trainer.py",
+       "end of metrics_fetch to the next train: scheduler, history, "
+       "checkpoint, JSONL, prints, set_epoch"),
+    _s("checkpoint.save", "hydragnn_tpu/train/trainer.py",
+       "one checkpoint write (best-model pickle, orbax, resume bundle)"),
+    _s("data.collate", "hydragnn_tpu/data/dataloader.py",
+       "collation of one padded batch (and its post_collate)"),
+    _s("data.stack", "hydragnn_tpu/parallel/mesh.py",
+       "DeviceStackLoader: np.stack of a dispatch group"),
+    _s("data.h2d", "hydragnn_tpu/data/prefetch.py",
+       "staging one batch on the device (resident staging, prefetcher)"),
+    _s("setup.stats", "hydragnn_tpu/config/config.py",
+       "DatasetStats.from_samples: one pass over every sample"),
+    _s("setup.loaders", "hydragnn_tpu/data/dataloader.py",
+       "create_dataloaders: pad specs and loaders"),
+    _s("setup.init_state", "hydragnn_tpu/train/trainer.py",
+       "create_train_state: model.init and optimizer init"),
+    _s("setup.mfu_cost", "hydragnn_tpu/telemetry/logger.py",
+       "the cost-analysis compile of the step behind mfu_est_pct"),
 ]
 
 SPAN_NAMES: Dict[str, SpanName] = {s.name: s for s in _SPAN_LIST}
+
+
+@dataclasses.dataclass(frozen=True)
+class ScopeName:
+    name: str
+    module: str  # module that opens the scope (repo-relative)
+    desc: str
+
+
+def _sc(name, module, desc):
+    return ScopeName(name=name, module=module, desc=desc)
+
+
+# jax.named_scope names inside the jitted steps: they reach the HLO
+# op_name of every op traced under them, and so the device trace
+_SCOPE_LIST = [
+    _sc("step.loss", "hydragnn_tpu/train/trainer.py",
+        "the value_and_grad call: jvp(...) below it is the forward, "
+        "transpose(jvp(...)) the backward"),
+    _sc("step.optimizer", "hydragnn_tpu/train/trainer.py",
+        "optimizer update and apply_updates (ZeRO slice/gather included)"),
+    _sc("step.metrics", "hydragnn_tpu/train/trainer.py",
+        "telemetry norms and real node/edge counts"),
+    _sc("step.guard", "hydragnn_tpu/train/trainer.py",
+        "non-finite flag and the old/new state select"),
+    _sc("step.eval", "hydragnn_tpu/train/trainer.py",
+        "the forward and loss of an eval step"),
+    _sc("comm.dp_psum", "hydragnn_tpu/parallel/mesh.py",
+        "gradient/metric psum-pmean over the DP axes"),
+    _sc("comm.zero_all_gather", "hydragnn_tpu/parallel/mesh.py",
+        "ZeRO stage-2 param all_gather before the forward"),
+    _sc("comm.halo_exchange", "hydragnn_tpu/parallel/mesh.py",
+        "halo-row exchange assembling the extended graph shard"),
+]
+
+SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelName:
+    name: str  # pl.pallas_call(name=...), <kernel>_<pass>
+    module: str
+    desc: str
+
+
+def _kn(name, module, desc):
+    return KernelName(name=name, module=module, desc=desc)
+
+
+_EDGE_BLOCK_KERNELS = [
+    ("scf", "SchNet CFConv edge pipeline (ops/scf_mp.py)"),
+    ("egcl", "EGNN EGCL block (ops/egcl_mp.py)"),
+    ("cgcnn", "CGCNN gated block (ops/cgcnn_mp.py)"),
+    ("dn_tri_builder", "DimeNet triplet-table builder (ops/dn_tri.py)"),
+]
+
+# what a Mosaic custom call is called in a device trace
+_KERNEL_LIST = [
+    _kn(f"{k}_{p}", "hydragnn_tpu/ops/fused_block.py", f"{what}: {pdesc}")
+    for k, what in _EDGE_BLOCK_KERNELS
+    for p, pdesc in (("fwd", "forward"),
+                     ("bwd_p", "backward, primary order: weights, "
+                               "geometry, primary-side dx"),
+                     ("bwd_s", "backward, other order: other-side dx"))
+] + [
+    _kn("gather_mul_seg_fwd", "hydragnn_tpu/ops/fused_mp.py",
+        "gather x[send] (* w) -> sorted segment sum"),
+    _kn("gather_mul_seg_bwd", "hydragnn_tpu/ops/fused_mp.py",
+        "the same kernel on the sender-sorted order: dx"),
+    _kn("seg_sum_dense_fwd", "hydragnn_tpu/ops/fused_mp.py",
+        "sorted segment sum on the dense schedule"),
+    _kn("dn_tri_fwd", "hydragnn_tpu/ops/dn_tri.py",
+        "DimeNet triplet message passing, forward"),
+    _kn("dn_tri_bwd", "hydragnn_tpu/ops/dn_tri.py",
+        "DimeNet triplet message passing, backward"),
+    _kn("dn_post_mlp_fwd", "hydragnn_tpu/ops/row_mlp.py",
+        "DimeNet post-triplet row MLP, forward"),
+    _kn("dn_post_mlp_bwd", "hydragnn_tpu/ops/row_mlp.py",
+        "DimeNet post-triplet row MLP, backward"),
+    _kn("poly_scatter_fwd", "hydragnn_tpu/ops/poly_mp.py",
+        "sorted segment moments (sum, sq, max/min, count)"),
+    _kn("poly_gather_fwd", "hydragnn_tpu/ops/poly_mp.py",
+        "gather x[send] -> segment moments (PNA)"),
+    _kn("gat_attn_fwd", "hydragnn_tpu/ops/gat_mp.py",
+        "GAT edge attention, forward"),
+    _kn("gat_attn_bwd_r", "hydragnn_tpu/ops/gat_mp.py",
+        "GAT edge attention backward, receiver order"),
+    _kn("gat_attn_bwd_s", "hydragnn_tpu/ops/gat_mp.py",
+        "GAT edge attention backward, sender order"),
+    _kn("seg_sum_pallas_fwd", "hydragnn_tpu/ops/aggregate.py",
+        "one-hot segment sum, any id order"),
+    _kn("seg_sum_sorted_fwd", "hydragnn_tpu/ops/aggregate.py",
+        "block-range segment sum over sorted ids"),
+]
+
+KERNEL_NAMES: Dict[str, KernelName] = {k.name: k for k in _KERNEL_LIST}
+
+
+TRACE_DOC_BEGIN = ("<!-- BEGIN GENERATED trace names "
+                   "(graftlint --emit-docs) -->")
+TRACE_DOC_END = "<!-- END GENERATED trace names -->"
+
+
+def emit_trace_docs() -> str:
+    """The region, scope and kernel name tables of docs/TELEMETRY.md
+    "Tracing", between TRACE_DOC_BEGIN and TRACE_DOC_END."""
+    def table(head, rows):
+        return "\n".join([f"| {head} | module | what |", "|---|---|---|"]
+                         + [f"| `{r.name}` | `{r.module}` | {r.desc} |"
+                            for r in rows])
+
+    return "\n\n".join([
+        TRACE_DOC_BEGIN,
+        "Host regions and spans (`SPAN_NAMES`): `utils/tracer` "
+        "`start`/`stop` and the flight recorder's `span` / "
+        "`record_interval`.",
+        table("region or span", SPAN_NAMES.values()),
+        "Device scopes (`SCOPE_NAMES`): `jax.named_scope` inside the "
+        "jitted steps, read from an op's `op_name`.",
+        table("scope", SCOPE_NAMES.values()),
+        "Kernel names (`KERNEL_NAMES`): `pl.pallas_call(name=...)`, what "
+        "a Mosaic custom call is called in a device trace.",
+        table("kernel", KERNEL_NAMES.values()),
+        TRACE_DOC_END,
+    ]) + "\n"
 
 
 KNOB_DOC_HEADER = """\

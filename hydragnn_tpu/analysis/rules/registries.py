@@ -17,7 +17,17 @@ from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from ..astutil import const_str
 from ..core import Finding, Rule, Severity, register
-from ..registry import HEALTH_KINDS, KNOBS, SPAN_NAMES, emit_knob_docs
+from ..registry import (
+    HEALTH_KINDS,
+    KERNEL_NAMES,
+    KNOBS,
+    SCOPE_NAMES,
+    SPAN_NAMES,
+    TRACE_DOC_BEGIN,
+    TRACE_DOC_END,
+    emit_knob_docs,
+    emit_trace_docs,
+)
 
 _KNOB_RE = re.compile(r"HYDRAGNN_[A-Z0-9_]+")
 
@@ -219,24 +229,74 @@ class HealthKindDrift(Rule):
         return out
 
 
-# trace-API entry points whose first positional arg is a span name.
-# ``span`` is deliberately held to a literal-only check (re.Match.span(1)
-# and other unrelated ``.span()`` spellings must not trip the rule);
-# ``record_interval``/``comm_region`` are unambiguous and also fail on
-# dynamic names the registry cannot see.
-_SPAN_CALL_NAMES = ("span", "record_interval", "comm_region")
-_SPAN_STRICT_NAMES = ("record_interval", "comm_region")
+# trace-API entry points whose first positional arg is a declared name,
+# and the registry that declares it.  ``span`` is deliberately held to a
+# literal-only check (re.Match.span(1) and other unrelated ``.span()``
+# spellings must not trip the rule); ``record_interval`` and the two
+# scope helpers are unambiguous and also fail on dynamic names the
+# registry cannot see.
+_NAME_CALLS = {
+    "span": ("span", SPAN_NAMES),
+    "record_interval": ("span", SPAN_NAMES),
+    "comm_region": ("scope", SCOPE_NAMES),
+    "phase": ("scope", SCOPE_NAMES),
+}
+_STRICT_NAMES = ("record_interval", "comm_region", "phase")
+# utils/tracer regions: ``tr.start("x")`` under the module's usual
+# aliases only — ``thread.start()`` and ``profiler.stop()`` are not
+# regions — and literal-only, like ``span``
+_TRACER_ALIASES = ("tr", "tracer")
+_TRACER_CALLS = ("start", "stop", "timer", "profile")
 
 
-def _iter_span_calls(tree: ast.AST):
+def _iter_name_calls(tree: ast.AST):
+    """(what, registry, strict, function name, call) per trace-API call
+    that takes a declared name first."""
     for node in ast.walk(tree):
-        if not isinstance(node, ast.Call):
+        if not isinstance(node, ast.Call) or not node.args:
             continue
         fn = node.func
         name = fn.attr if isinstance(fn, ast.Attribute) else (
             fn.id if isinstance(fn, ast.Name) else None)
-        if name in _SPAN_CALL_NAMES and node.args:
-            yield name, node
+        if (isinstance(fn, ast.Attribute) and name in _TRACER_CALLS
+                and isinstance(fn.value, ast.Name)
+                and fn.value.id in _TRACER_ALIASES):
+            yield "span", SPAN_NAMES, False, f"{fn.value.id}.{name}", node
+        elif name in _NAME_CALLS:
+            what, registry = _NAME_CALLS[name]
+            yield what, registry, name in _STRICT_NAMES, name, node
+
+
+def _kernel_name_nodes(tree: ast.AST):
+    """(node, value) of every place a kernel gets its name: the ``name=``
+    of a ``pallas_call`` (None when it is missing), a ``kernel_name=``
+    keyword, the default of a ``kernel_name`` parameter."""
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call):
+            fn = node.func
+            callee = fn.attr if isinstance(fn, ast.Attribute) else (
+                fn.id if isinstance(fn, ast.Name) else None)
+            kws = {k.arg: k.value for k in node.keywords}
+            if callee == "pallas_call":
+                yield node, kws.get("name")
+            if "kernel_name" in kws:
+                yield node, kws["kernel_name"]
+        elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            args = node.args
+            pos = args.posonlyargs + args.args
+            for a, d in zip(pos[len(pos) - len(args.defaults):],
+                            args.defaults):
+                if a.arg == "kernel_name":
+                    yield d, d
+            for a, d in zip(args.kwonlyargs, args.kw_defaults):
+                if a.arg == "kernel_name" and d is not None:
+                    yield d, d
+
+
+def _fstring_pattern(node: ast.JoinedStr) -> "re.Pattern":
+    return re.compile("".join(
+        re.escape(const_str(v)) if const_str(v) is not None else ".+"
+        for v in node.values) + r"\Z")
 
 
 @register
@@ -244,31 +304,71 @@ class UndeclaredSpanName(Rule):
     id = "REG006"
     name = "undeclared-span-name"
     severity = Severity.ERROR
-    doc = ("every span-name literal passed to the trace API (span/"
-           "record_interval/comm_region) must be declared in the "
-           "span-name registry (analysis/registry.py)")
+    doc = ("every literal name passed to the trace API must be declared "
+           "in analysis/registry.py: spans and host regions (span/"
+           "record_interval, tracer start/stop/timer/profile), device "
+           "scopes (phase/comm_region), kernel names (pallas_call "
+           "name=); docs/TELEMETRY.md's name tables are generated")
 
     def check_file(self, ctx) -> Iterable[Finding]:
         out: List[Finding] = []
-        for fname, call in _iter_span_calls(ctx.tree):
+        for what, registry, strict, fname, call in _iter_name_calls(
+                ctx.tree):
             s = const_str(call.args[0])
             if s is None:
-                if fname in _SPAN_STRICT_NAMES:
+                if strict:
                     out.append(self.finding(
                         ctx, call,
-                        f"{fname}() called with a non-literal span name "
-                        f"— the registry rule cannot see it; pass a "
-                        f"literal declared in SPAN_NAMES or suppress "
-                        f"with a reason"))
+                        f"{fname}() called with a non-literal {what} "
+                        f"name — the registry rule cannot see it; pass a "
+                        f"declared literal or suppress with a reason"))
                 continue
-            if s not in SPAN_NAMES:
+            if s not in registry:
                 out.append(self.finding(
                     ctx, call,
-                    f"span name `{s}` is not declared in the span-name "
-                    f"registry (hydragnn_tpu/analysis/registry.py) — "
-                    f"declare it (name/module/desc) and document it in "
-                    f"docs/TELEMETRY.md"))
+                    f"{what} name `{s}` is not declared in the {what}-"
+                    f"name registry (hydragnn_tpu/analysis/registry.py) "
+                    f"— declare it (name/module/desc) and regenerate "
+                    f"docs/TELEMETRY.md (`tools/graftlint.py "
+                    f"--emit-docs`)"))
+        for node, value in _kernel_name_nodes(ctx.tree):
+            s = const_str(value) if value is not None else None
+            if value is None:
+                msg = ("pallas_call() without name= — the kernel would "
+                       "be named by its caller's scope in a device trace")
+            elif s is not None:
+                msg = None if s in KERNEL_NAMES else (
+                    f"kernel name `{s}` is not declared in KERNEL_NAMES "
+                    f"(hydragnn_tpu/analysis/registry.py)")
+            elif isinstance(value, ast.JoinedStr):
+                pat = _fstring_pattern(value)
+                msg = None if any(pat.match(k) for k in KERNEL_NAMES) \
+                    else ("no declared kernel name has the form of this "
+                          "f-string (KERNEL_NAMES, analysis/registry.py)")
+            elif isinstance(value, ast.Name) and value.id == "kernel_name":
+                msg = None  # the parameter: checked where it is given
+            else:
+                msg = ("kernel name the registry rule cannot see; pass "
+                       "a declared literal or a `kernel_name` parameter")
+            if msg:
+                out.append(self.finding(ctx, node, msg))
         return out
+
+    def check_project(self, project) -> Iterable[Finding]:
+        reg_ctx = next((f for f in project.files
+                        if f.rel.endswith("analysis/registry.py")), None)
+        docs = project.read_text("docs/TELEMETRY.md") or ""
+        a, b = docs.find(TRACE_DOC_BEGIN), docs.find(TRACE_DOC_END)
+        if reg_ctx is not None and (
+                a < 0 or b < 0
+                or docs[a:b + len(TRACE_DOC_END)] + "\n"
+                != emit_trace_docs()):
+            return [self.finding(
+                reg_ctx, 1,
+                "docs/TELEMETRY.md's generated name tables are missing "
+                "or stale — regenerate with `python tools/graftlint.py "
+                "--emit-docs`")]
+        return []
 
 
 # (writer file, writer function, reader file, reader function) pairs for

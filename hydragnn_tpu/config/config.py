@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import numpy as np
 
 from hydragnn_tpu.graph.batch import HeadSpec
+from hydragnn_tpu.utils import tracer
 
 # Architecture keys defaulted to None when absent, matching
 # reference hydragnn/utils/config_utils.py:59-80.
@@ -290,6 +291,7 @@ class DatasetStats:
         self.minmax_graph_feature = minmax_graph_feature
 
     @staticmethod
+    @tracer.profile("setup.stats")
     def from_samples(samples, need_deg: bool = False) -> "DatasetStats":
         """Compute stats by scanning host-side GraphSamples (degree histogram
         parity with reference gather_deg, hydragnn/preprocess/utils.py:177-195)."""

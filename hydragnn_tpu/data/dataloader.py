@@ -23,6 +23,7 @@ from hydragnn_tpu.graph.batch import (
     collate,
 )
 from hydragnn_tpu.telemetry import pipeline as tele_pipe
+from hydragnn_tpu.utils import tracer
 
 
 class GraphDataLoader:
@@ -161,15 +162,16 @@ class GraphDataLoader:
     ) -> GraphBatch:
         """Pure (thread-safe) collation of one planned batch."""
         batch, spec = item
-        out = collate(
-            batch,
-            spec,
-            self.head_specs,
-            self.graph_feature_slices,
-            self.node_feature_slices,
-        )
-        if self.post_collate is not None:
-            out = self.post_collate(out)
+        with tracer.timer("data.collate"):
+            out = collate(
+                batch,
+                spec,
+                self.head_specs,
+                self.graph_feature_slices,
+                self.node_feature_slices,
+            )
+            if self.post_collate is not None:
+                out = self.post_collate(out)
         if tele_pipe.enabled():
             # collate volume: how many bytes/batches the host side produced
             # (telemetry epoch records relate this to H2D transfer bytes)
@@ -285,6 +287,7 @@ def bucket_pad_specs(
         nodes, edges, batch_size, n_buckets, round_to, n_sim, seed)
 
 
+@tracer.profile("setup.loaders")
 def create_dataloaders(
     trainset: Sequence[GraphSample],
     valset: Sequence[GraphSample],

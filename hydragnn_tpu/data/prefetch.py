@@ -22,6 +22,7 @@ from typing import Iterator, Optional
 # input-pipeline telemetry counters (no-ops unless a MetricsLogger enabled
 # them — see hydragnn_tpu/telemetry/pipeline.py)
 from hydragnn_tpu.telemetry import pipeline as tele_pipe
+from hydragnn_tpu.utils import tracer
 
 
 def drain_bounded_queue(q, sentinel, stop, on_item=None) -> None:
@@ -68,10 +69,13 @@ def _make_stage(sharding=None):
     device is the only possible placement."""
     import jax
 
+    def stage_batch(t):
+        return t
+
     if sharding is not None:
-        ident = jax.jit(lambda t: t, out_shardings=sharding)
+        ident = jax.jit(stage_batch, out_shardings=sharding)
     else:
-        ident = jax.jit(lambda t: t)
+        ident = jax.jit(stage_batch)
 
     def stage(batch):
         leaves = jax.tree_util.tree_leaves(batch)
@@ -85,7 +89,10 @@ def _make_stage(sharding=None):
             # moved nothing)
             tele_pipe.add("h2d_bytes", tele_pipe.batch_nbytes(batch))
             tele_pipe.add("h2d_batches", 1)
-        return ident(batch)
+        # the call ingests the arguments: the host->device copy is
+        # enqueued (and, from numpy, made) before it returns
+        with tracer.timer("data.h2d"):
+            return ident(batch)
 
     return stage
 

@@ -46,6 +46,7 @@ from hydragnn_tpu.graph.batch import (
     collate,
 )
 from hydragnn_tpu.telemetry import pipeline as tele_pipe
+from hydragnn_tpu.utils import tracer
 
 
 def _sample_nbytes(s: GraphSample) -> int:
@@ -266,15 +267,16 @@ class StreamingGraphLoader:
     ) -> GraphBatch:
         """Pure (thread-safe) collation of one planned batch."""
         batch, spec = item
-        out = collate(
-            batch,
-            spec,
-            self.head_specs,
-            self.graph_feature_slices,
-            self.node_feature_slices,
-        )
-        if self.post_collate is not None:
-            out = self.post_collate(out)
+        with tracer.timer("data.collate"):
+            out = collate(
+                batch,
+                spec,
+                self.head_specs,
+                self.graph_feature_slices,
+                self.node_feature_slices,
+            )
+            if self.post_collate is not None:
+                out = self.post_collate(out)
         if tele_pipe.enabled():
             tele_pipe.add("collate_bytes", tele_pipe.batch_nbytes(out))
             tele_pipe.add("collate_batches", 1)

@@ -167,6 +167,7 @@ def _pallas_segment_sum_impl(data2d, segment_ids, n_pad: int,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
         interpret=interpret,
+        name="seg_sum_pallas_fwd",
     )(seg_p, data_p)
 
 
@@ -246,6 +247,7 @@ def _sorted_impl(data2d, segment_ids, num_segments: int,
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="seg_sum_sorted_fwd",
     )(start, end, seg_p, data_p)
 
 

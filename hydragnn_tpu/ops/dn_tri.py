@@ -279,6 +279,7 @@ def _tri_fwd(radial, x2, cbf, w1, w2, idx_kj, idx_ji, tmask, num_radial):
         out_shape=jax.ShapeDtypeStruct((e_pad, _GH), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="dn_tri_fwd",
     )(step_i, step_eb, acc_valid, is_first,
       kj_p, ji_p, cbf_p, w1_p, w2_p, exp_m,
       xcat, xcat, xcat, xcat, xcat)
@@ -357,6 +358,7 @@ def _tri_vjp_bwd(num_radial, res, dout):
         ],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="dn_tri_bwd",
     )(step_i, step_eb, acc_valid, is_first, first_tb,
       kj_s, ji_s, cbf_s, w1_p, w2_p, exp_m,
       xcat, xcat, xcat, xcat, xcat,

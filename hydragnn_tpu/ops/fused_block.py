@@ -508,6 +508,7 @@ def _fused_fwd(spec, x, geo, em, weights, senders, receivers, sender_perm,
                    for wk in widths],
         grid_spec=grid_spec,
         interpret=interpret,
+        name=f"{spec.name}_fwd",
     )(step_i, step_eb, acc_valid, is_first, p_p, o_p, geo_p,
       *weights, *([x_p] * spec.window))
     return tuple(outs)
@@ -573,6 +574,7 @@ def _fused_bwd(spec, res, cts):
         out_shape=out_shape_p,
         grid_spec=grid_p,
         interpret=interpret,
+        name=f"{spec.name}_bwd_p",
     )(step_i, step_eb, acc_valid, is_first, feb,
       p_p, o_p, geo_p, *weights, *([x_p] * spec.window), *ct_ps)
     dws_p = outs_p[:nw]
@@ -611,6 +613,7 @@ def _fused_bwd(spec, res, cts):
             out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
             grid_spec=grid_s,
             interpret=interpret,
+            name=f"{spec.name}_bwd_s",
         )(step_i2, step_eb2, acc_valid2, is_first2,
           sord_p, wside_p, geo_sp, *weights, *([x_p] * spec.window),
           *ct_wins)

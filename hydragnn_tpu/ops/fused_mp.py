@@ -105,7 +105,7 @@ def _fwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
 
 
 def _fused_impl(x, w, senders, receivers, interpret, mask=None, window=3,
-                edge_valid=None):
+                edge_valid=None, kernel_name="gather_mul_seg_fwd"):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -173,6 +173,7 @@ def _fused_impl(x, w, senders, receivers, interpret, mask=None, window=3,
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name=kernel_name,
     )(step_i, step_eb, acc_valid, is_first, send_p, recv_p, w_p,
       *([x_p] * window))
     return out[:n, :f].astype(x.dtype)
@@ -236,7 +237,8 @@ def _vjp_bwd(window, res, g):
         g.astype(jnp.float32), w[sender_perm].astype(jnp.float32),
         receivers[sender_perm], senders[sender_perm],
         jax.default_backend() != "tpu", window=window,
-        edge_valid=None if edge_valid is None else edge_valid[sender_perm])
+        edge_valid=None if edge_valid is None else edge_valid[sender_perm],
+        kernel_name="gather_mul_seg_bwd")
     return dx.astype(x.dtype), dw, None, None, None, None
 
 
@@ -269,7 +271,8 @@ def _gss_bwd(res, g):
     mp = None if mask is None else mask[sender_perm]
     dx = _fused_impl(
         g.astype(jnp.float32), None, receivers[sender_perm],
-        senders[sender_perm], interpret, mask=mp, edge_valid=mp)
+        senders[sender_perm], interpret, mask=mp, edge_valid=mp,
+        kernel_name="gather_mul_seg_bwd")
     return dx.astype(g.dtype), None, None, None, None
 
 
@@ -342,6 +345,7 @@ def _scatter_impl(data2d, sorted_ids, num_segments, interpret):
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="seg_sum_dense_fwd",
     )(step_i, step_eb, acc_valid, is_first, ids_p, data_p)
     return out[:num_segments, :f].astype(data2d.dtype)
 

@@ -265,6 +265,7 @@ def _fwd_impl(xl, xr, att_mat, senders, receivers, edge_mask, b_edge,
         ],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="gat_attn_fwd",
     )(step_i, step_eb, acc_valid, is_first,
       send_p, recv_p, mask_p, b_p, am_p, xl_p, xl_p, xl_p, xr_p)
     return acc[:n], m[:n, :h], d[:n, :h]
@@ -548,6 +549,7 @@ def _gea_bwd(slope_f, res, cot):
         ],
         grid_spec=grid_r,
         interpret=interpret,
+        name="gat_attn_bwd_r",
     )(step_i, step_eb, acc_valid, is_first,
       send_p, recv_p, mask_p, b_p, am_p, qm_p,
       xl_p, xl_p, xl_p, xr_p, ga_p, mg)
@@ -596,6 +598,7 @@ def _gea_bwd(slope_f, res, cot):
         out_shape=jax.ShapeDtypeStruct((n_pad, hf), jnp.float32),
         grid_spec=grid_s,
         interpret=interpret,
+        name="gat_attn_bwd_s",
     )(step_i2, step_eb2, acc_valid2, is_first2,
       send_s, recv_s, mask_s, b_s, am_p, qm_p,
       xl_p, xr_p, xr_p, xr_p, ga_p, ga_p, ga_p, mg, mg, mg)

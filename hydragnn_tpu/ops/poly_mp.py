@@ -281,6 +281,7 @@ def _poly_scatter_impl(data2d, sorted_ids, num_segments, moments, interpret):
                    for w in widths],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="poly_scatter_fwd",
     )(step_i, step_eb, acc_valid, is_first, ids_p, data_p)
     return _slice_outs(moments, outs, num_segments, f, f_pad, data2d.dtype)
 
@@ -345,6 +346,7 @@ def _poly_gather_impl(x, senders, receivers, moments, mask, interpret,
                    for w in widths],
         grid_spec=grid_spec,
         interpret=interpret,
+        name="poly_gather_fwd",
     )(step_i, step_eb, acc_valid, is_first, send_p, recv_p, mask_p,
       *([x_p] * window))
     return _slice_outs(moments, outs, n, f, f_pad, x.dtype)
@@ -476,7 +478,8 @@ def _gps_bwd(moments, res, g):
         mp = m[sender_perm]
         dx = _fused_impl(
             g_sum, None, receivers[sender_perm], senders[sender_perm],
-            interpret, mask=mp, edge_valid=mp)
+            interpret, mask=mp, edge_valid=mp,
+            kernel_name="gather_mul_seg_bwd")
         return dx.astype(x.dtype), None, None, None, None
 
     # sq/mxmn need the messages: recompute the gather (receivers gather of

@@ -189,6 +189,7 @@ def _post_fwd(tri, x_ji, x_edge, n_before, n_after, wb):
         ],
         out_specs=pl.BlockSpec((_RB, _HP), lambda s: (s, 0)),
         interpret=interpret,
+        name="dn_post_mlp_fwd",
     )(tri_p, xji_p, xe_p, w_p, b_p)
     return out[:e, :h].astype(x_edge.dtype)
 
@@ -235,6 +236,7 @@ def _post_vjp_bwd(n_before, n_after, res, g):
                    pl.BlockSpec((L, _HP, _HP), lambda s: (0, 0, 0)),
                    pl.BlockSpec((L, 8, _HP), lambda s: (0, 0, 0))],
         interpret=interpret,
+        name="dn_post_mlp_bwd",
     )(tri_p, xji_p, xe_p, w_p, b_p, g_p)
 
     grads = [dtri_p[:e, :d].astype(tri.dtype),

@@ -92,8 +92,12 @@ def make_gspmd_train_step(model, cfg, opt_spec, mesh: Mesh,
         model, cfg, opt_spec, output_names,
         telemetry_metrics=telemetry_metrics,
         nonfinite_guard=nonfinite_guard)
-    return jax.jit(step, in_shardings=(repl, None), out_shardings=repl,
-                   donate_argnums=0)
+
+    def gspmd_train_step(state, g):
+        return step(state, g)
+
+    return jax.jit(gspmd_train_step, in_shardings=(repl, None),
+                   out_shardings=repl, donate_argnums=0)
 
 
 def make_gspmd_eval_step(model, cfg, mesh: Mesh):
@@ -101,7 +105,12 @@ def make_gspmd_eval_step(model, cfg, mesh: Mesh):
     from hydragnn_tpu.train.trainer import make_eval_step
 
     repl = NamedSharding(mesh, P())
-    return jax.jit(make_eval_step(model, cfg),
+    step = make_eval_step(model, cfg)
+
+    def gspmd_eval_step(state, g):
+        return step(state, g)
+
+    return jax.jit(gspmd_eval_step,
                    in_shardings=(repl, None), out_shardings=repl)
 
 

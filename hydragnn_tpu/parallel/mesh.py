@@ -19,7 +19,6 @@ Multi-host bootstrap: :func:`setup_distributed` wraps
 
 from __future__ import annotations
 
-import contextlib
 import os
 from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -32,7 +31,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.base import Base, ModelConfig
 from hydragnn_tpu.train.optimizer import OptimizerSpec
-from hydragnn_tpu.train.trainer import TrainState, _force_head_indices, _loss_and_metrics
+from hydragnn_tpu.utils import tracer
+from hydragnn_tpu.train.trainer import (
+    TrainState,
+    _force_head_indices,
+    _loss_and_metrics,
+    phase,
+)
 
 def _shard_map(fn, mesh, in_specs, out_specs):
     """``jax.shard_map`` with varying-manual-axes checking off (the metric
@@ -330,20 +335,15 @@ def _zero_state_specs(zero_sh, zero_specs, zero_stage2: bool) -> TrainState:
         opt_state=opt_spec_tree)
 
 
-def comm_region(name: str, probe: bool = False):
-    """Collective-attribution region (docs/TELEMETRY.md "Tracing").
-
-    Default OFF returns a plain ``nullcontext`` — the traced program is
-    byte-identical to the pre-tracing one (asserted like the PR-15 dtype
-    default-off purity).  With ``probe=True`` the region becomes a
+def comm_region(name: str):
+    """Collective-attribution region (docs/TELEMETRY.md "Tracing"): a
     ``jax.named_scope``, so every op it encloses carries the ``comm.*``
-    name in lowered HLO metadata and device profiles — the handle the
-    comms A/B probe (telemetry/comms.py) and xprof use to attribute
-    collective time.  Declared names: ``comm.dp_psum``,
-    ``comm.zero_all_gather``, ``comm.halo_exchange``
-    (analysis/registry.py SPAN_NAMES, lint REG006)."""
-    if not probe:
-        return contextlib.nullcontext()
+    name in lowered HLO metadata and device profiles — the handle xprof
+    and the benchmark's trace reader use to attribute collective time.
+    Metadata only, like the step phases (train/trainer.py:phase).
+    Declared names: ``comm.dp_psum``, ``comm.zero_all_gather``,
+    ``comm.halo_exchange`` (analysis/registry.py SCOPE_NAMES, lint
+    REG006)."""
     return jax.named_scope(name)
 
 
@@ -360,7 +360,6 @@ def make_dp_train_step(
     telemetry_metrics: bool = False,
     nonfinite_guard: bool = False,
     dtype_policy: str = "f32",
-    comm_probe: bool = False,
 ):
     """jit'd DP train step over stacked batches [D, ...].
 
@@ -399,10 +398,10 @@ def make_dp_train_step(
     the gradient pmean and the update stay f32.  Default "f32" traces the
     exact pre-policy program.
 
-    ``comm_probe`` wraps the collective sites (ZeRO all_gather, gradient
-    pmean + metric psums) in named ``comm.*`` regions for comm-vs-compute
-    attribution (telemetry/comms.py).  Default OFF traces the exact
-    pre-probe program.
+    The collective sites (ZeRO all_gather, gradient pmean + metric psums)
+    sit in named ``comm.*`` regions and the rest of the step in the
+    trainer's phases (train/trainer.py:phase): metadata for the device
+    trace, no op of their own.
     """
     energy_head, forces_head = _force_head_indices(output_names)
     axes = _dp_axes(axis)
@@ -425,7 +424,7 @@ def make_dp_train_step(
             # at-rest copy stays 1/N)
             from hydragnn_tpu.parallel import zero
 
-            with comm_region("comm.zero_all_gather", comm_probe):
+            with comm_region("comm.zero_all_gather"):
                 params_full = zero.unshard_tree_dims(
                     state.params, zero_sh.param_dims, zero_axis)
         else:
@@ -437,9 +436,10 @@ def make_dp_train_step(
                 energy_head, forces_head, dropout_rng,
                 dtype_policy=dtype_policy)
 
-        (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params_full)
-        with comm_region("comm.dp_psum", comm_probe):
+        with phase("step.loss"):
+            (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params_full)
+        with comm_region("comm.dp_psum"):
             # gradient pmean across devices = DDP all-reduce parity (over
             # a multi-slice mesh XLA reduces hierarchically: ICI first,
             # then DCN)
@@ -452,9 +452,10 @@ def make_dp_train_step(
             per_head = [jax.lax.psum(p * ng_local, axes) / denom
                         for p in per_head]
 
-        new_params, new_opt_state, updates = _apply_sharded_update(
-            state, grads, params_full, opt_spec, cfg, zero_specs,
-            zero_stage2, zero_axis, n_zero)
+        with phase("step.optimizer"):
+            new_params, new_opt_state, updates = _apply_sharded_update(
+                state, grads, params_full, opt_spec, cfg, zero_specs,
+                zero_stage2, zero_axis, n_zero)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -469,20 +470,23 @@ def make_dp_train_step(
         if telemetry_metrics:
             from hydragnn_tpu.train.trainer import step_telemetry_metrics
 
-            tele = step_telemetry_metrics(g, grads, new_params, updates)
-            # counts are per-shard — make them global like num_graphs
-            tele["nodes_real"] = jax.lax.psum(tele["nodes_real"], axes)
-            tele["edges_real"] = jax.lax.psum(tele["edges_real"], axes)
-            if zero_specs is not None:
-                # ZeRO: updates live sharded along zero_axis — the global
-                # norm is the psum-of-slice-norms (_zero_slice_norm;
-                # grad/param norms at stage 1 are already replicated:
-                # pmean'd grads, all-gathered params)
-                tele["update_norm"] = _zero_slice_norm(updates, zero_axis)
-                if zero_stage2:
-                    # stage 2: new_params are slices too
-                    tele["param_norm"] = _zero_slice_norm(
-                        new_params, zero_axis)
+            with phase("step.metrics"):
+                tele = step_telemetry_metrics(g, grads, new_params, updates)
+                # counts are per-shard — make them global like num_graphs
+                tele["nodes_real"] = jax.lax.psum(tele["nodes_real"], axes)
+                tele["edges_real"] = jax.lax.psum(tele["edges_real"], axes)
+                if zero_specs is not None:
+                    # ZeRO: updates live sharded along zero_axis — the
+                    # global norm is the psum-of-slice-norms
+                    # (_zero_slice_norm; grad/param norms at stage 1 are
+                    # already replicated: pmean'd grads, all-gathered
+                    # params)
+                    tele["update_norm"] = _zero_slice_norm(
+                        updates, zero_axis)
+                    if zero_stage2:
+                        # stage 2: new_params are slices too
+                        tele["param_norm"] = _zero_slice_norm(
+                            new_params, zero_axis)
             metrics.update(tele)
         if nonfinite_guard:
             from hydragnn_tpu.resilience.guards import (
@@ -493,9 +497,10 @@ def make_dp_train_step(
             # grads are already pmean'd (replicated) and loss psum'd, so
             # `bad` is identical on every replica; the selects revert the
             # sharded (ZeRO) opt-state slices and replicated params alike
-            bad = nonfinite_flag(loss, grads)
-            new_state, metrics = apply_step_guard(
-                bad, state, new_state, metrics)
+            with phase("step.guard"):
+                bad = nonfinite_flag(loss, grads)
+                new_state, metrics = apply_step_guard(
+                    bad, state, new_state, metrics)
         return new_state, metrics
 
     state_specs = _zero_state_specs(zero_sh, zero_specs, zero_stage2)
@@ -515,7 +520,12 @@ def make_dp_train_step(
             return state, merge_scanned_metrics(ms)
 
         return jax.jit(multi, donate_argnums=0)
-    return jax.jit(sharded, donate_argnums=0)
+
+    # the program's name in a device trace is the jitted function's
+    def train_step(state, g):
+        return sharded(state, g)
+
+    return jax.jit(train_step, donate_argnums=0)
 
 
 def make_dp_eval_step(
@@ -544,15 +554,17 @@ def make_dp_eval_step(
 
             params = zero_mod.unshard_tree_dims(
                 state.params, zero.param_dims, zero.axis)
-        loss, (per_head, _, outputs) = _loss_and_metrics(
-            model, cfg, params, state.batch_stats, g, False)
-        # weight by real graphs so empty wrap-padding shards don't dilute
-        ng_local = g.n_real_graphs
-        num_graphs = jax.lax.psum(ng_local, axes)
-        denom = jnp.maximum(num_graphs, 1.0)
-        loss = jax.lax.psum(loss * ng_local, axes) / denom
-        per_head = [jax.lax.psum(p * ng_local, axes) / denom
-                    for p in per_head]
+        with phase("step.eval"):
+            loss, (per_head, _, outputs) = _loss_and_metrics(
+                model, cfg, params, state.batch_stats, g, False)
+            # weight by real graphs so empty wrap-padding shards don't
+            # dilute
+            ng_local = g.n_real_graphs
+            num_graphs = jax.lax.psum(ng_local, axes)
+            denom = jnp.maximum(num_graphs, 1.0)
+            loss = jax.lax.psum(loss * ng_local, axes) / denom
+            per_head = [jax.lax.psum(p * ng_local, axes) / denom
+                        for p in per_head]
         # re-add the device axis so outputs gather across shards
         outputs = jax.tree.map(lambda x: x[None], outputs)
         return {
@@ -581,7 +593,11 @@ def make_dp_eval_step(
             "outputs": P(axes),
         },
     )
-    return jax.jit(sharded)
+
+    def dp_eval_step(state, g):
+        return sharded(state, g)
+
+    return jax.jit(dp_eval_step)
 
 
 def make_halo_train_step(
@@ -595,7 +611,6 @@ def make_halo_train_step(
     zero_axis: Optional[str] = None,
     telemetry_metrics: bool = False,
     nonfinite_guard: bool = False,
-    comm_probe: bool = False,
 ):
     """jit'd train step over a halo-sharded GIANT graph: the input is a
     stacked :class:`~hydragnn_tpu.graph.partition.HaloBatch` [D, ...] —
@@ -649,7 +664,7 @@ def make_halo_train_step(
         if zero_stage2:
             from hydragnn_tpu.parallel import zero
 
-            with comm_region("comm.zero_all_gather", comm_probe):
+            with comm_region("comm.zero_all_gather"):
                 params_full = zero.unshard_tree_dims(
                     state.params, zero_sh.param_dims, zero_axis)
         else:
@@ -657,14 +672,15 @@ def make_halo_train_step(
 
         def loss_fn(params):
             with halo_context(axes[0]):
-                with comm_region("comm.halo_exchange", comm_probe):
+                with comm_region("comm.halo_exchange"):
                     g_ext = assemble_extended(hb, axes[0])
                 return _loss_and_metrics(
                     model, cfg, params, state.batch_stats, g_ext, True,
                     energy_head, forces_head, dropout_rng)
 
-        (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params_full)
+        with phase("step.loss"):
+            (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params_full)
         # per-shard grads are PARTIAL sums over disjoint owned subgraphs;
         # psum (not pmean) assembles the global gradient.  loss, per-head
         # losses and BN statistics came back GLOBAL already (the
@@ -680,13 +696,14 @@ def make_halo_train_step(
         # when the step builders move to check_vma=True).
         cal = jax.grad(lambda s: jax.lax.psum(s, axes[0]))(
             jnp.asarray(1.0, jnp.float32))
-        with comm_region("comm.dp_psum", comm_probe):
+        with comm_region("comm.dp_psum"):
             grads = jax.lax.psum(
                 jax.tree.map(lambda g: g / cal, grads), axes)
         num_graphs = hb.n_real_graphs  # graph arrays replicated per shard
-        new_params, new_opt_state, updates = _apply_sharded_update(
-            state, grads, params_full, opt_spec, cfg, zero_specs,
-            zero_stage2, zero_axis, n_zero)
+        with phase("step.optimizer"):
+            new_params, new_opt_state, updates = _apply_sharded_update(
+                state, grads, params_full, opt_spec, cfg, zero_specs,
+                zero_stage2, zero_axis, n_zero)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -702,20 +719,24 @@ def make_halo_train_step(
             from hydragnn_tpu.train.trainer import tree_l2_norm
 
             owned = hb.extras.get("edge_owned_mask", hb.edge_mask)
-            metrics.update({
-                "grad_norm": tree_l2_norm(grads),
-                "param_norm": tree_l2_norm(new_params),
-                "update_norm": tree_l2_norm(updates),
-                # counts over OWNED rows/edges — halo duplicates excluded,
-                # so padding-waste accounting stays meaningful
-                "nodes_real": jax.lax.psum(jnp.sum(hb.node_mask), axes),
-                "edges_real": jax.lax.psum(jnp.sum(owned), axes),
-            })
-            if zero_specs is not None:
-                metrics["update_norm"] = _zero_slice_norm(updates, zero_axis)
-                if zero_stage2:
-                    metrics["param_norm"] = _zero_slice_norm(
-                        new_params, zero_axis)
+            with phase("step.metrics"):
+                metrics.update({
+                    "grad_norm": tree_l2_norm(grads),
+                    "param_norm": tree_l2_norm(new_params),
+                    "update_norm": tree_l2_norm(updates),
+                    # counts over OWNED rows/edges — halo duplicates
+                    # excluded, so padding-waste accounting stays
+                    # meaningful
+                    "nodes_real": jax.lax.psum(
+                        jnp.sum(hb.node_mask), axes),
+                    "edges_real": jax.lax.psum(jnp.sum(owned), axes),
+                })
+                if zero_specs is not None:
+                    metrics["update_norm"] = _zero_slice_norm(
+                        updates, zero_axis)
+                    if zero_stage2:
+                        metrics["param_norm"] = _zero_slice_norm(
+                            new_params, zero_axis)
         if nonfinite_guard:
             from hydragnn_tpu.resilience.guards import (
                 apply_step_guard,
@@ -724,9 +745,10 @@ def make_halo_train_step(
 
             # grads are psum'd (replicated) and the loss is global, so the
             # flag is identical on every shard
-            bad = nonfinite_flag(loss, grads)
-            new_state, metrics = apply_step_guard(
-                bad, state, new_state, metrics)
+            with phase("step.guard"):
+                bad = nonfinite_flag(loss, grads)
+                new_state, metrics = apply_step_guard(
+                    bad, state, new_state, metrics)
         return new_state, metrics
 
     state_specs = _zero_state_specs(zero_sh, zero_specs, zero_stage2)
@@ -736,7 +758,11 @@ def make_halo_train_step(
         in_specs=(state_specs, P(axes)),
         out_specs=(state_specs, P()),
     )
-    return jax.jit(sharded, donate_argnums=0)
+
+    def halo_train_step(state, hb):
+        return sharded(state, hb)
+
+    return jax.jit(halo_train_step, donate_argnums=0)
 
 
 def make_halo_eval_step(
@@ -767,7 +793,7 @@ def make_halo_eval_step(
 
             params = zero_mod.unshard_tree_dims(
                 state.params, zero.param_dims, zero.axis)
-        with halo_context(axes[0]):
+        with phase("step.eval"), halo_context(axes[0]):
             g_ext = assemble_extended(hb, axes[0])
             loss, (per_head, _, outputs) = _loss_and_metrics(
                 model, cfg, params, state.batch_stats, g_ext, False)
@@ -798,7 +824,11 @@ def make_halo_eval_step(
             "outputs": P(axes),
         },
     )
-    return jax.jit(sharded)
+
+    def halo_eval_step(state, hb):
+        return sharded(state, hb)
+
+    return jax.jit(halo_eval_step)
 
 
 class DeviceStackLoader:
@@ -837,7 +867,9 @@ class DeviceStackLoader:
         for g in self.loader:
             group.append(g)
             if len(group) == self.n_devices:
-                yield stack_batches(group)
+                with tracer.timer("data.stack"):
+                    stacked = stack_batches(group)
+                yield stacked
                 group = []
         if group and not self.drop_last:
             # pad with empty copies shaped like THIS group (zero graph_mask);
@@ -845,7 +877,9 @@ class DeviceStackLoader:
             empty = jax.tree.map(np.zeros_like, group[0])
             while len(group) < self.n_devices:
                 group.append(empty)
-            yield stack_batches(group)
+            with tracer.timer("data.stack"):
+                stacked = stack_batches(group)
+            yield stacked
 
 
 class GlobalBatchLoader:

@@ -7,12 +7,11 @@ designed: *what fraction of a DP / ZeRO / halo step is collective time?*
 Per-op timers can't answer it inside one fused XLA program, so the probe
 measures it differentially:
 
-  - **A (step)** — the full train step, built with ``comm_probe=True`` so
-    every collective sits in a named ``comm.*`` region
-    (:func:`~hydragnn_tpu.parallel.mesh.comm_region`).  The annotation
-    changes HLO *metadata only* — the timed program is the production
-    program — and doubles as the xprof/Perfetto attribution handle when a
-    device trace is captured (utils/profile.py).
+  - **A (step)** — the full train step as the trainer builds it; every
+    collective sits in a named ``comm.*`` region
+    (:func:`~hydragnn_tpu.parallel.mesh.comm_region`): HLO *metadata
+    only*, and the xprof/Perfetto attribution handle when a device trace
+    is captured (utils/profile.py).
   - **B (comm-only)** — a shard_map program that replays JUST the step's
     collectives on identically-shaped data: the gradient ``pmean`` over a
     param-shaped tree for DP, plus the ZeRO ``all_gather`` of the param
@@ -102,13 +101,12 @@ def dp_comms_probe(model, cfg, opt_spec, mesh, state, batches,
     zero_sh, _zero_specs, zero_axis, _n_zero, zero_stage2 = \
         _resolve_zero_request(zero_specs, None, axes, mesh)
 
-    # A: the annotated production step.  It donates its state input, so
+    # A: the production step.  It donates its state input, so
     # the probe feeds a COPY and only ever re-feeds the previous
     # iteration's output — the caller's state is never donated.
     step = make_dp_train_step(model, cfg, opt_spec, mesh, output_names,
                               axis=axis if axis is not None else DATA_AXIS,
-                              zero_specs=zero_specs, steps=steps,
-                              comm_probe=True)
+                              zero_specs=zero_specs, steps=steps)
     st = _copy_tree(state)
     b = _copy_tree(batches)
     st, m = step(st, b)  # compile + warmup

@@ -45,6 +45,7 @@ from hydragnn_tpu.telemetry.flops import (
     step_cost_flops,
 )
 from hydragnn_tpu.telemetry.sinks import Sink, TensorBoardSink, build_sinks
+from hydragnn_tpu.utils import tracer
 from hydragnn_tpu.utils.env import env_flag, env_int, env_str
 
 
@@ -507,7 +508,10 @@ class MetricsLogger:
         if avals is None or self._state_avals is None:
             return None
         try:
-            fl = step_cost_flops(self._step_fn, self._state_avals, avals)
+            # a second compile of the step, for XLA's cost model only
+            with tracer.timer("setup.mfu_cost"):
+                fl = step_cost_flops(
+                    self._step_fn, self._state_avals, avals)
             self._flops_cache[sig] = fl
             return fl
         except Exception:  # graftlint: disable=ROB001 (cost analysis is best-effort; _mfu_broken records it)

@@ -45,6 +45,7 @@ from typing import Any, Callable, Dict, List, Optional
 __all__ = [
     "SpanContext",
     "SpanRecorder",
+    "RegionSpans",
     "Span",
     "extract_trace_context",
     "chrome_trace",
@@ -261,6 +262,33 @@ class SpanRecorder:
         with self._lock:
             total = self._next
         return {"recorded": total, "by_name": self.percentiles()}
+
+
+class RegionSpans:
+    """The ``utils/tracer`` tracer that turns the trainer's host regions
+    (``train.dispatch``, ``epoch.fetch``, ``data.collate`` ...) into
+    spans of one recorder, all on one ``trace_id``: the trainer registers
+    it while ``Telemetry.trace`` is on.  It only reads a clock, so the
+    traced trainer is the same program as the untraced one."""
+
+    def __init__(self, recorder: SpanRecorder):
+        from hydragnn_tpu.utils.tracer import OpenRegions
+
+        self._rec = recorder
+        self._trace_id = new_trace_id()
+        self._open = OpenRegions()
+
+    def start(self, name: str) -> None:
+        self._open.push(name, time.perf_counter())
+
+    def stop(self, name: str) -> None:
+        t0 = self._open.pop(name)
+        if t0 is not None:
+            self._rec.record_interval(  # graftlint: disable=REG006 (a region's name is checked where the trainer opens it)
+                name, t0, time.perf_counter(), trace_id=self._trace_id)
+
+    def reset(self) -> None:
+        pass
 
 
 def chrome_trace(records) -> Dict[str, Any]:

@@ -39,6 +39,7 @@ from hydragnn_tpu.train.optimizer import (
     select_optimizer,
     set_learning_rate,
 )
+from hydragnn_tpu.utils import tracer as tr
 
 
 @struct.dataclass
@@ -49,6 +50,7 @@ class TrainState:
     opt_state: Any
 
 
+@tr.profile("setup.init_state")
 def create_train_state(
     model: Base,
     example_batch: GraphBatch,
@@ -222,6 +224,19 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
     }
 
 
+def phase(name: str):
+    """One phase of a step: a ``jax.named_scope``, so every op traced
+    inside carries ``name`` in its HLO ``op_name`` and the device trace can
+    be split by it (docs/TELEMETRY.md "Tracing").  Every step builder,
+    here and in parallel/, takes its phases from this one helper; the
+    names are declared in analysis/registry.py SCOPE_NAMES (lint REG006).
+    Metadata only: the executed program is the same with or without it.
+    ``step.loss`` goes round the ``value_and_grad`` call: JAX then writes
+    ``jvp(...)`` below it for the forward and ``transpose(jvp(...))`` for
+    the backward, and the flax module path follows."""
+    return jax.named_scope(name)
+
+
 def make_train_step(
     model: Base,
     cfg: ModelConfig,
@@ -257,16 +272,18 @@ def make_train_step(
                 energy_head, forces_head, dropout_rng,
                 dtype_policy=dtype_policy)
 
-        (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
-        updates, new_opt_state = opt_spec.tx.update(
-            grads, state.opt_state, state.params)
-        from hydragnn_tpu.models.base import encoder_freeze_mask
+        with phase("step.loss"):
+            (loss, (per_head, new_stats, _)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params)
+        with phase("step.optimizer"):
+            updates, new_opt_state = opt_spec.tx.update(
+                grads, state.opt_state, state.params)
+            from hydragnn_tpu.models.base import encoder_freeze_mask
 
-        updates = encoder_freeze_mask(updates, cfg.freeze_conv)
-        import optax
+            updates = encoder_freeze_mask(updates, cfg.freeze_conv)
+            import optax
 
-        new_params = optax.apply_updates(state.params, updates)
+            new_params = optax.apply_updates(state.params, updates)
         new_state = TrainState(
             step=state.step + 1,
             params=new_params,
@@ -279,17 +296,19 @@ def make_train_step(
             **{f"task_{i}": t for i, t in enumerate(per_head)},
         }
         if telemetry_metrics:
-            metrics.update(
-                step_telemetry_metrics(g, grads, new_params, updates))
+            with phase("step.metrics"):
+                metrics.update(
+                    step_telemetry_metrics(g, grads, new_params, updates))
         if nonfinite_guard:
             from hydragnn_tpu.resilience.guards import (
                 apply_step_guard,
                 nonfinite_flag,
             )
 
-            bad = nonfinite_flag(loss, grads)
-            new_state, metrics = apply_step_guard(
-                bad, state, new_state, metrics)
+            with phase("step.guard"):
+                bad = nonfinite_flag(loss, grads)
+                new_state, metrics = apply_step_guard(
+                    bad, state, new_state, metrics)
         return new_state, metrics
 
     return train_step
@@ -431,8 +450,9 @@ def make_eval_step(
     model: Base, cfg: ModelConfig
 ) -> Callable[[TrainState, GraphBatch], Dict[str, Any]]:
     def eval_step(state: TrainState, g: GraphBatch):
-        loss, (per_head, _, outputs) = _loss_and_metrics(
-            model, cfg, state.params, state.batch_stats, g, False)
+        with phase("step.eval"):
+            loss, (per_head, _, outputs) = _loss_and_metrics(
+                model, cfg, state.params, state.batch_stats, g, False)
         return {
             "loss": loss,
             "num_graphs": g.n_real_graphs,
@@ -535,7 +555,9 @@ class CheckpointTracker:
         if self.count < self.warmup or metric >= self.best:
             return False
         self.best = metric
-        save_state(self.transform(state), self.name, self.path, rank=self.rank)
+        with tr.timer("checkpoint.save"):
+            save_state(self.transform(state), self.name, self.path,
+                       rank=self.rank)
         return True
 
     def state_dict(self) -> Dict[str, float]:
@@ -589,43 +611,6 @@ def load_state(state: TrainState, log_name: str, path: str = "./logs/") -> Train
 # ---------------------------------------------------------------------------
 
 
-def _traced_loader(loader, tr):
-    """Yield ``loader``'s batches, recording each blocking ``next()`` as a
-    ``train.data_wait`` span.  Only wrapped in when tracing is on — the
-    default epoch loop iterates the raw loader untouched."""
-    it = iter(loader)
-    while True:
-        t0 = time.perf_counter()
-        try:
-            g = next(it)
-        except StopIteration:
-            return
-        tr.record_interval("train.data_wait", t0, time.perf_counter())
-        yield g
-
-
-def _traced_step(step_fn, tr):
-    """Trace-mode train-step wrapper: splits each dispatch into an
-    arg-ingest span (``train.h2d`` — the jit call's synchronous host->
-    device transfer of the batch) and an on-device span (``train.step`` —
-    compute + collectives; split the two with the ``comms`` probe's
-    comm_pct).  The completion block is ONE added device sync per step:
-    the flight recorder trades the zero-sync telemetry discipline for
-    phase attribution, which is why tracing is opt-in."""
-
-    def stepped(state, g):
-        t0 = time.perf_counter()
-        state, metrics = step_fn(state, g)
-        t1 = time.perf_counter()
-        jax.block_until_ready(metrics["loss"])
-        t2 = time.perf_counter()
-        tr.record_interval("train.h2d", t0, t1)
-        tr.record_interval("train.step", t1, t2)
-        return state, metrics
-
-    return stepped
-
-
 def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
                steps_per_item: int = 1, telemetry=None, guard=None,
                preempt=None, chaos=None, skip_first: int = 0,
@@ -645,13 +630,11 @@ def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
     total = None
     tasks = None
     n = None
-    # flight recorder (opt-in, telemetry.trace): wrap the loader and step
-    # so phase spans are recorded WITHOUT touching the default loop body —
-    # tracing off leaves this function's hot path byte-identical
-    tr = getattr(telemetry, "spans", None) if train else None
-    if tr is not None:
-        loader = _traced_loader(loader, tr)
-        step_fn = _traced_step(step_fn, tr)
+    # host regions (utils/tracer): the loader's blocking next() and the
+    # step call itself — argument ingest and enqueue, which does NOT wait
+    # for the device.  None adds a sync, so the loop is the same program
+    # whatever listens (docs/TELEMETRY.md "Tracing")
+    wait_region = "train.data_wait" if train else "eval.data_wait"
     # HYDRAGNN_MAX_NUM_BATCH caps TRAIN STEPS per epoch (reference
     # get_nbatch, train_validate_test.py:40-50 — used for weak-scaling
     # measurement).  With scan chunking each loader item carries
@@ -659,7 +642,17 @@ def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
     # (floor(nbatch/K) dispatches), so a K>1 run never does more optimizer
     # steps than the K=1 run it's compared against.
     nbatch = int(os.getenv("HYDRAGNN_MAX_NUM_BATCH", "0")) or None
-    for ibatch, g in enumerate(loader):
+    batches = iter(loader)
+    ibatch = -1
+    while True:
+        tr.start(wait_region)
+        try:
+            g = next(batches, None)
+        finally:
+            tr.stop(wait_region)
+        if g is None:
+            break
+        ibatch += 1
         if nbatch is not None and (ibatch + 1) * steps_per_item > nbatch:
             break
         if ibatch < skip_first:
@@ -677,7 +670,9 @@ def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
         if train:
             if chaos is not None:
                 g = chaos.on_train_dispatch(g)
+            tr.start("train.dispatch")
             state, metrics = step_fn(state, g)
+            tr.stop("train.dispatch")
             if telemetry is not None:
                 # zero-sync: device scalars + host timestamp are buffered;
                 # the one fetch happens in telemetry.flush_steps at epoch end
@@ -690,7 +685,9 @@ def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
             n_tasks = sum(1 for k in metrics if k.startswith("task_"))
             per_head = [metrics[f"task_{i}"] for i in range(n_tasks)]
         else:
+            tr.start("eval.dispatch")
             metrics = step_fn(state, g)
+            tr.stop("eval.dispatch")
             per_head = metrics["per_head"]
         ng = metrics["num_graphs"]
         loss_w = metrics["loss"] * ng
@@ -700,7 +697,7 @@ def _run_epoch(step_fn, state, loader, train: bool, profiler=None,
         else:
             total, tasks, n = total + loss_w, tasks + ph, n + ng
         if profiler is not None:
-            profiler.step()
+            profiler.step(steps_per_item)
         if train and preempt is not None:
             if chaos is not None and chaos.preempt_now():
                 preempt.request()
@@ -1398,7 +1395,6 @@ def train_validate_test(
     orbax_dir = os.path.join(logs_dir, log_name, "orbax")
 
     from hydragnn_tpu.utils.print_utils import print_distributed
-    from hydragnn_tpu.utils import tracer as tr
     from hydragnn_tpu.utils.profile import Profiler
 
     # per-batch wait/warmup/active trace schedule (reference wires
@@ -1556,18 +1552,36 @@ def train_validate_test(
             # the saved position with (resilience/elastic.py)
             "world": _launched_world(),
         }
-        ok = save_resume_bundle(
-            consolidate(state), meta, resume_dir(logs_dir, log_name),
-            rank=rank, retries=res_cfg.ckpt_retries,
-            backoff=res_cfg.ckpt_backoff, telemetry=telemetry,
-            chaos=chaos, reason=reason,
-            cross_rank=(not explicit_mesh and world_size > 1))
+        with tr.timer("checkpoint.save"):
+            ok = save_resume_bundle(
+                consolidate(state), meta, resume_dir(logs_dir, log_name),
+                rank=rank, retries=res_cfg.ckpt_retries,
+                backoff=res_cfg.ckpt_backoff, telemetry=telemetry,
+                chaos=chaos, reason=reason,
+                cross_rank=(not explicit_mesh and world_size > 1))
         telemetry.health(
             "walltime_save" if reason == "walltime" else "preempt_save",
             epoch=epoch_i, items=items, ok=ok,
             step=int(jax.device_get(state.step)))
         return ok
 
+    # host regions are spans too while the flight recorder is on
+    if telemetry.spans is not None:
+        from hydragnn_tpu.telemetry.trace import RegionSpans
+
+        tr.register("spans", RegionSpans(telemetry.spans))
+    # while the regions are annotated for a profiler, leave it the map
+    # from compiled instruction to scope, which a device trace does not
+    # carry (telemetry/hlo_scopes.py); written once, after the first epoch
+    step_notes = None
+    if tr.has("jax") and telemetry.enabled and rank == 0:
+        from hydragnn_tpu.telemetry.hlo_scopes import StepPrograms
+
+        step_notes = StepPrograms()
+        train_step = step_notes.watch(train_step)
+        eval_step = step_notes.watch(eval_step)
+    # epoch.tail: from the end of metrics_fetch to the next epoch's train
+    in_tail = False
     try:
         for epoch in range(start_epoch, num_epoch):
             t0 = time.time()
@@ -1590,6 +1604,9 @@ def train_validate_test(
             # sync stalls dispatch until the device catches up).  The tr
             # regions therefore time dispatch, not execution; the fetch
             # region carries the wait.
+            if in_tail:
+                tr.stop("epoch.tail")
+                in_tail = False
             tr.start("train")
             state, train_acc = _run_epoch(
                 train_step, state, train_loader, True, profiler=profiler,
@@ -1640,12 +1657,23 @@ def train_validate_test(
                 _, test_acc = _run_epoch(eval_step, state, test_loader, False)
                 tr.stop("test")
             tr.start("metrics_fetch")
+            # the drain: the host waits here for everything it dispatched
+            tr.start("epoch.fetch")
             train_acc, val_acc, test_acc = jax.device_get(
                 (train_acc, val_acc, test_acc))
+            tr.stop("epoch.fetch")
             # drain the buffered per-step telemetry in the same sync window
             # (one device_get of tiny scalars; no-op when disabled)
+            tr.start("telemetry.flush")
             telemetry.flush_steps()
+            tr.stop("telemetry.flush")
             tr.stop("metrics_fetch")
+            tr.start("epoch.tail")
+            in_tail = True
+            if step_notes is not None:
+                step_notes.write(
+                    os.path.join(telemetry.out_dir, "hlo_scopes.json"))
+                step_notes = None
             train_loss, train_tasks = _epoch_metrics(train_acc)
             if valtest:
                 val_loss, _ = _epoch_metrics(val_acc)
@@ -1718,14 +1746,15 @@ def train_validate_test(
                 from hydragnn_tpu.resilience.ckpt_io import with_retries
                 from hydragnn_tpu.utils.checkpoint import save_checkpoint
 
-                consolidated = consolidate(state)
-                with_retries(
-                    lambda: save_checkpoint(consolidated, orbax_dir),
-                    retries=res_cfg.ckpt_retries,
-                    backoff=res_cfg.ckpt_backoff,
-                    what="periodic full-state checkpoint",
-                    telemetry=telemetry, chaos=chaos, on_fail="warn",
-                    cross_rank=(not explicit_mesh and world_size > 1))
+                with tr.timer("checkpoint.save"):
+                    consolidated = consolidate(state)
+                    with_retries(
+                        lambda: save_checkpoint(consolidated, orbax_dir),
+                        retries=res_cfg.ckpt_retries,
+                        backoff=res_cfg.ckpt_backoff,
+                        what="periodic full-state checkpoint",
+                        telemetry=telemetry, chaos=chaos, on_fail="warn",
+                        cross_rank=(not explicit_mesh and world_size > 1))
             if earlystopper is not None and earlystopper(val_loss):
                 print_distributed(verbosity, f"Early stopping at epoch {epoch}")
                 break
@@ -1790,6 +1819,9 @@ def train_validate_test(
         close_manager(os.path.join(
             _resume.resume_dir(logs_dir, log_name), _resume.STATE_DIRNAME))
         profiler.disable()
+        if in_tail:
+            tr.stop("epoch.tail")
+        tr.unregister("spans")
         timer = tr.get("timer")
         telemetry.finalize(
             history, timers=timer.summary() if timer is not None else None)

@@ -46,6 +46,7 @@ class Profiler:
         if "HYDRAGNN_PROFILE_DIR" in os.environ:
             self.trace_dir = env_str("HYDRAGNN_PROFILE_DIR", self.trace_dir)
         self._step = 0
+        self._stop_at = 0
         self._tracing = False
         self._done = False
 
@@ -57,25 +58,29 @@ class Profiler:
                 os.path.dirname(os.path.dirname(self.trace_dir)) or "./logs/")
         return self
 
-    def step(self) -> None:
-        """Advance the schedule; start/stop the device trace at boundaries."""
+    def step(self, n: int = 1) -> None:
+        """Advance the schedule by the ``n`` optimizer steps of the
+        dispatch just made (scan-K makes K a call), so ``wait``,
+        ``warmup`` and ``active`` count steps whatever K is.  The trace
+        opens once ``wait + warmup`` steps are dispatched and closes once
+        ``active`` more are: at least one whole dispatch."""
         if not self.enabled or self._done:
             return
-        start_at = self.wait + self.warmup
-        stop_at = start_at + self.active
-        if self._step == start_at and not self._tracing:
+        self._step += max(1, int(n))
+        if self._tracing:
+            if self._step >= self._stop_at:
+                import jax.profiler
+
+                jax.profiler.stop_trace()
+                self._tracing = False
+                self._done = True
+        elif self._step >= self.wait + self.warmup:
             import jax.profiler
 
             os.makedirs(self.trace_dir, exist_ok=True)
             jax.profiler.start_trace(self.trace_dir)
             self._tracing = True
-        self._step += 1
-        if self._step >= stop_at and self._tracing:
-            import jax.profiler
-
-            jax.profiler.stop_trace()
-            self._tracing = False
-            self._done = True
+            self._stop_at = self._step + self.active
 
     def disable(self):
         if self._tracing:

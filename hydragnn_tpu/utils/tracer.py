@@ -11,8 +11,9 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import threading
 import time
-from typing import Dict, Optional
+from typing import Dict, List, Optional, Tuple
 
 _tracers: Dict[str, "Tracer"] = {}
 _enabled = True
@@ -29,51 +30,79 @@ class Tracer:
         ...
 
 
+class OpenRegions:
+    """What a tracer holds per open region, kept as one stack per
+    (thread, name): regions nest, the same name may be open twice, and a
+    loader's prefetch thread cannot close the trainer thread's region."""
+
+    def __init__(self):
+        self._open: Dict[Tuple[int, str], List[object]] = {}
+
+    def push(self, name: str, item) -> None:
+        self._open.setdefault(
+            (threading.get_ident(), name), []).append(item)
+
+    def pop(self, name: str):
+        """The innermost open ``name`` of this thread, or None."""
+        stack = self._open.get((threading.get_ident(), name))
+        return stack.pop() if stack else None
+
+    def clear(self) -> None:
+        self._open.clear()
+
+
 class TimerTracer(Tracer):
     """Named cumulative wall-clock regions (GPTL-style)."""
 
     def __init__(self):
         self.totals: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
-        self._open: Dict[str, float] = {}
+        self._open = OpenRegions()
+        self._lock = threading.Lock()
 
     def start(self, name: str):
-        self._open[name] = time.perf_counter()
+        self._open.push(name, time.perf_counter())
 
     def stop(self, name: str):
-        t0 = self._open.pop(name, None)
+        t0 = self._open.pop(name)
         if t0 is None:
             return
-        self.totals[name] = self.totals.get(name, 0.0) + time.perf_counter() - t0
-        self.counts[name] = self.counts.get(name, 0) + 1
+        dt = time.perf_counter() - t0
+        with self._lock:
+            self.totals[name] = self.totals.get(name, 0.0) + dt
+            self.counts[name] = self.counts.get(name, 0) + 1
 
     def reset(self):
-        self.totals.clear()
-        self.counts.clear()
+        with self._lock:
+            self.totals.clear()
+            self.counts.clear()
         self._open.clear()
 
     def summary(self) -> Dict[str, Dict[str, float]]:
-        return {
-            k: {"total_s": v, "count": self.counts.get(k, 0)}
-            for k, v in sorted(self.totals.items())
-        }
+        with self._lock:
+            return {
+                k: {"total_s": v, "count": self.counts.get(k, 0)}
+                for k, v in sorted(self.totals.items())
+            }
 
 
 class JaxProfilerTracer(Tracer):
-    """Region names become jax.profiler trace annotations."""
+    """Region names become jax.profiler trace annotations: the regions
+    land in the ``/host:CPU`` plane of a profiler trace, on the clock the
+    device planes use."""
 
     def __init__(self):
-        self._open: Dict[str, object] = {}
+        self._open = OpenRegions()
 
     def start(self, name: str):
         import jax.profiler
 
         ann = jax.profiler.TraceAnnotation(name)
         ann.__enter__()
-        self._open[name] = ann
+        self._open.push(name, ann)
 
     def stop(self, name: str):
-        ann = self._open.pop(name, None)
+        ann = self._open.pop(name)
         if ann is not None:
             ann.__exit__(None, None, None)
 
@@ -84,6 +113,15 @@ def initialize(timer: bool = True, jax_annotations: bool = False) -> None:
         _tracers["timer"] = TimerTracer()
     if jax_annotations:
         _tracers["jax"] = JaxProfilerTracer()
+
+
+def register(name: str, tracer: Tracer) -> None:
+    """Plug ``tracer`` in under ``name`` (replacing that name's)."""
+    _tracers[name] = tracer
+
+
+def unregister(name: str) -> None:
+    _tracers.pop(name, None)
 
 
 def has(name: str) -> bool:
@@ -106,13 +144,14 @@ def disable():
 
 def start(name: str):
     if _enabled:
-        for t in _tracers.values():
+        # a copy: another thread may register or unregister meanwhile
+        for t in tuple(_tracers.values()):
             t.start(name)
 
 
 def stop(name: str):
     if _enabled:
-        for t in _tracers.values():
+        for t in tuple(_tracers.values()):
             t.stop(name)
 
 

@@ -186,3 +186,159 @@ def test_timers():
         pass
     t = get_timer("region_a")
     assert t.count == 1 and t.total >= 0.0
+
+
+# -- utils/tracer: registration, nesting, threads (both built-in tracers) -----
+
+
+class _Recording:
+    """A tracer that writes down what it is told."""
+
+    def __init__(self):
+        self.events = []
+
+    def start(self, name):
+        self.events.append(("start", name))
+
+    def stop(self, name):
+        self.events.append(("stop", name))
+
+    def reset(self):
+        self.events.clear()
+
+
+def test_tracer_register_unregister():
+    from hydragnn_tpu.utils import tracer as tr
+
+    rec = _Recording()
+    tr.register("rec", rec)
+    try:
+        assert tr.has("rec") and tr.get("rec") is rec
+        with tr.timer("train"):
+            pass
+        tr.unregister("rec")
+        tr.start("validate")
+        tr.stop("validate")
+    finally:
+        tr.unregister("rec")        # a second time: no error
+    assert not tr.has("rec")
+    assert rec.events == [("start", "train"), ("stop", "train")]
+
+
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: enter and exit must
+    pair up per thread, innermost first."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        import threading
+
+        _FakeAnnotation.log.append(
+            ("enter", self.name, threading.get_ident()))
+
+    def __exit__(self, *exc):
+        import threading
+
+        _FakeAnnotation.log.append(
+            ("exit", self.name, threading.get_ident()))
+
+
+@pytest.fixture
+def tracer_kind(request, monkeypatch):
+    """(tracer, closed): ``closed()`` lists the regions the tracer has
+    closed, as (name, thread) in closing order."""
+    import threading
+
+    from hydragnn_tpu.utils import tracer as tr
+
+    if request.param == "timer":
+        t = tr.TimerTracer()
+        order = []
+        stop = t.stop
+
+        def stop_and_note(name):
+            before = t.counts.get(name, 0)
+            stop(name)
+            if t.counts.get(name, 0) > before:
+                order.append((name, threading.get_ident()))
+
+        t.stop = stop_and_note
+        return t, lambda: list(order)
+    _FakeAnnotation.log = []
+    monkeypatch.setattr("jax.profiler.TraceAnnotation", _FakeAnnotation)
+    t = tr.JaxProfilerTracer()
+    return t, lambda: [(n, th) for what, n, th in _FakeAnnotation.log
+                       if what == "exit"]
+
+
+@pytest.mark.parametrize("tracer_kind", ["timer", "jax"], indirect=True)
+def test_tracer_regions_nest(tracer_kind):
+    import threading
+
+    t, closed = tracer_kind
+    me = threading.get_ident()
+    t.start("outer")
+    t.start("inner")
+    t.start("inner")            # the same name, open twice
+    t.stop("inner")
+    t.stop("inner")
+    t.stop("inner")             # a third stop closes nothing
+    t.stop("outer")
+    assert closed() == [("inner", me), ("inner", me), ("outer", me)]
+
+
+@pytest.mark.parametrize("tracer_kind", ["timer", "jax"], indirect=True)
+def test_tracer_regions_are_per_thread(tracer_kind):
+    """A prefetch thread that opens and closes ``data.collate`` must not
+    close the trainer thread's region of the same name."""
+    import threading
+
+    t, closed = tracer_kind
+    me = threading.get_ident()
+    t.start("data.collate")
+    other = {}
+
+    def worker():
+        other["id"] = threading.get_ident()
+        t.stop("data.collate")          # not this thread's: nothing
+        t.start("data.collate")
+        t.stop("data.collate")
+
+    th = threading.Thread(target=worker)
+    th.start()
+    th.join()
+    assert closed() == [("data.collate", other["id"])]
+    t.stop("data.collate")
+    assert closed() == [("data.collate", other["id"]), ("data.collate", me)]
+
+
+def test_profiler_schedule_counts_optimizer_steps(tmp_path, monkeypatch):
+    """Under scan-K a dispatch is K steps: wait/warmup/active still mean
+    steps, and the trace holds at least one whole dispatch."""
+    from hydragnn_tpu.utils import profile as prof
+
+    calls = []
+    monkeypatch.setattr(
+        "jax.profiler.start_trace", lambda d: calls.append("start"))
+    monkeypatch.setattr(
+        "jax.profiler.stop_trace", lambda: calls.append("stop"))
+    cfg = {"enable": 1, "wait": 40, "warmup": 24, "active": 3,
+           "trace_dir": str(tmp_path / "tr")}
+    p = prof.Profiler(cfg)
+    seen = []
+    for _ in range(5):                  # five dispatches of K=32
+        p.step(32)
+        seen.append(list(calls))
+    # 64 steps are dispatched after the second call; the third dispatch
+    # is traced whole
+    assert seen == [[], ["start"], ["start", "stop"], ["start", "stop"],
+                    ["start", "stop"]]
+    calls.clear()
+    p = prof.Profiler(cfg)
+    for _ in range(70):                 # K=1: 64 wait+warmup, 3 active
+        p.step()
+    assert calls == ["start", "stop"] and p._step == 67

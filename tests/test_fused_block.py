@@ -155,6 +155,56 @@ def test_model_fused_matches_scatter(model_type, monkeypatch):
                                    rtol=1e-4, atol=1e-5)
 
 
+def _eqns_with_stacks(jaxpr, prefix=""):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it, each with
+    its whole name stack (an inner equation's own is relative to the
+    equation that holds it)."""
+    for eqn in jaxpr.eqns:
+        own = str(eqn.source_info.name_stack)
+        stack = "/".join(p for p in (prefix, own) if p)
+        yield eqn, stack
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_with_stacks(sub, stack)
+
+
+@pytest.mark.parametrize("model_type", ALL_ARCHS)
+def test_train_step_kernels_and_phases_are_named(model_type, monkeypatch):
+    """What a device trace will call things: every ``pallas_call`` of a
+    fused train step carries a declared kernel name, and the step's ops
+    sit under the declared phases."""
+    from hydragnn_tpu.analysis.registry import KERNEL_NAMES, SCOPE_NAMES
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.trainer import (
+        create_train_state, make_train_step)
+
+    monkeypatch.setenv("HYDRAGNN_AGGR_BACKEND", "fused")
+    cfg = _model_cfg(model_type)
+    model = create_model(cfg)
+    batch = _model_batch(model_type, _ARCH_SEED.get(model_type, 9))
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
+    state = jax.eval_shape(
+        lambda b: create_train_state(model, b, opt), batch)
+    step = make_train_step(model, cfg, opt, telemetry_metrics=True,
+                           nonfinite_guard=True)
+    eqns = list(_eqns_with_stacks(jax.make_jaxpr(step)(state, batch).jaxpr))
+    kernels = [(e.params["name"], stack) for e, stack in eqns
+               if e.primitive.name == "pallas_call"]
+    assert kernels, "the fused backend traced no kernel"
+    for name, stack in kernels:
+        assert name in KERNEL_NAMES, (name, stack)
+        assert "step.loss" in stack, (name, stack)
+    # both passes are there, and the backward's kernels say so
+    assert any("transpose(" in s for _n, s in kernels)
+    assert any("transpose(" not in s for _n, s in kernels)
+    phases = {m for _e, stack in eqns for m in stack.split("/")
+              if m in SCOPE_NAMES}
+    assert phases == {"step.loss", "step.optimizer", "step.metrics",
+                      "step.guard"}
+
+
 # ---------------------------------------------------------------------------
 # 2. poly multi-moment kernels (ops/poly_mp.py)
 # ---------------------------------------------------------------------------

@@ -1,8 +1,8 @@
 """Flight recorder (docs/TELEMETRY.md "Tracing"): trace-identity
 adoption, the bounded lock-guarded span ring, end-to-end serve spans
 (request -> linked flush -> queue-wait/pad/predict children), trace ids
-on shed/timeout answers and across failover, the train-phase wrappers,
-the comm-vs-compute A/B probe, the SLO burn-rate monitor, and the
+on shed/timeout answers and across failover, the trainer's host regions
+as spans, the comm-vs-compute A/B probe, the SLO burn-rate monitor, and the
 PR-15-style default-off purity claims."""
 
 import json
@@ -192,7 +192,7 @@ def test_span_context_manager_and_chrome_export():
     rec = SpanRecorder(ring=16)
     with rec.span("serve.flush", trace_id="tr1", bucket=4):
         time.sleep(0.002)
-    rec.record_interval("train.step", 1.0, 1.5, trace_id="run",
+    rec.record_interval("train.dispatch", 1.0, 1.5, trace_id="run",
                         parent_id="abcd")
     doc = chrome_trace(rec.snapshot() + [{"event": "step"}])  # non-spans skipped
     evs = doc["traceEvents"]
@@ -201,7 +201,7 @@ def test_span_context_manager_and_chrome_export():
     assert flush["ph"] == "X" and flush["pid"] == "serve"
     assert flush["dur"] >= 2000  # microseconds
     assert flush["args"]["bucket"] == 4 and flush["args"]["trace_id"] == "tr1"
-    step = next(e for e in evs if e["name"] == "train.step")
+    step = next(e for e in evs if e["name"] == "train.dispatch")
     assert step["pid"] == "train" and step["dur"] == pytest.approx(5e5)
     assert step["args"]["parent_id"] == "abcd"
 
@@ -379,28 +379,202 @@ def test_trace_id_survives_midflight_failover(engine):
 # ---------------------------------------------------------------------------
 
 
-def test_traced_loader_and_step_record_phases():
+def test_regions_reach_the_span_recorder_without_a_sync(monkeypatch):
+    """With tracing on the trainer's host regions become spans through
+    the RegionSpans adapter, and the epoch loop stays the same program:
+    the same dispatches, no ``block_until_ready`` per dispatch."""
+    import jax
     import jax.numpy as jnp
 
-    from hydragnn_tpu.train.trainer import _traced_loader, _traced_step
+    from hydragnn_tpu.telemetry.trace import RegionSpans
+    from hydragnn_tpu.train.trainer import _run_epoch
+    from hydragnn_tpu.utils import tracer as tr
 
-    rec = SpanRecorder(ring=32)
-    batches = list(range(3))
-    seen = list(_traced_loader(iter(batches), rec))
-    assert seen == batches  # pass-through, order preserved
+    syncs, dispatched = [], []
+    real_block = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: (syncs.append(1), real_block(x))[1])
 
     def step_fn(state, g):
-        return state + g, {"loss": jnp.float32(g)}
+        dispatched.append(g)
+        return state + g, {"loss": jnp.float32(g),
+                           "num_graphs": jnp.float32(1.0),
+                           "task_0": jnp.float32(g)}
 
-    stepped = _traced_step(step_fn, rec)
-    state = 0
-    for g in seen:
-        state, metrics = stepped(state, g)
-    assert state == 3 and float(metrics["loss"]) == 2.0
+    def epoch():
+        dispatched.clear()
+        state, acc = _run_epoch(step_fn, 0, [1, 2, 3, 4], True)
+        return state, float(acc[0]), list(dispatched)
+
+    untraced = epoch()
+    rec = SpanRecorder(ring=64)
+    tr.register("spans", RegionSpans(rec))
+    try:
+        traced = epoch()
+    finally:
+        tr.unregister("spans")
+    assert traced == untraced == (10, 10.0, [1, 2, 3, 4])
+    assert not syncs
     pct = rec.percentiles()
-    assert pct["train.data_wait"]["count"] == 3
-    assert pct["train.h2d"]["count"] == 3
-    assert pct["train.step"]["count"] == 3
+    assert pct["train.dispatch"]["count"] == 4
+    # four batches and the next() that finds the loader exhausted
+    assert pct["train.data_wait"]["count"] == 5
+    assert set(pct) == {"train.dispatch", "train.data_wait"}
+    assert len({s["trace_id"] for s in rec.snapshot()}) == 1
+
+
+class _NestingTracer:
+    """Writes every region down and holds it to the contract: a stop
+    closes the innermost open region of its thread."""
+
+    def __init__(self):
+        self.open = {}              # thread -> stack of names
+        self.closed = []            # (name, thread, depth)
+        self.faults = []
+
+    def start(self, name):
+        self.open.setdefault(threading.get_ident(), []).append(name)
+
+    def stop(self, name):
+        stack = self.open.get(threading.get_ident(), [])
+        if not stack or stack[-1] != name:
+            self.faults.append((name, list(stack)))
+            return
+        stack.pop()
+        self.closed.append((name, threading.get_ident(), len(stack)))
+
+    def reset(self):
+        pass
+
+
+def test_training_regions_close_nest_and_are_declared(tmp_path, monkeypatch):
+    """Two epochs through the real ``train_validate_test`` (DP mesh path,
+    resident staging, a checkpoint, tracing on): every region the trainer
+    and the data path open closes, nests, and is declared; the flight
+    recorder's manifest block has them."""
+    from test_resilience import _Loaders, _run
+
+    from hydragnn_tpu.analysis.registry import SPAN_NAMES
+    from hydragnn_tpu.utils import tracer as tr
+
+    monkeypatch.setenv("HYDRAGNN_RESIDENT_DATASET", "1")
+    monkeypatch.setenv("HYDRAGNN_STEPS_PER_DISPATCH", "1")
+    rec = _NestingTracer()
+    tr.register("nesting", rec)
+    tel = _traced_logger(tmp_path / "tel", sinks=("jsonl",))
+    try:
+        _, hist = _run(_Loaders(n_train=128), tmp_path, "regions",
+                       num_epoch=2, use_mesh_dp=True, telemetry=tel,
+                       training_extra={"Checkpoint": True})
+    finally:
+        tr.unregister("nesting")
+    assert len(hist["train"]) == 2
+    assert not rec.faults, rec.faults
+    assert not any(rec.open.values()), rec.open
+    assert not tr.has("spans")          # the adapter left with the run
+    names = {n for n, _t, _d in rec.closed}
+    assert names <= set(SPAN_NAMES), names - set(SPAN_NAMES)
+    assert names >= {
+        "train", "validate", "test", "metrics_fetch", "train.data_wait",
+        "train.dispatch", "eval.data_wait", "eval.dispatch", "epoch.fetch",
+        "telemetry.flush", "epoch.tail", "checkpoint.save", "data.collate",
+        "data.stack", "data.h2d", "setup.mfu_cost"}, names
+    depth = {n: d for n, _t, d in rec.closed}
+    assert depth["train"] == depth["metrics_fetch"] == depth[
+        "epoch.tail"] == 0
+    assert depth["train.dispatch"] == depth["epoch.fetch"] == 1
+    assert depth["checkpoint.save"] == 1        # inside epoch.tail
+    # epoch 0 stages the resident corpus; epoch 1 collates nothing
+    n_tail = sum(1 for n, _t, _d in rec.closed if n == "epoch.tail")
+    assert n_tail == 2
+    recs = [json.loads(line) for line in open(tel.jsonl_path)]
+    manifest = next(r for r in recs if r["event"] == "manifest")
+    by_name = manifest["spans"]["by_name"]
+    for name in ("train.data_wait", "train.dispatch", "epoch.fetch",
+                 "epoch.tail", "data.collate", "data.h2d"):
+        assert by_name[name]["count"] >= 1, name
+    assert "train.step" not in by_name and "train.h2d" not in by_name
+
+
+_HLO = """HloModule jit_step, is_scheduled=true
+
+FileNames
+1 "/x/hydragnn_tpu/models/schnet.py"
+2 "/venv/flax/linen/linear.py"
+
+FunctionNames
+1 "SCFConv.__call__"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=111 end_line=111 column=1 end_column=2}
+2 {file_name_id=2 function_name_id=1 line=287 end_line=287 column=1 end_column=2}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=1}
+
+%fused_computation.1 (p: f32[8]) -> f32[8] {
+  %p = f32[8]{0} parameter(0)
+  ROOT %mul.1 = f32[8]{0} multiply(%p, %p), metadata={op_name="jit(step)/step.loss/jvp(M)/lin/mul" stack_frame_id=2}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused_computation.1
+  %copy-start.1 = (f32[8]{0}, f32[8]{0}, u32[]) copy-start(%fusion.1)
+  %copy-done.1 = f32[8]{0} copy-done(%copy-start.1)
+  ROOT %add.1 = f32[8]{0} add(%copy-done.1, %a), metadata={op_name="jit(step)/step.optimizer/add" stack_frame_id=1}
+}
+"""
+
+
+def test_instruction_scopes_from_hlo_text():
+    from hydragnn_tpu.telemetry.hlo_scopes import instruction_scopes
+
+    got = instruction_scopes(_HLO)
+    fwd = "jit(step)/step.loss/jvp(M)/lin/mul"
+    # a fusion without op_name takes its root's; the line is the
+    # package's own frame up the chain (frame 1 is its own parent: no
+    # loop); an instruction inside the fusion is no op of its own
+    assert got["fusion.1"] == ["f32[8]", fwd, 0, "models/schnet.py:111"]
+    assert "mul.1" not in got and "p" not in got
+    # the compiler's copies have no name: the nearest operand's, marked
+    assert got["copy-start.1"] == ["f32[8]", fwd, 1, "models/schnet.py:111"]
+    assert got["copy-done.1"] == ["f32[8]", fwd, 1, "models/schnet.py:111"]
+    assert got["add.1"] == ["f32[8]", "jit(step)/step.optimizer/add", 0,
+                            "models/schnet.py:111"]
+    assert got["a"] == ["f32[8]", "", 0, ""]
+
+
+def test_step_programs_writes_scopes_once(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry.hlo_scopes import StepPrograms
+    from hydragnn_tpu.train.trainer import phase
+
+    @jax.jit
+    def step(x):
+        with phase("step.loss"):
+            return jnp.sin(x) * 2.0
+
+    notes = StepPrograms()
+    watched = notes.watch(step)
+    a, b = jnp.ones(4), jnp.ones(9)
+    for x in (a, a, b):             # two shapes: two executables
+        assert jnp.allclose(watched(x), step(x))
+    path = tmp_path / "telemetry" / "hlo_scopes.json"
+    notes.write(str(path))
+    programs = json.loads(path.read_text())["programs"]
+    assert [p["name"] for p in programs] == ["jit_step", "jit_step"]
+    for p in programs:
+        assert any("step.loss" in scope
+                   for _shape, scope, _inh, _src in p["instructions"].values())
+    path.unlink()
+    watched(jnp.ones(5))            # after the write: no more notes
+    notes.write(str(path))
+    assert not path.exists()
 
 
 # ---------------------------------------------------------------------------
@@ -431,28 +605,63 @@ def mesh_harness():
     return cfg, model, opt, mesh, state, batches
 
 
-def test_comm_probe_default_off_hlo_pure(mesh_harness):
-    """PR-15-style purity: default-off lowers the SAME program, and the
-    probe annotation changes compiled-HLO METADATA only — the lowered
-    StableHLO is byte-identical, so the timed program IS the production
-    program."""
+def test_scopes_and_kernel_names_change_metadata_only(mesh_harness,
+                                                      monkeypatch):
+    """Phase scopes, ``comm.*`` regions and kernel names are names: the
+    lowered StableHLO (printed without debug locations) is the one a build
+    with the helpers stubbed out lowers, so the traced program IS the
+    production program.  The names reach the compiled program's op
+    metadata, where a device trace reads them."""
+    import contextlib
+
+    from jax.experimental import pallas as pl
+
+    from hydragnn_tpu.parallel import mesh as mesh_mod
     from hydragnn_tpu.parallel.mesh import make_dp_train_step
+    from hydragnn_tpu.train import trainer as trainer_mod
+
+    import jax
+
+    from test_fused_block import _model_batch, _model_cfg
+
+    from hydragnn_tpu.train.trainer import create_train_state
 
     cfg, model, opt, mesh, state, batches = mesh_harness
-    base_l = make_dp_train_step(model, cfg, opt, mesh).lower(state, batches)
-    off_l = make_dp_train_step(model, cfg, opt, mesh, comm_probe=False
-                               ).lower(state, batches)
-    on_l = make_dp_train_step(model, cfg, opt, mesh, comm_probe=True
-                              ).lower(state, batches)
-    base_txt = base_l.as_text()
-    assert off_l.as_text() == base_txt
-    assert on_l.as_text() == base_txt  # annotation is metadata-only
-    assert "comm.dp_psum" not in base_txt
-    # the compiled program carries the region names as op metadata — the
-    # xprof/Perfetto attribution handle
-    compiled_on = on_l.compile().as_text()
-    assert "comm.dp_psum" in compiled_on
-    assert "comm.dp_psum" not in base_l.compile().as_text()
+    # a one-device step on the fused backend: it has the kernels
+    monkeypatch.setenv("HYDRAGNN_AGGR_BACKEND", "fused")
+    f_cfg = _model_cfg("SchNet")
+    f_model = create_model(f_cfg)
+    f_batch = _model_batch("SchNet", 5)
+    f_state = jax.eval_shape(
+        lambda b: create_train_state(f_model, b, opt), f_batch)
+
+    def lowered():
+        return make_dp_train_step(
+            model, cfg, opt, mesh, telemetry_metrics=True,
+            nonfinite_guard=True).lower(state, batches)
+
+    def lowered_fused():
+        return jax.jit(trainer_mod.make_train_step(
+            f_model, f_cfg, opt)).lower(f_state, f_batch).as_text()
+
+    named, named_fused = lowered(), lowered_fused()
+    no_scope = lambda name: contextlib.nullcontext()  # noqa: E731
+    monkeypatch.setattr(trainer_mod, "phase", no_scope)
+    monkeypatch.setattr(mesh_mod, "phase", no_scope)
+    monkeypatch.setattr(mesh_mod, "comm_region", no_scope)
+    real_call = pl.pallas_call
+    monkeypatch.setattr(
+        pl, "pallas_call",
+        lambda *a, name=None, **kw: real_call(*a, **kw))
+    bare = lowered()
+    assert named.as_text() == bare.as_text()
+    assert named_fused == lowered_fused()
+    assert "step.loss" not in named.as_text()
+    named_hlo, bare_hlo = named.compile().as_text(), bare.compile().as_text()
+    for scope in ("step.loss", "step.optimizer", "step.metrics",
+                  "step.guard", "comm.dp_psum"):
+        assert scope in named_hlo, scope
+        assert scope not in bare_hlo, scope
 
 
 def test_dp_comms_probe_reports_split_and_preserves_state(mesh_harness):

@@ -6,7 +6,7 @@ Usage:
     python tools/graftlint.py --json              # machine-readable findings
     python tools/graftlint.py --diff [REF]        # only findings on lines changed vs REF (default HEAD)
     python tools/graftlint.py --selftest          # run the rule fixtures
-    python tools/graftlint.py --emit-docs         # regenerate docs/KNOBS.md from the knob registry
+    python tools/graftlint.py --emit-docs         # regenerate docs/KNOBS.md and docs/TELEMETRY.md's trace name tables from the registry
     python tools/graftlint.py --write-baseline    # grandfather current findings (justify each entry!)
     python tools/graftlint.py --list-rules        # rule catalog one-liners
 
@@ -90,6 +90,20 @@ def main(argv=None) -> int:
             fh.write(a.emit_knob_docs())
         print(f"wrote {os.path.relpath(out, ROOT)} "
               f"({len(a.KNOBS)} knobs)")
+        # the region / scope / kernel name tables of "Tracing"
+        out = os.path.join(ROOT, "docs", "TELEMETRY.md")
+        with open(out, encoding="utf-8") as fh:
+            doc = fh.read()
+        i, j = doc.find(a.TRACE_DOC_BEGIN), doc.find(a.TRACE_DOC_END)
+        if i < 0 or j < 0:
+            print(f"graftlint: {os.path.relpath(out, ROOT)} has no "
+                  f"generated-names block to rewrite", file=sys.stderr)
+            return 2
+        with open(out, "w", encoding="utf-8") as fh:
+            fh.write(doc[:i] + a.emit_trace_docs().rstrip("\n")
+                     + doc[j + len(a.TRACE_DOC_END):])
+        print(f"wrote the trace name tables of "
+              f"{os.path.relpath(out, ROOT)}")
         return 0
 
     rules = a.all_rules()
