@@ -722,7 +722,7 @@ _KERNEL_LIST = [
     _kn("gather_mul_seg_fwd", "hydragnn_tpu/ops/fused_mp.py",
         "gather x[send] (* w) -> sorted segment sum"),
     _kn("gather_mul_seg_bwd", "hydragnn_tpu/ops/fused_mp.py",
-        "the same kernel on the sender-sorted order: dx"),
+        "receiver-order backward pass: dw per edge + windowed dx partials"),
     _kn("seg_sum_dense_fwd", "hydragnn_tpu/ops/fused_mp.py",
         "sorted segment sum on the dense schedule"),
     _kn("dn_tri_fwd", "hydragnn_tpu/ops/dn_tri.py",

@@ -312,12 +312,14 @@ def collate(
 
     extras: Dict[str, np.ndarray] = {}
     # HYDRAGNN_AGGR_BACKEND=fused: attach the sender-sorted edge permutation
-    # the fused message-passing kernel's backward needs
-    # (ops/fused_mp.py) — only when the kernel's block-locality invariant
-    # holds (every graph fits one node block).  All other invariants
-    # (nondecreasing receivers, contiguous graphs, intra-graph edges) hold
-    # by construction of this function; the models fall back to the XLA
-    # path whenever the permutation is absent.
+    # — the fused kernels' gate (its presence is collate's word that their
+    # invariants hold) and the sender order the backward passes of
+    # ops/fused_block.py, gat_mp.py and poly_mp.py run on (ops/fused_mp.py
+    # reads only the receiver order) — only when the block-locality
+    # invariant holds (every graph fits one node block).  All other
+    # invariants (nondecreasing receivers, contiguous graphs, intra-graph
+    # edges) hold by construction of this function; the models fall back to
+    # the XLA path whenever the permutation is absent.
     from hydragnn_tpu.ops.aggregate import aggr_backend
 
     if aggr_backend() == "fused":

@@ -62,15 +62,18 @@ def gather_mul_segment(x, w, g):
     Pallas pass (ops/fused_mp.py) that never materializes the gathered
     messages in HBM; otherwise the standard gather + masked segment_sum.
     """
-    perm = g.extras.get("edge_perm_sender") if g.extras else None
-    _count("gather_mul", perm is not None)
-    if perm is not None:
+    # the permutation's PRESENCE is the gate (collate's word that the
+    # kernel's invariants hold); the kernel itself reads the edge list as
+    # shipped, forward and backward, and never the permutation
+    fused = bool(g.extras) and "edge_perm_sender" in g.extras
+    _count("gather_mul", fused)
+    if fused:
         from hydragnn_tpu.ops.fused_mp import gather_mul_segment_sum
 
         w = w * _bcast(g.edge_mask, w)
         # edge_valid: the kernel's schedule skips masked-edge blocks
         # outright (~half the slots at flagship padding ratios)
-        return gather_mul_segment_sum(x, w, g.senders, g.receivers, perm,
+        return gather_mul_segment_sum(x, w, g.senders, g.receivers,
                                       edge_valid=g.edge_mask)
     return segment_sum(
         x[g.senders] * w, g.receivers, x.shape[0], g.edge_mask)
@@ -80,13 +83,12 @@ def gather_segment(x, g):
     """Plain neighbor sum ``out[n] = sum_{e: recv[e]=n} x[send[e]]`` over
     real edges — fused-kernel path when available (same dispatch rules as
     :func:`gather_mul_segment`), else gather + masked segment_sum."""
-    perm = g.extras.get("edge_perm_sender") if g.extras else None
-    _count("gather_sum", perm is not None)
-    if perm is not None:
+    fused = bool(g.extras) and "edge_perm_sender" in g.extras
+    _count("gather_sum", fused)
+    if fused:
         from hydragnn_tpu.ops.fused_mp import gather_segment_sum
 
-        return gather_segment_sum(
-            x, g.senders, g.receivers, perm, g.edge_mask)
+        return gather_segment_sum(x, g.senders, g.receivers, g.edge_mask)
     return segment_sum(
         x[g.senders], g.receivers, x.shape[0], g.edge_mask)
 

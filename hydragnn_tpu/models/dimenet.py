@@ -541,13 +541,14 @@ class InteractionPPBlock(nn.Module):
             sbf_emb = nn.Dense(self.int_emb_size, use_bias=False, name="lin_sbf2")(sbf_emb)
             # the triplet contraction IS message passing in EDGE space:
             # out[e'] = sum_{t: ji(t)=e'} x_kj[kj(t)] * sbf_emb[t] — one
-            # fused W-window pass (fwd AND its dx backward via perm_kj)
-            # instead of gather + [T, D] materialization + sorted scatter
+            # fused W-window pass (fwd, and dx + dsbf_emb from one
+            # backward pass over the triplets in ji order) instead of
+            # gather + [T, D] materialization + sorted scatter
             from hydragnn_tpu.ops.fused_mp import gather_mul_segment_sum
 
             x_kj = gather_mul_segment_sum(
                 x_kj, sbf_emb * triplet_mask[:, None], idx_kj, idx_ji,
-                perm_kj, self.tri_window)
+                self.tri_window)
         else:
             sbf_emb = nn.Dense(self.basis_emb_size, use_bias=False, name="lin_sbf1")(sbf)
             sbf_emb = nn.Dense(self.int_emb_size, use_bias=False, name="lin_sbf2")(sbf_emb)

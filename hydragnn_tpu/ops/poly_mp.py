@@ -50,9 +50,9 @@ Backward (custom VJP, no kernel differentiation):
                        counts ride ONE segment_sum_dense pass)
   cnt carries no data gradient.
 Gather mode chains these through ``msgs = x[send] * mask`` and scatters at
-senders via the sender-sorted permutation (collate's ``edge_perm_sender``),
-exactly like fused_mp's backward; the sum-only case rides the fused
-gather->scatter kernel directly with no [E, F] intermediate.
+senders via the sender-sorted permutation (collate's ``edge_perm_sender``);
+the sum-only case rides fused_mp's receiver-order backward pass directly
+with no [E, F] intermediate and no permutation.
 """
 
 from __future__ import annotations
@@ -454,16 +454,13 @@ def _gps_fwd(x, senders, receivers, sender_perm, moments, mask=None):
 
 
 def _gps_bwd(moments, res, g):
-    from hydragnn_tpu.ops.fused_mp import _fused_impl
+    from hydragnn_tpu.ops.fused_mp import _bwd_call, _pack
 
     moments = _norm_moments(moments)
     x, senders, receivers, sender_perm, mask, mxmn = res
     n, f = x.shape
-    interpret = jax.default_backend() != "tpu"
     m = (jnp.ones((senders.shape[0],), jnp.float32) if mask is None
          else mask.astype(jnp.float32))
-    if sender_perm is None:
-        sender_perm = jnp.argsort(senders, stable=True)
 
     moms = dict(zip(moments, g))
     need_msgs = ("sq" in moments) or ("mxmn" in moments)
@@ -471,16 +468,15 @@ def _gps_bwd(moments, res, g):
         return jnp.zeros_like(x), None, None, None, None  # cnt-only
     if not need_msgs:
         # sum-only (cnt has no x-grad): dx[n] = sum_{e: send=n} m_e
-        # g_sum[recv_e] — the fused gather->scatter kernel on the
-        # sender-sorted ordering, no [E, F] intermediate (fused_mp's
-        # _gss_bwd structure)
-        g_sum = moms["sum"].astype(jnp.float32)
-        mp = m[sender_perm]
-        dx = _fused_impl(
-            g_sum, None, receivers[sender_perm], senders[sender_perm],
-            interpret, mask=mp, edge_valid=mp,
-            kernel_name="gather_mul_seg_bwd")
-        return dx.astype(x.dtype), None, None, None, None
+        # g_sum[recv_e] — fused_mp's w-less backward pass over the edges
+        # in receiver order, no [E, F] intermediate and no permutation
+        g_p, m_p, send_p, recv_p = _pack(
+            moms["sum"].astype(jnp.float32), None, senders, receivers,
+            m, m)
+        dx_p, _ = _bwd_call(False, 3, None, m_p, send_p, recv_p, g_p)
+        return dx_p[:n, :f].astype(x.dtype), None, None, None, None
+    if sender_perm is None:
+        sender_perm = jnp.argsort(senders, stable=True)
 
     # sq/mxmn need the messages: recompute the gather (receivers gather of
     # g is sorted and cheap; senders gather of x is the one re-read)

@@ -899,7 +899,7 @@ def _arrays(b, f=64, seed=1):
     x = jnp.asarray(rng.rand(n, f), jnp.float32)
     w = jnp.asarray(rng.rand(e, f), jnp.float32) * jnp.asarray(
         b.edge_mask)[:, None]
-    return x, w, _sender_perm(b)
+    return x, w
 
 
 def _gms_ref(b, x, w):
@@ -910,9 +910,9 @@ def _gms_ref(b, x, w):
 
 def test_fused_forward_exact():
     b = _batch()
-    x, w, perm = _arrays(b)
+    x, w = _arrays(b)
     out = gather_mul_segment_sum(
-        x, w, jnp.asarray(b.senders), jnp.asarray(b.receivers), perm)
+        x, w, jnp.asarray(b.senders), jnp.asarray(b.receivers))
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_gms_ref(b, x, w)),
                                rtol=1e-5, atol=1e-5)
@@ -920,12 +920,12 @@ def test_fused_forward_exact():
 
 def test_fused_gradients_exact():
     b = _batch(seed=2)
-    x, w, perm = _arrays(b, seed=3)
+    x, w = _arrays(b, seed=3)
     s, r = jnp.asarray(b.senders), jnp.asarray(b.receivers)
 
     gx1, gw1 = jax.grad(
         lambda x_, w_: jnp.sum(
-            gather_mul_segment_sum(x_, w_, s, r, perm) ** 2),
+            gather_mul_segment_sum(x_, w_, s, r) ** 2),
         argnums=(0, 1))(x, w)
     gx2, gw2 = jax.grad(
         lambda x_, w_: jnp.sum(_gms_ref(b, x_, w_) ** 2),
@@ -937,31 +937,40 @@ def test_fused_gradients_exact():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_extreme_degrees_exact():
-    """The dense schedule has no degree bound: dense all-to-all graphs
-    (degree 15 in a 16-node graph) are processed exactly, fwd and bwd."""
+def _dense_collated():
+    """Dense all-to-all graphs: degree 15 in a 16-node graph."""
     rng = np.random.RandomState(0)
     samples = []
     for _ in range(24):
-        n = 16
-        pos = rng.rand(n, 3).astype(np.float32)  # dense: everyone in range
-        x = rng.rand(n, 2).astype(np.float32)
-        ei = radius_graph(pos, 10.0, 15)
-        samples.append(GraphSample(x=x, pos=pos, edge_index=ei,
-                                   graph_y=np.ones(1, np.float32), node_y=x))
-    pad = PadSpec.for_batch(24, 16, 16 * 15)
-    b = collate(samples, pad, [HeadSpec("e", "graph", 1)])
-    x, w, perm = _arrays(b)
+        pos = rng.rand(16, 3).astype(np.float32)
+        x = rng.rand(16, 2).astype(np.float32)
+        samples.append(GraphSample(
+            x=x, pos=pos, edge_index=radius_graph(pos, 10.0, 15),
+            graph_y=np.ones(1, np.float32), node_y=x))
+    return collate(samples, PadSpec.for_batch(24, 16, 16 * 15),
+                   [HeadSpec("e", "graph", 1)])
+
+
+def test_extreme_degrees_exact():
+    """The dense schedule has no degree bound: dense all-to-all graphs
+    (degree 15 in a 16-node graph) are processed exactly, fwd and bwd."""
+    b = _dense_collated()
+    x, w = _arrays(b)
     s, r = jnp.asarray(b.senders), jnp.asarray(b.receivers)
-    out = gather_mul_segment_sum(x, w, s, r, perm)
+    out = gather_mul_segment_sum(x, w, s, r)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(_gms_ref(b, x, w)),
                                rtol=1e-5, atol=1e-5)
     gx1 = jax.grad(lambda x_: jnp.sum(
-        gather_mul_segment_sum(x_, w, s, r, perm) ** 2))(x)
+        gather_mul_segment_sum(x_, w, s, r) ** 2))(x)
     gx2 = jax.grad(lambda x_: jnp.sum(_gms_ref(b, x_, w) ** 2))(x)
     np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx2),
                                rtol=1e-5, atol=1e-5)
+
+
+def _gss_ref(x, s, r, mask):
+    return jax.ops.segment_sum(x[s] * mask[:, None], r,
+                               num_segments=x.shape[0])
 
 
 def test_gather_segment_sum_wless_exact():
@@ -969,22 +978,250 @@ def test_gather_segment_sum_wless_exact():
     from hydragnn_tpu.ops.fused_mp import gather_segment_sum
 
     b = _batch(seed=7)
-    x, _, perm = _arrays(b, seed=8)
+    x, _ = _arrays(b, seed=8)
     s, r = jnp.asarray(b.senders), jnp.asarray(b.receivers)
     mask = jnp.asarray(b.edge_mask)
 
-    out = gather_segment_sum(x, s, r, perm, mask)
-    want = jax.ops.segment_sum(
-        x[s] * mask[:, None], r, num_segments=x.shape[0])
+    out = gather_segment_sum(x, s, r, mask)
+    want = _gss_ref(x, s, r, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
     g1 = jax.grad(lambda x_: jnp.sum(
-        gather_segment_sum(x_, s, r, perm, mask) ** 2))(x)
-    g2 = jax.grad(lambda x_: jnp.sum(jax.ops.segment_sum(
-        x_[s] * mask[:, None], r, num_segments=x.shape[0]) ** 2))(x)
+        gather_segment_sum(x_, s, r, mask) ** 2))(x)
+    g2 = jax.grad(lambda x_: jnp.sum(_gss_ref(x_, s, r, mask) ** 2))(x)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
                                rtol=1e-5, atol=1e-5)
+
+
+# --- the receiver-order backward (one pass: dw and dx from the edge list as
+# shipped).  Synthetic edge lists, built straight to the kernel's contract,
+# pin down the schedule corners collate's molecules only graze. ---
+
+_NB, _EB = 128, 512      # the kernel's node block and edge block
+
+# edges per node block, then the length of the masked tail
+_LAYOUTS = {
+    # (a) E is not a multiple of the edge block
+    "ragged": ([300, 700, 411], 0),
+    # (b) edge block 0 holds the edges of four node blocks, block 1 of two
+    "straddle": ([300, 100, 90, 900, 250], 0),
+    # (c) a node block without edges in the middle and at the end
+    "empty_blocks": ([400, 0, 600, 200, 0], 0),
+    # (d) a masked tail longer than one edge block (plus a ragged end)
+    "masked_tail": ([350, 500, 220], 2 * _EB + 77),
+}
+
+
+def _synthetic_edges(layout, window, seed, f=40):
+    """A receiver-sorted edge list whose senders honour the ``window``
+    invariant (an edge of node block i sends from blocks i-hw..i+hw), with
+    a masked tail parked on node N-1 the way collate parks padding."""
+    counts, tail = _LAYOUTS[layout]
+    rng = np.random.RandomState(seed)
+    nb, hw = len(counts), window // 2
+    n = nb * _NB - 37                   # N is not a whole number of blocks
+    recv, send = [], []
+    for i, c in enumerate(counts):
+        hi = min((i + 1) * _NB, n)
+        recv.append(np.sort(rng.randint(i * _NB, hi, c)))
+        send.append(rng.randint(max(0, i - hw) * _NB,
+                                min(n, (i + hw + 1) * _NB), c))
+    recv = np.concatenate(recv + [np.full(tail, n - 1)]).astype(np.int32)
+    send = np.concatenate(send + [np.full(tail, n - 1)]).astype(np.int32)
+    valid = np.concatenate(
+        [np.ones(sum(counts)), np.zeros(tail)]).astype(np.float32)
+    x = jnp.asarray(rng.randn(n, f), jnp.float32)
+    w = jnp.asarray(rng.randn(recv.size, f) * valid[:, None], jnp.float32)
+    return x, w, jnp.asarray(send), jnp.asarray(recv), jnp.asarray(valid)
+
+
+def _check_bwd_against_composed(x, w, s, r, valid, window):
+    """dx and dw of the fused op (``edge_valid`` passed) against the
+    composed gather + segment_sum; masked dw exactly zero, nothing NaN."""
+    n = x.shape[0]
+    ct = jnp.asarray(
+        np.random.RandomState(5).randn(n, x.shape[1]), jnp.float32)
+
+    def fused(x_, w_):
+        return jnp.sum(ct * gather_mul_segment_sum(
+            x_, w_, s, r, window, edge_valid=valid))
+
+    def composed(x_, w_):
+        return jnp.sum(ct * jax.ops.segment_sum(
+            x_[s] * w_, r, num_segments=n))
+
+    gx1, gw1 = jax.grad(fused, argnums=(0, 1))(x, w)
+    gx2, gw2 = jax.grad(composed, argnums=(0, 1))(x, w)
+    gw1, m = np.asarray(gw1), np.asarray(valid) != 0
+    # poison check: unvisited dw blocks are uninitialised memory (NaN in
+    # interpret mode) — the rule must select them away, not multiply
+    assert np.isfinite(gw1).all() and np.isfinite(np.asarray(gx1)).all()
+    assert (gw1[~m] == 0.0).all()
+    np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx2),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(gw1[m], np.asarray(gw2)[m],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+@pytest.mark.parametrize("window", [3, 5])
+def test_receiver_order_backward_exact(window, layout):
+    x, w, s, r, valid = _synthetic_edges(layout, window, seed=21)
+    np.testing.assert_allclose(
+        np.asarray(gather_mul_segment_sum(x, w, s, r, window,
+                                          edge_valid=valid)),
+        np.asarray(jax.ops.segment_sum(x[s] * w, r,
+                                       num_segments=x.shape[0])),
+        rtol=1e-5, atol=1e-5)
+    _check_bwd_against_composed(x, w, s, r, valid, window)
+
+
+def test_receiver_order_backward_wide_f_halves_edge_block():
+    """The one thing that adapts: at wide F the backward halves its edge
+    block (VMEM), on the forward's 512-padded operands — a second schedule
+    over the same edge list, same gradients."""
+    from hydragnn_tpu.ops.fused_mp import _EDGE_BLOCK, _bwd_edge_block
+
+    assert _bwd_edge_block(128, 3) == _EDGE_BLOCK == _bwd_edge_block(512, 5)
+    assert _bwd_edge_block(640, 5) == _EDGE_BLOCK // 2
+    x, w, s, r, valid = _synthetic_edges("straddle", 5, seed=28, f=520)
+    _check_bwd_against_composed(x, w, s, r, valid, 5)
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_receiver_order_backward_wless_exact(layout):
+    from hydragnn_tpu.ops.fused_mp import gather_segment_sum
+
+    x, _, s, r, valid = _synthetic_edges(layout, 3, seed=22)
+    n = x.shape[0]
+    ct = jnp.asarray(
+        np.random.RandomState(6).randn(n, x.shape[1]), jnp.float32)
+    g1 = jax.grad(lambda x_: jnp.sum(
+        ct * gather_segment_sum(x_, s, r, valid)))(x)
+    g2 = jax.grad(lambda x_: jnp.sum(ct * _gss_ref(x_, s, r, valid)))(x)
+    assert np.isfinite(np.asarray(g1)).all()
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("has_w", [True, False], ids=["w", "wless"])
+def test_receiver_order_backward_dense_graphs(has_w):
+    from hydragnn_tpu.ops.fused_mp import gather_segment_sum
+
+    b = _dense_collated()
+    x, w = _arrays(b, seed=23)
+    s, r = jnp.asarray(b.senders), jnp.asarray(b.receivers)
+    valid = jnp.asarray(b.edge_mask)
+    if has_w:
+        _check_bwd_against_composed(x, w, s, r, valid, 3)
+        return
+    g1 = jax.grad(lambda x_: jnp.sum(
+        gather_segment_sum(x_, s, r, valid) ** 2))(x)
+    g2 = jax.grad(lambda x_: jnp.sum(_gss_ref(x_, s, r, valid) ** 2))(x)
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("has_w", [True, False], ids=["w", "wless"])
+def test_receiver_order_backward_under_jit_scan(has_w):
+    """Two steps of a ``lax.scan`` under ``jax.jit`` (the scan-K trainer's
+    shape): each step's gradients equal the composed path's."""
+    from hydragnn_tpu.ops.fused_mp import gather_segment_sum
+
+    x, w, s, r, valid = _synthetic_edges("masked_tail", 3, seed=24)
+    n = x.shape[0]
+    scales = jnp.asarray([1.0, -0.5], jnp.float32)
+
+    def grads(op):
+        def body(carry, scale):
+            g = jax.grad(lambda x_, w_: jnp.sum(op(x_ * scale, w_) ** 2),
+                         argnums=(0, 1))(x, w)
+            return carry, g
+        return jax.jit(lambda: jax.lax.scan(body, 0.0, scales)[1])()
+
+    if has_w:
+        got = grads(lambda x_, w_: gather_mul_segment_sum(
+            x_, w_, s, r, edge_valid=valid))
+        want = grads(lambda x_, w_: jax.ops.segment_sum(
+            x_[s] * w_, r, num_segments=n))
+    else:
+        got = grads(lambda x_, w_: gather_segment_sum(x_, s, r, valid))
+        want = grads(lambda x_, w_: _gss_ref(x_, s, r, valid))
+    m = np.asarray(valid) != 0
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=1e-5, atol=1e-5)
+    assert np.isfinite(np.asarray(got[1])).all()
+    np.testing.assert_allclose(np.asarray(got[1])[:, m],
+                               np.asarray(want[1])[:, m],
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_dense_schedule_revisits_only_consecutively(layout):
+    """Host-side, on the tables themselves: every node block and every
+    edge block occupies ONE consecutive run of grid steps — Pallas keeps
+    an accumulated output block resident only across consecutive steps,
+    and the backward accumulates into both (P by node block, dw by edge
+    block)."""
+    from hydragnn_tpu.ops.fused_block import _dense_schedule
+    from hydragnn_tpu.ops.fused_mp import _pack
+
+    x, w, s, r, valid = _synthetic_edges(layout, 3, seed=25)
+    _, _, _, recv_p = _pack(x, w, s, r, None, valid)
+    n_blocks = -(-x.shape[0] // _NB)
+    n_eblocks = recv_p.shape[0] // _EB
+    si, se, av, fi, s_max = _dense_schedule(
+        recv_p[:, 0], n_blocks, _NB, _EB, n_eblocks)
+    si, se, av, fi = (np.asarray(t) for t in (si, se, av, fi))
+    assert si.shape == (s_max,) == se.shape
+    for table in (si, se):
+        starts = np.flatnonzero(np.diff(table, prepend=table[0] - 1))
+        assert len(set(table[starts])) == len(starts), table
+    # each node block is entered once, every real edge is scheduled with
+    # its own node block exactly once, and no masked edge ever is
+    assert (si[fi == 1] == np.arange(n_blocks)).all()
+    recv = np.asarray(recv_p[:, 0]).reshape(n_eblocks, _EB)
+    hits = np.zeros(recv.shape, np.int64)
+    for i, eb in zip(si[av == 1], se[av == 1]):
+        hits[eb] += recv[eb] // _NB == i
+    assert (hits.ravel() == (recv.ravel() < n_blocks * _NB)).all()
+
+
+def test_backward_has_no_sort_and_no_edge_sized_gather(monkeypatch):
+    """Structure, no chip needed: through the dispatcher on the fused
+    path, the gradient's jaxpr holds no sort, no gather with E rows, and
+    ONE kernel named ``gather_mul_seg_bwd`` per call of the op — the
+    sender-order tax cannot creep back unseen."""
+    monkeypatch.setenv("HYDRAGNN_AGGR_BACKEND", "fused")
+    b = _batch(seed=26)
+    assert "edge_perm_sender" in b.extras
+    x, w = _arrays(b, seed=27)
+    e = w.shape[0]
+
+    def loss(x_, w_):
+        h = segment.gather_mul_segment(x_, w_, b)
+        return jnp.sum(segment.gather_mul_segment(h, w_, b) ** 2)
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, w).jaxpr
+    eqns = [eq for eq, _stack in _eqns_with_stacks(jaxpr)]
+    names = [eq.primitive.name for eq in eqns]
+    assert not [n for n in names if "sort" in n], names
+    # nothing E-sized is gathered: no gather RESULT has E rows, and the only
+    # gathers that READ an E-row operand are the schedule's binary-search
+    # probes (searchsorted over the receivers: one row per node block)
+    probes = -(-x.shape[0] // _NB) + 1
+    for eq in eqns:
+        if eq.primitive.name != "gather":
+            continue
+        operand, out = eq.invars[0].aval.shape, eq.outvars[0].aval.shape
+        assert not out or out[0] < e, (operand, out)
+        if operand[0] >= e:
+            assert int(np.prod(out)) <= probes, (operand, out)
+    kernels = [eq.params["name"] for eq in eqns
+               if eq.primitive.name == "pallas_call"]
+    assert kernels.count("gather_mul_seg_bwd") == 2, kernels
+    assert kernels.count("gather_mul_seg_fwd") == 2, kernels
 
 
 def test_segment_sum_dense_exact():
