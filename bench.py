@@ -715,18 +715,18 @@ def _child(platform: str) -> None:
             dres = {"graphs_per_sec": round(dense_batch / dstep_s, 1),
                     "step_ms": round(dstep_s * 1e3, 3)}
             dres.update(_roofline(dstep, dstate, dbatch, dstep_s))
-            # the fused CFConv edge pipeline (default-on at this width,
-            # models/schnet.py) hides the filter MLP's E*F^2 flops
-            # inside a Pallas call that XLA's cost model cannot see —
-            # take the useful-flops basis from the composed-twin
-            # program (identical math/params) at TIGHT edge padding
-            # (real-edge work only).
-            from hydragnn_tpu.models.schnet import _scf_pipeline_enabled
+            # under the fused backend the CFConv filter network runs
+            # inside the gather-multiply kernels (ops/scf_mp.py), which
+            # hides its E*F^2 flops in a Pallas call that XLA's cost
+            # model cannot see — take the useful-flops basis from the
+            # composed-twin program (the scatter backend: identical
+            # math/params) at TIGHT edge padding (real-edge work only).
+            from hydragnn_tpu.ops.scf_mp import SCF_F_LIMIT
 
             dres["flops_method"] = "XLA cost model of the timed program"
-            if _scf_pipeline_enabled(hidden, 50):
-                prior = os.environ.get("HYDRAGNN_SCF_FUSED")
-                os.environ["HYDRAGNN_SCF_FUSED"] = "0"
+            prior = os.environ.get("HYDRAGNN_AGGR_BACKEND")
+            if prior == "fused" and hidden <= SCF_F_LIMIT:
+                os.environ["HYDRAGNN_AGGR_BACKEND"] = "scatter"
                 try:
                     key = (hidden, dense_batch)
                     if key not in twin_flops:
@@ -757,10 +757,7 @@ def _child(platform: str) -> None:
                         dres["mfu_pct_loose_twin"] = round(
                             fl2 / dstep_s / _mxu_peak() * 100, 2)
                 finally:
-                    if prior is None:
-                        os.environ.pop("HYDRAGNN_SCF_FUSED", None)
-                    else:
-                        os.environ["HYDRAGNN_SCF_FUSED"] = prior
+                    os.environ["HYDRAGNN_AGGR_BACKEND"] = prior
             name = (f"SchNet-h{hidden}-bf16-b{dense_batch}"
                     + ("-tight" if tight else ""))
             dense[name] = dres

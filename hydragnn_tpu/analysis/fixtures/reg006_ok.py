@@ -25,7 +25,7 @@ class Traced:
             pass
 
     def kernels(self, pl, body, spec, kernel_name="gather_mul_seg_fwd"):
-        pl.pallas_call(body, name="scf_fwd")
+        pl.pallas_call(body, name="egcl_fwd")
         pl.pallas_call(body, name=f"{spec.name}_bwd_p")
         pl.pallas_call(body, name=kernel_name)
         self.kernels(pl, body, spec, kernel_name="gather_mul_seg_bwd")

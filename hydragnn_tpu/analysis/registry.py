@@ -160,12 +160,6 @@ _KNOB_LIST = [
     _k("HYDRAGNN_AGGR_BACKEND", "", "scatter",
        "hydragnn_tpu/ops/aggregate.py",
        "aggregation backend: fused (Pallas) | scatter (XLA)"),
-    _k("HYDRAGNN_SCF_FUSED", "", "auto",
-       "hydragnn_tpu/models/schnet.py",
-       "SchNet fused CFConv pipeline gate"),
-    _k("HYDRAGNN_SCF_BE_R", "", "auto",
-       "hydragnn_tpu/ops/scf_mp.py",
-       "fused-CFConv edge-block residency override"),
     _k("HYDRAGNN_GAT_FUSED", "", "auto",
        "hydragnn_tpu/models/gat.py",
        "GAT fused edge-attention gate"),
@@ -704,7 +698,6 @@ def _kn(name, module, desc):
 
 
 _EDGE_BLOCK_KERNELS = [
-    ("scf", "SchNet CFConv edge pipeline (ops/scf_mp.py)"),
     ("egcl", "EGNN EGCL block (ops/egcl_mp.py)"),
     ("cgcnn", "CGCNN gated block (ops/cgcnn_mp.py)"),
     ("dn_tri_builder", "DimeNet triplet-table builder (ops/dn_tri.py)"),
@@ -720,9 +713,11 @@ _KERNEL_LIST = [
                      ("bwd_s", "backward, other order: other-side dx"))
 ] + [
     _kn("gather_mul_seg_fwd", "hydragnn_tpu/ops/fused_mp.py",
-        "gather x[send] (* w) -> sorted segment sum"),
+        "gather x[send] (* w, an array or made in VMEM from its chain) "
+        "-> sorted segment sum"),
     _kn("gather_mul_seg_bwd", "hydragnn_tpu/ops/fused_mp.py",
-        "receiver-order backward pass: dw per edge + windowed dx partials"),
+        "receiver-order backward pass: dw per edge (or, chain form, its "
+        "pullback to the chain's weights) + windowed dx partials"),
     _kn("seg_sum_dense_fwd", "hydragnn_tpu/ops/fused_mp.py",
         "sorted segment sum on the dense schedule"),
     _kn("dn_tri_fwd", "hydragnn_tpu/ops/dn_tri.py",

@@ -17,7 +17,7 @@ kernel) — see hydragnn_tpu/ops/aggregate.py.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +52,39 @@ def segment_sum(data, segment_ids, num_segments, mask=None):
     return jax.ops.segment_sum(data, segment_ids, num_segments)
 
 
+class CFFilter(NamedTuple):
+    """SchNet's continuous filter as its GENERATOR instead of as an
+    ``[E, F]`` array: ``w[e] = (ssp(rbf[e] @ k0 + b0) @ k1 + b1) * cut[e]``.
+    Handed to :func:`gather_mul_segment` in ``w``'s place, it lets the
+    fused kernels make each block of ``w`` in VMEM (ops/scf_mp.py), so no
+    edge-sized filter, pre-activation or filter cotangent ever exists in
+    HBM; :meth:`dense` is the composed expression every other path (the
+    ``scatter`` backend, an equivariant layer's coordinate MLP, a width
+    the kernels cannot hold) evaluates."""
+    rbf: jax.Array   # [E, G] radial basis
+    cut: jax.Array   # [E] cutoff envelope (unmasked)
+    k0: jax.Array    # [G, F]
+    b0: jax.Array    # [F]
+    k1: jax.Array    # [F, F]
+    b1: jax.Array    # [F]
+
+    def dense(self, edge_mask):
+        """The masked ``[E, F]`` filter, composed in plain XLA."""
+        # shifted softplus, as models/layers.shifted_softplus
+        act = jax.nn.softplus(self.rbf @ self.k0 + self.b0) - jnp.log(2.0)
+        filt = act @ self.k1 + self.b1
+        return filt * self.cut[:, None] * edge_mask[:, None]
+
+    def fits_kernel(self) -> bool:
+        """Structural limits of the in-VMEM form: the basis (plus the
+        cutoff and bias lanes) within the geometry tile, ``[F, F]`` weight
+        blocks within VMEM."""
+        from hydragnn_tpu.ops.scf_mp import SCF_F_LIMIT, SCF_G_LIMIT
+
+        return (self.rbf.shape[1] <= SCF_G_LIMIT
+                and self.k1.shape[1] <= SCF_F_LIMIT)
+
+
 def gather_mul_segment(x, w, g):
     """The message-passing core ``out[n] = sum_{e: recv[e]=n}
     x[send[e]] * w[e]`` — gather, edge-multiply, segment-sum.
@@ -61,12 +94,29 @@ def gather_mul_segment(x, w, g):
     the block-locality invariant holds) this lowers to the single fused
     Pallas pass (ops/fused_mp.py) that never materializes the gathered
     messages in HBM; otherwise the standard gather + masked segment_sum.
+
+    ``w`` is the ``[E, F]`` multiplier or its generator, a
+    :class:`CFFilter`: on the fused path the same two kernels then make
+    ``w`` in VMEM, forward and backward (tallied ``gather_mul_filter``);
+    everywhere else the generator is evaluated to the array first.
     """
     # the permutation's PRESENCE is the gate (collate's word that the
     # kernel's invariants hold); the kernel itself reads the edge list as
     # shipped, forward and backward, and never the permutation
     fused = bool(g.extras) and "edge_perm_sender" in g.extras
     _count("gather_mul", fused)
+    if isinstance(w, CFFilter):
+        in_vmem = fused and w.fits_kernel()
+        _count("gather_mul_filter", in_vmem)
+        if in_vmem:
+            from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
+
+            # cm zeroes padding edges; em lets the schedule skip them
+            return scf_edge_pipeline(
+                x, w.rbf, w.cut * g.edge_mask,
+                g.edge_mask.astype(jnp.int32), w.k0, w.b0, w.k1, w.b1,
+                g.senders, g.receivers)
+        w = w.dense(g.edge_mask)
     if fused:
         from hydragnn_tpu.ops.fused_mp import gather_mul_segment_sum
 

@@ -30,6 +30,24 @@ Padding edges (parked on node N-1 by collate with edge_mask 0) contribute
 nothing: the caller's pre-masked ``w`` zeroes them, and out-of-window
 one-hot rows are all-zero anyway.
 
+The multiplier ``w`` has THREE sources, a static choice per call site; the
+kernels, the schedule and the names (``gather_mul_seg_fwd`` / ``_bwd``) are
+the same for all three:
+
+  * an ARRAY ``w[E, F]`` streamed from HBM (:func:`gather_mul_segment_sum`
+    — GAT's composed path, DimeNet's ``tri_window``, any caller whose
+    multiplier already exists);
+  * NOTHING: the ``[E, 1]`` edge-mask column rides in ``w``'s slot and the
+    messages are the gathered features (:func:`gather_segment_sum` — the
+    GIN / SAGE / MFC sums, ``poly_mp``'s sum-only backward);
+  * a CHAIN (:func:`gather_chain_segment_sum`): per edge block an
+    ``[BE, GPW]`` geometry tile and constant-mapped weight blocks, from
+    which a pure-JAX ``chain(w_vals, geo, dt)`` makes ``w`` in VMEM, forward
+    and backward — SchNet's filter network (ops/scf_mp.py packs it).  No
+    ``[E, F]`` operand or cotangent exists in HBM: the geometry stream takes
+    the ``w`` stream's place, the backward keeps ``dw`` in VMEM and pulls it
+    back through the chain there (below).
+
 The grid is a DENSE CSR-style schedule: scalar-prefetched step tables map
 each grid step to one populated (node-block, edge-block) pair, so no step
 is a wasted DMA and — unlike a rectangular (block, k_max) grid bounded by a
@@ -50,7 +68,15 @@ the overlap-add of the ``P_i`` in node space (slot k of block i lands on
 block i - hw + k; slots off either end are dropped).  Edge blocks holding
 only parked edges are never entered, so their ``dw`` rows are unwritten
 memory, selected to exact zero.  The w-less op runs the same body without
-the ``x`` window and the ``dw`` stream.
+the ``x`` window and the ``dw`` stream.  The chain form recomputes ``w`` from
+its geometry tile, keeps ``dw`` in VMEM and pulls it back through the chain
+inside the same step (``jax.vjp`` on the chain body): the weight
+cotangents accumulate into constant-mapped f32 blocks zeroed at step 0 —
+``g_r``'s zero rows gate ``dw`` and the pullback is linear in it, so every
+edge feeds them exactly once — and a ``dgeo`` stream (``dw``'s first-visit
+select) is written ONLY when the geometry is itself differentiated
+(``custom_vjp(symbolic_zeros=True)`` tells the forward rule): with the
+geometry from batch data the backward has no edge-sized output at all.
 """
 
 from __future__ import annotations
@@ -70,18 +96,26 @@ from hydragnn_tpu.ops.fused_block import _window_maps
 _EDGE_BLOCK = 512   # edges per inner step
 
 
-def _fwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
+def _multiplier(chain, src_refs, dt):
+    """The edge block's multiplier from its source (module docstring): the
+    ``w`` block / the mask column as stored, or the chain evaluated in
+    VMEM from its geometry block and constant weight blocks."""
+    if chain is None:
+        return src_refs[0][:].astype(jnp.float32)
+    geo_ref, *w_refs = src_refs
+    return chain(tuple(r[:] for r in w_refs), geo_ref[:], dt)
+
+
+def _fwd_kernel(chain, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
                 recv_ref, *rest):
     from jax.experimental import pallas as pl
 
-    if has_w:
-        w_ref = rest[0]
-    else:
-        # w omitted: messages are the gathered features themselves, scaled
-        # by the scalar edge mask (GIN/MFC-style sum aggregation)
-        mask_ref = rest[0]
-    xwin_refs = rest[1:1 + window]
-    out_ref = rest[1 + window]
+    # the multiplier's source: ONE ref for an array (``w``, or without it
+    # the scalar edge-mask column: messages are the gathered features
+    # themselves, GIN/MFC-style), geo + weight blocks for a chain
+    src_refs = rest[:-(window + 1)]
+    xwin_refs = rest[-(window + 1):-1]
+    out_ref = rest[-1]
 
     s = pl.program_id(0)
     i = si_ref[s]
@@ -108,10 +142,7 @@ def _fwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
         msgs = jax.lax.dot_general(
             onehot_s, xcat, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # [BE, F]
-        if has_w:
-            msgs = msgs * w_ref[:].astype(jnp.float32)
-        else:
-            msgs = msgs * mask_ref[:].astype(jnp.float32)
+        msgs = msgs * _multiplier(chain, src_refs, xwin_refs[0].dtype)
         rloc = recv_ref[:] - i * bn
         onehot_r = (rloc == jax.lax.broadcasted_iota(
             jnp.int32, (be, bn), 1)).astype(jnp.float32)
@@ -150,6 +181,14 @@ def _pack(x, w, senders, receivers, mask=None, edge_valid=None):
         m = (jnp.ones((e,), jnp.float32) if mask is None
              else mask.astype(jnp.float32))
         w_p = jnp.pad(m, (0, e_pad - e))[:, None]
+    send_p, recv_p = _pack_ids(senders, receivers, e_pad, n_pad, edge_valid)
+    return x_p, w_p, send_p, recv_p
+
+
+def _pack_ids(senders, receivers, e_pad, n_pad, edge_valid=None):
+    """The two ``[E_pad, 1]`` id columns, shape- and mask-padding edges
+    parked on ``n_pad`` (:func:`_pack`)."""
+    e = senders.shape[0]
     if edge_valid is not None:
         ev = edge_valid != 0
         senders = jnp.where(ev, senders, n_pad)
@@ -159,21 +198,53 @@ def _pack(x, w, senders, receivers, mask=None, edge_valid=None):
         return jnp.pad(v.astype(jnp.int32), (0, e_pad - e),
                        constant_values=n_pad)[:, None]
 
-    send_p, recv_p = ids(senders), ids(receivers)
-    return x_p, w_p, send_p, recv_p
+    return ids(senders), ids(receivers)
 
 
-def _fwd_call(has_w, window, x_p, w_p, send_p, recv_p):
+def _chain_edge_block(f_pad, backward):
+    """Edge block of the chain form.  Beside the array form's operands it
+    holds the ``[F_pad, F_pad]`` weight block (and, backward, its f32
+    gradient accumulator) and the chain's ``[BE, F_pad]`` f32 temporaries
+    (~6 forward, ~15 with the pullback), so the block shrinks once F_pad
+    passes 512: 256 forward, 128 backward (see :func:`_chain_vmem`)."""
+    if f_pad <= 512:
+        return _EDGE_BLOCK
+    return _EDGE_BLOCK // 4 if backward else _EDGE_BLOCK // 2
+
+
+def _chain_vmem(f_pad):
+    """``compiler_params`` of the chain-form kernels.  At F_pad 128 both
+    passes hold ~5 MB and the default 16 MiB scoped VMEM stands.  Wider,
+    the stack comes within a megabyte of that limit or past it — a 1024
+    bf16 backward asked for 16.84 MB on the v5e, and ambient ``highest``
+    matmul precision adds the f32 dots' multi-pass scratch — so those
+    kernels ask for 64 of the chip's 128 MiB instead (AOT-compiled,
+    tools/mosaic_aot.py: 256 / 512 / 768 / 1024 in f32 and bf16, at
+    default and ``highest``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if f_pad <= 128 or jax.default_backend() != "tpu":
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=64 * 1024 * 1024)}
+
+
+def _fwd_call(window, x_p, w_p, send_p, recv_p, chain=None, weights=()):
+    """The forward pass on whole blocks.  With a ``chain`` the multiplier's
+    slot ``w_p`` holds the ``[E_pad, GPW]`` geometry stream and
+    ``weights`` its constant-mapped blocks."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     assert window % 2 == 1, "window must be odd"
     bn, be = _NODE_BLOCK, _EDGE_BLOCK
     n_pad, f_pad = x_p.shape
+    if chain is not None:
+        be = _chain_edge_block(f_pad, False)
     n_blocks, n_eblocks = n_pad // bn, send_p.shape[0] // be
     step_i, step_eb, acc_valid, is_first, s_max = _dense_schedule(
         recv_p[:, 0], n_blocks, bn, be, n_eblocks)
-    eix, xoff, _, outx = _window_maps(n_blocks)
+    eix, xoff, const, outx = _window_maps(n_blocks)
 
     hw = window // 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -183,23 +254,57 @@ def _fwd_call(has_w, window, x_p, w_p, send_p, recv_p):
             pl.BlockSpec((be, 1), eix),
             pl.BlockSpec((be, 1), eix),
             pl.BlockSpec((be, w_p.shape[1]), eix),
-        ] + [pl.BlockSpec((bn, f_pad), xoff(o))
-             for o in range(-hw, hw + 1)],
+        ] + [pl.BlockSpec(w.shape, const) for w in weights]
+        + [pl.BlockSpec((bn, f_pad), xoff(o))
+           for o in range(-hw, hw + 1)],
         out_specs=pl.BlockSpec((bn, f_pad), outx),
     )
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, has_w, window),
+        functools.partial(_fwd_kernel, chain, window),
         out_shape=jax.ShapeDtypeStruct((n_pad, f_pad), jnp.float32),
         grid_spec=grid_spec,
         interpret=jax.default_backend() != "tpu",
         name="gather_mul_seg_fwd",
-    )(step_i, step_eb, acc_valid, is_first, send_p, recv_p, w_p,
+        **({} if chain is None else _chain_vmem(f_pad)),
+    )(step_i, step_eb, acc_valid, is_first, send_p, recv_p, w_p, *weights,
       *([x_p] * window))
 
 
 # ---------------------------------------------------------------------------
 # backward: ONE pass over the edge list in the order collate ships it
 # ---------------------------------------------------------------------------
+
+
+def _bwd_gathers(window, i, send_ref, recv_ref, g_ref, xwin_refs):
+    """What every form of the backward step starts from: ``(g_r,
+    onehot_s, x_s)`` — the cotangent at the receivers, the forward's
+    window one-hot, the features at the senders (None without windows)."""
+    bn = g_ref.shape[0]
+    be = send_ref.shape[0]
+    # g[recv]: block-local, an edge of another node block gets an
+    # all-zero row — it gates every product below, so a boundary edge
+    # block contributes each edge exactly once (on its own block's
+    # visit)
+    rloc = recv_ref[:] - i * bn
+    onehot_r = (rloc == jax.lax.broadcasted_iota(
+        jnp.int32, (be, bn), 1)).astype(jnp.float32)
+    g_r = jax.lax.dot_general(
+        onehot_r, g_ref[:].astype(jnp.float32),
+        (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)           # [BE, F]
+    # the forward's window one-hot (same base, same clamped slots)
+    hw = window // 2
+    sloc = send_ref[:] - (i - hw) * bn
+    onehot_s = (sloc == jax.lax.broadcasted_iota(
+        jnp.int32, (be, window * bn), 1)).astype(jnp.float32)
+    if not xwin_refs:
+        return g_r, onehot_s, None
+    xcat = jnp.concatenate(
+        [r[:] for r in xwin_refs], axis=0).astype(jnp.float32)
+    x_s = jax.lax.dot_general(
+        onehot_s, xcat, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)           # [BE, F]
+    return g_r, onehot_s, x_s
 
 
 def _bwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, fe_ref,
@@ -219,30 +324,9 @@ def _bwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, fe_ref,
 
     @pl.when(av_ref[s] == 1)
     def _acc():
-        bn = g_ref.shape[0]
-        be = send_ref.shape[0]
-        # g[recv]: block-local, an edge of another node block gets an
-        # all-zero row — it gates every product below, so a boundary edge
-        # block contributes each edge exactly once (on its own block's
-        # visit)
-        rloc = recv_ref[:] - i * bn
-        onehot_r = (rloc == jax.lax.broadcasted_iota(
-            jnp.int32, (be, bn), 1)).astype(jnp.float32)
-        g_r = jax.lax.dot_general(
-            onehot_r, g_ref[:].astype(jnp.float32),
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [BE, F]
-        # the forward's window one-hot (same base, same clamped slots)
-        hw = window // 2
-        sloc = send_ref[:] - (i - hw) * bn
-        onehot_s = (sloc == jax.lax.broadcasted_iota(
-            jnp.int32, (be, window * bn), 1)).astype(jnp.float32)
+        g_r, onehot_s, x_s = _bwd_gathers(
+            window, i, send_ref, recv_ref, g_ref, xwin_refs)
         if has_w:
-            xcat = jnp.concatenate(
-                [r[:] for r in xwin_refs], axis=0).astype(jnp.float32)
-            x_s = jax.lax.dot_general(
-                onehot_s, xcat, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [BE, F]
             dw = x_s * g_r
             # per-edge stream: overwrite on the edge block's first
             # accumulated visit (the memory is uninitialised — select,
@@ -269,6 +353,42 @@ def _bwd_edge_block(f_pad, window):
     return _EDGE_BLOCK if f_pad * window <= 2560 else _EDGE_BLOCK // 2
 
 
+def _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks):
+    """The dense schedule of the backward pass plus ``first_e`` (an edge
+    block's first accumulated visit: per-edge output streams overwrite on
+    it).  Every step that accumulates nothing (the forced step of an empty
+    node block, the trailing clamped steps) HOLDS the last accumulated
+    edge block: it fetches nothing and, above all, enters no per-edge
+    output block — so such a block is entered exactly once, on consecutive
+    steps, and initialised by its first accumulated visit."""
+    step_i, step_eb, acc_valid, is_first, s_max = _dense_schedule(
+        recv_p[:, 0], n_blocks, bn, be, n_eblocks)
+    held = jax.lax.cummax(jnp.where(acc_valid == 1, step_eb, -1))
+    prev = jnp.concatenate([jnp.full(1, -1, jnp.int32), held[:-1]])
+    first_e = ((acc_valid == 1) & (step_eb != prev)).astype(jnp.int32)
+    step_eb = jnp.maximum(held, 0)
+    return (step_i, step_eb, acc_valid, is_first, first_e), s_max
+
+
+def _overlap_add(p, n_blocks, window, bn, f_pad):
+    """``dx_p`` from the per-node-block window sums: slot k of node block
+    i holds its edges' contributions to block i - hw + k; slots that fall
+    off either end hold nothing (no sender is negative, parked edges are
+    gated by g_r) and are dropped, not wrapped."""
+    hw = window // 2
+    p = p.reshape(n_blocks, window, bn, f_pad)
+    dx_p = None
+    for k in range(window):
+        d = k - hw
+        lo, hi = max(0, -d), n_blocks - max(0, d)
+        if hi <= lo:
+            continue
+        part = jnp.pad(p[lo:hi, k],
+                       ((lo + d, n_blocks - hi - d), (0, 0), (0, 0)))
+        dx_p = part if dx_p is None else dx_p + part
+    return dx_p.reshape(n_blocks * bn, f_pad)
+
+
 def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
     """``(dx_p, dw_p)`` of the padded problem (``dw_p`` None without w)."""
     from jax.experimental import pallas as pl
@@ -280,17 +400,7 @@ def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
     be = _bwd_edge_block(f_pad, window)
     n_blocks, n_eblocks = n_pad // bn, e_pad // be
     hw = window // 2
-    step_i, step_eb, acc_valid, is_first, s_max = _dense_schedule(
-        recv_p[:, 0], n_blocks, bn, be, n_eblocks)
-    # every step that accumulates nothing (the forced step of an empty
-    # node block, the trailing clamped steps) HOLDS the last accumulated
-    # edge block: it fetches nothing and, above all, enters no dw block —
-    # so a dw block is entered exactly once, on consecutive steps, and
-    # initialised by its first accumulated visit
-    held = jax.lax.cummax(jnp.where(acc_valid == 1, step_eb, -1))
-    prev = jnp.concatenate([jnp.full(1, -1, jnp.int32), held[:-1]])
-    first_e = ((acc_valid == 1) & (step_eb != prev)).astype(jnp.int32)
-    step_eb = jnp.maximum(held, 0)
+    tables, s_max = _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks)
     eix, xoff, _, outx = _window_maps(n_blocks)
 
     in_specs = [
@@ -321,23 +431,9 @@ def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
         ),
         interpret=jax.default_backend() != "tpu",
         name="gather_mul_seg_bwd",
-    )(step_i, step_eb, acc_valid, is_first, first_e, *operands)
+    )(*tables, *operands)
 
-    # overlap-add: slot k of node block i holds its edges' contributions
-    # to block i - hw + k; slots that fall off either end hold nothing
-    # (no sender is negative, parked edges are gated by g_r) and are
-    # dropped, not wrapped
-    p = outs[-1].reshape(n_blocks, window, bn, f_pad)
-    dx_p = None
-    for k in range(window):
-        d = k - hw
-        lo, hi = max(0, -d), n_blocks - max(0, d)
-        if hi <= lo:
-            continue
-        part = jnp.pad(p[lo:hi, k],
-                       ((lo + d, n_blocks - hi - d), (0, 0), (0, 0)))
-        dx_p = part if dx_p is None else dx_p + part
-    dx_p = dx_p.reshape(n_pad, f_pad)
+    dx_p = _overlap_add(outs[-1], n_blocks, window, bn, f_pad)
     if not has_w:
         return dx_p, None
     # edge blocks the schedule never accumulates (parked edges only) are
@@ -346,16 +442,126 @@ def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
     return dx_p, dw_p
 
 
+def _bwd_chain_kernel(chain, nw, window, want_dgeo, si_ref, se_ref, av_ref,
+                      fi_ref, fe_ref, send_ref, recv_ref, geo_ref, *rest):
+    """:func:`_bwd_kernel` with the multiplier recomputed from its chain:
+    ``dw = x_s * g_r`` stays in VMEM and is pulled back through the chain
+    here (``jax.vjp`` on its body; weight VALUES upcast to f32 so their
+    cotangents accumulate without per-step rounding) into constant-mapped
+    f32 blocks zeroed at step 0 — and, only when the geometry is
+    differentiated, into a per-edge ``dgeo`` stream with the first-visit
+    select ``dw`` has in the array form."""
+    from jax.experimental import pallas as pl
+
+    w_refs = rest[:nw]
+    g_ref = rest[nw]
+    xwin_refs = rest[nw + 1:nw + 1 + window]
+    outs = rest[nw + 1 + window:]
+    dws_refs = outs[:nw]
+    dgeo_ref = outs[nw] if want_dgeo else None
+    p_ref = outs[-1]
+
+    s = pl.program_id(0)
+    i = si_ref[s]
+
+    @pl.when(s == 0)
+    def _init_w():
+        for r in dws_refs:
+            r[:] = jnp.zeros_like(r)
+
+    @pl.when(fi_ref[s] == 1)
+    def _init():
+        p_ref[:] = jnp.zeros_like(p_ref)
+
+    @pl.when(av_ref[s] == 1)
+    def _acc():
+        dt = xwin_refs[0].dtype
+        # g_r's zero rows gate dw, hence the whole (linear) pullback:
+        # every edge feeds the weight gradients exactly once
+        g_r, onehot_s, x_s = _bwd_gathers(
+            window, i, send_ref, recv_ref, g_ref, xwin_refs)
+        dw = x_s * g_r
+        w_vals = tuple(r[:].astype(jnp.float32) for r in w_refs)
+        geo = geo_ref[:]
+        if want_dgeo:
+            w, pull = jax.vjp(lambda wv, gv: chain(wv, gv, dt), w_vals, geo)
+            dws, dgeo = pull(dw)
+            dgeo_ref[:] = jnp.where(fe_ref[s] == 1, dgeo,
+                                    dgeo_ref[:] + dgeo)
+        else:
+            w, pull = jax.vjp(lambda wv: chain(wv, geo, dt), w_vals)
+            (dws,) = pull(dw)
+        for r, d in zip(dws_refs, dws):
+            r[:] += d
+        p_ref[:] += jax.lax.dot_general(
+            onehot_s, w * g_r, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)           # [W*BN, F]
+
+
+def _bwd_chain_call(chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
+                    want_dgeo):
+    """``(dx_p, dgeo_p, dweights)`` of the padded chain-form problem, one
+    pass; ``dgeo_p`` is None (no E-sized output exists) unless asked."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bn = _NODE_BLOCK
+    n_pad, f_pad = g_p.shape
+    e_pad, gpw = geo_p.shape
+    be = _chain_edge_block(f_pad, True)
+    n_blocks, n_eblocks = n_pad // bn, e_pad // be
+    hw = window // 2
+    nw = len(weights)
+    tables, s_max = _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks)
+    eix, xoff, const, outx = _window_maps(n_blocks)
+
+    in_specs = [
+        pl.BlockSpec((be, 1), eix),
+        pl.BlockSpec((be, 1), eix),
+        pl.BlockSpec((be, gpw), eix),
+    ] + [pl.BlockSpec(w.shape, const) for w in weights] \
+      + [pl.BlockSpec((bn, f_pad), outx)] \
+      + [pl.BlockSpec((bn, f_pad), xoff(o)) for o in range(-hw, hw + 1)]
+    out_specs = [pl.BlockSpec(w.shape, const) for w in weights]
+    out_shape = [jax.ShapeDtypeStruct(w.shape, jnp.float32)
+                 for w in weights]
+    if want_dgeo:
+        out_specs.append(pl.BlockSpec((be, gpw), eix))
+        out_shape.append(jax.ShapeDtypeStruct((e_pad, gpw), jnp.float32))
+    out_specs.append(pl.BlockSpec((window * bn, f_pad), outx))
+    out_shape.append(jax.ShapeDtypeStruct(
+        (n_blocks * window * bn, f_pad), jnp.float32))
+    outs = pl.pallas_call(
+        functools.partial(_bwd_chain_kernel, chain, nw, window, want_dgeo),
+        out_shape=out_shape,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(s_max,),
+            in_specs=in_specs,
+            out_specs=out_specs,
+        ),
+        interpret=jax.default_backend() != "tpu",
+        name="gather_mul_seg_bwd",
+        **_chain_vmem(f_pad),
+    )(*tables, send_p, recv_p, geo_p, *weights, g_p, *([x_p] * window))
+
+    dx_p = _overlap_add(outs[-1], n_blocks, window, bn, f_pad)
+    # never-accumulated edge blocks are uninitialised memory: select
+    dgeo_p = (jnp.where(recv_p < n_pad, outs[nw], 0.0) if want_dgeo
+              else None)
+    return dx_p, dgeo_p, tuple(outs[:nw])
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _gms_padded(has_w, window, x_p, w_p, send_p, recv_p):
     """The op on whole blocks: ``[N_pad, F_pad]`` f32 segment sums.  The
     public ops pad and slice round it in plain jnp, so AD zero-pads the
     cotangent and slices ``dx`` / ``dw`` by itself."""
-    return _fwd_call(has_w, window, x_p, w_p, send_p, recv_p)
+    return _fwd_call(window, x_p, w_p, send_p, recv_p)
 
 
 def _gms_fwd(has_w, window, x_p, w_p, send_p, recv_p):
-    out = _fwd_call(has_w, window, x_p, w_p, send_p, recv_p)
+    out = _fwd_call(window, x_p, w_p, send_p, recv_p)
     # the w-less backward reads no x: hold its dtype, none of its rows
     return out, (x_p if has_w else x_p[:0], w_p, send_p, recv_p)
 
@@ -368,6 +574,43 @@ def _gms_bwd(has_w, window, res, g_p):
 
 
 _gms_padded.defvjp(_gms_fwd, _gms_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _gcs_padded(chain, window, x_p, geo_p, weights, send_p, recv_p):
+    """:func:`_gms_padded` with the multiplier made in VMEM: ``geo_p``
+    ``[E_pad, GPW]`` and the constant ``weights`` blocks take ``w_p``'s
+    place, and ``chain(w_vals, geo, dt) -> [BE, F_pad]`` f32 (pure JAX,
+    static) turns one block of them into one block of ``w``."""
+    return _fwd_call(window, x_p, geo_p, send_p, recv_p, chain,
+                     tuple(weights))
+
+
+def _gcs_fwd(chain, window, x_p, geo_p, weights, send_p, recv_p):
+    # symbolic_zeros: every argument arrives as (value, perturbed).  The
+    # geometry's flag decides whether the backward writes a dgeo stream at
+    # all; it reaches the backward rule as the residuals' STRUCTURE
+    # (a pytree with no leaves), the one static channel the two rules share
+    want_dgeo = () if geo_p.perturbed else None
+    x_p, geo_p, send_p, recv_p = (
+        a.value for a in (x_p, geo_p, send_p, recv_p))
+    weights = tuple(w.value for w in weights)
+    out = _fwd_call(window, x_p, geo_p, send_p, recv_p, chain, weights)
+    return out, (x_p, geo_p, weights, send_p, recv_p, want_dgeo)
+
+
+def _gcs_bwd(chain, window, res, g_p):
+    x_p, geo_p, weights, send_p, recv_p, want_dgeo = res
+    dx_p, dgeo_p, dws = _bwd_chain_call(
+        chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
+        want_dgeo is not None)
+    return (dx_p.astype(x_p.dtype),
+            None if dgeo_p is None else dgeo_p.astype(geo_p.dtype),
+            tuple(d.astype(w.dtype) for d, w in zip(dws, weights)),
+            None, None)
+
+
+_gcs_padded.defvjp(_gcs_fwd, _gcs_bwd, symbolic_zeros=True)
 
 
 def gather_mul_segment_sum(x, w, senders, receivers, window=3,
@@ -402,6 +645,33 @@ def gather_mul_segment_sum(x, w, senders, receivers, window=3,
     n, f = x.shape
     out = _gms_padded(True, window,
                       *_pack(x, w, senders, receivers, None, edge_valid))
+    return out[:n, :f].astype(x.dtype)
+
+
+def gather_chain_segment_sum(x, geo_p, weights, chain, senders, receivers,
+                             edge_valid=None, window=3):
+    """:func:`gather_mul_segment_sum` whose multiplier is never an array:
+    ``w[e] = chain(weights, geo_p[e])`` is evaluated per edge block in
+    VMEM, forward and backward (module docstring, the third source).
+
+    ``geo_p`` is the ``[E_pad, GPW]`` f32 geometry stream (rows padded to
+    a whole number of ``_EDGE_BLOCK``s, lanes to whole 128-lane tiles),
+    ``weights`` the tuple of packed constant blocks, ``chain`` a static
+    pure-JAX ``(w_vals, geo, dt) -> [BE, F_pad]`` — callers pack with
+    plain jnp ops (``lax.pad``, not scatters) so the raw operands'
+    gradients fall out by AD (ops/scf_mp.py is the packing front for
+    SchNet).  The chain must give masked (``edge_valid == 0``) edges a
+    zero multiplier; like ``dw`` there, their ``dgeo`` rows are exactly
+    zero.  Differentiable wrt ``x``, ``weights`` and ``geo_p``; the
+    backward is ONE pass, and writes a ``dgeo`` stream only when
+    ``geo_p`` is itself being differentiated."""
+    n, f = x.shape
+    n_pad = _round_up(n, _NODE_BLOCK)
+    x_p = jnp.pad(x, ((0, n_pad - n), (0, _round_up(max(f, 1), 128) - f)))
+    send_p, recv_p = _pack_ids(senders, receivers, geo_p.shape[0], n_pad,
+                               edge_valid)
+    out = _gcs_padded(chain, window, x_p, geo_p, tuple(weights), send_p,
+                      recv_p)
     return out[:n, :f].astype(x.dtype)
 
 

@@ -83,12 +83,22 @@ def select_optimizer(opt_config: Dict[str, Any],
 
 
 def set_learning_rate(opt_state, lr: float):
-    """Functionally rewrite the injected learning rate in an optimizer state."""
+    """Functionally rewrite the injected learning rate in an optimizer state.
+
+    The new leaf keeps the old one's placement: a state replicated over a
+    mesh must stay so, or the jitted mesh step sees another input sharding
+    and compiles a second time in the middle of the run (11 s on the
+    four-chip cell the first time the plateau scheduler fired inside a
+    window; PERF.md, PR 27)."""
+    import jax
     import jax.numpy as jnp
 
     hp = dict(opt_state.hyperparams)
     old = jnp.asarray(hp["learning_rate"])
-    hp["learning_rate"] = jnp.asarray(lr, dtype=old.dtype)
+    new = jnp.asarray(lr, dtype=old.dtype)
+    if isinstance(old, jax.Array) and old.committed:
+        new = jax.device_put(new, old.sharding)
+    hp["learning_rate"] = new
     return opt_state._replace(hyperparams=hp)
 
 

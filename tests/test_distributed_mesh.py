@@ -83,6 +83,50 @@ def test_dp_matches_single_device():
                                    rtol=1e-4, atol=1e-6)
 
 
+def test_lr_change_keeps_the_dp_step_compiled():
+    """The plateau scheduler rewrites the learning rate between epochs:
+    the new leaf must keep the replicated placement of the one it
+    replaces, or the mesh step compiles a second time in mid-run (it did,
+    11 s on four chips, the first time the scheduler fired inside a
+    benchmark window)."""
+    from hydragnn_tpu.train.optimizer import (
+        get_learning_rate, set_learning_rate)
+
+    n_dev = len(jax.devices())
+    mesh = make_mesh()
+    cfg = _cfg()
+    model = create_model(cfg)
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 0.01})
+    (batch,), _ = (lambda t: (t[0], t[1]))(_make_batches(1))
+    stacked = stack_batches([batch] * n_dev)
+    state = replicate_state(
+        create_train_state(model, batch, opt, seed=0), mesh)
+    dp_step = make_dp_train_step(model, cfg, opt, mesh)
+    state, _ = dp_step(state, stacked)
+    state, _ = dp_step(state, stacked)
+    old = state.opt_state.hyperparams["learning_rate"]
+
+    state = state.replace(opt_state=set_learning_rate(state.opt_state, 5e-3))
+    new = state.opt_state.hyperparams["learning_rate"]
+    assert new.sharding == old.sharding and new.dtype == old.dtype
+    assert get_learning_rate(state.opt_state) == np.float32(5e-3)
+
+    compiles = []
+
+    def on_duration(name, *_a, **_k):
+        if name.endswith("backend_compile_duration"):
+            compiles.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        state, m = dp_step(state, stacked)
+    finally:
+        from jax._src import monitoring
+        monitoring.unregister_event_duration_listener(on_duration)
+    assert np.isfinite(float(m["loss"]))
+    assert compiles == [], compiles
+
+
 def test_dp_training_loop_converges():
     """Run ~40 DP steps over distinct per-device batches; loss must drop."""
     n_dev = len(jax.devices())

@@ -155,19 +155,22 @@ def test_model_fused_matches_scatter(model_type, monkeypatch):
                                    rtol=1e-4, atol=1e-5)
 
 
-def _eqns_with_stacks(jaxpr, prefix=""):
+def _eqns_with_stacks(jaxpr, prefix="", into_kernels=True):
     """Every equation of ``jaxpr`` and of the jaxprs inside it, each with
     its whole name stack (an inner equation's own is relative to the
-    equation that holds it)."""
+    equation that holds it); ``into_kernels=False`` yields a
+    ``pallas_call`` but not its body."""
     for eqn in jaxpr.eqns:
         own = str(eqn.source_info.name_stack)
         stack = "/".join(p for p in (prefix, own) if p)
         yield eqn, stack
+        if eqn.primitive.name == "pallas_call" and not into_kernels:
+            continue
         for v in eqn.params.values():
             for sub in (v if isinstance(v, (list, tuple)) else (v,)):
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns_with_stacks(sub, stack)
+                    yield from _eqns_with_stacks(sub, stack, into_kernels)
 
 
 @pytest.mark.parametrize("model_type", ALL_ARCHS)
@@ -1222,6 +1225,229 @@ def test_backward_has_no_sort_and_no_edge_sized_gather(monkeypatch):
                if eq.primitive.name == "pallas_call"]
     assert kernels.count("gather_mul_seg_bwd") == 2, kernels
     assert kernels.count("gather_mul_seg_fwd") == 2, kernels
+
+
+# --- the multiplier's third source: a chain evaluated in VMEM (SchNet's
+# filter network, packed by ops/scf_mp.py) on the same two kernels ---
+
+_CG = 7      # basis lanes of the synthetic chain problems
+
+
+def _chain_problem(layout, seed, f=40):
+    """The synthetic edge list of ``layout`` with a CFConv generator on
+    it: rbf, the masked cutoff column and the filter network's weights."""
+    x, _, s, r, valid = _synthetic_edges(layout, 3, seed, f=f)
+    rng = np.random.RandomState(seed + 100)
+    e = int(s.shape[0])
+    rbf = jnp.asarray(rng.rand(e, _CG), jnp.float32)
+    cm = jnp.asarray(rng.rand(e).astype(np.float32)) * valid
+    k0 = jnp.asarray(rng.randn(_CG, f) * 0.4, jnp.float32)
+    b0 = jnp.asarray(rng.randn(f) * 0.1, jnp.float32)
+    k1 = jnp.asarray(rng.randn(f, f) * 0.2, jnp.float32)
+    b1 = jnp.asarray(rng.randn(f) * 0.1, jnp.float32)
+    return (x, rbf, cm, k0, b0, k1, b1), s, r, valid
+
+
+def _chain_fused(args, s, r, valid):
+    from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
+
+    x, rbf, cm, k0, b0, k1, b1 = args
+    return scf_edge_pipeline(x, rbf, cm, valid.astype(jnp.int32),
+                             k0, b0, k1, b1, s, r)
+
+
+def _chain_composed(args, s, r):
+    x, rbf, cm, k0, b0, k1, b1 = args
+    filt = (jax.nn.softplus(rbf @ k0 + b0) - jnp.log(2.0)) @ k1 + b1
+    return jax.ops.segment_sum(x[s] * filt * cm[:, None], r,
+                               num_segments=x.shape[0])
+
+
+@pytest.mark.parametrize("layout", list(_LAYOUTS))
+def test_chain_form_matches_composed(layout):
+    """The filter made inside the kernels against the composed expression
+    over every schedule corner: the output and dh, drbf, dcut, dk0, db0,
+    dk1, db1 from the ONE backward pass; masked edges exact zeros."""
+    args, s, r, valid = _chain_problem(layout, seed=31)
+    n = args[0].shape[0]
+    np.testing.assert_allclose(
+        np.asarray(_chain_fused(args, s, r, valid)),
+        np.asarray(_chain_composed(args, s, r)), rtol=2e-5, atol=2e-5)
+    ct = jnp.asarray(
+        np.random.RandomState(32).randn(n, args[0].shape[1]), jnp.float32)
+    got = jax.grad(lambda a: jnp.sum(ct * _chain_fused(a, s, r, valid)))(args)
+    want = jax.grad(lambda a: jnp.sum(ct * _chain_composed(a, s, r)))(args)
+    m = np.asarray(valid) != 0
+    for name, a, b in zip(("h", "rbf", "cut", "k0", "b0", "k1", "b1"),
+                          got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.isfinite(a).all(), name
+        if name in ("rbf", "cut"):
+            # never-visited blocks are uninitialised memory, selected away
+            assert (a[~m] == 0.0).all(), name
+            a, b = a[m], b[m]
+        np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4, err_msg=name)
+
+
+def test_chain_form_wide_f_shrinks_edge_blocks():
+    """What adapts in the chain form: past F_pad 512 the ``[F, F]`` weight
+    block and its gradient accumulator crowd VMEM, so the forward runs
+    256-edge and the backward 128-edge blocks on the same 512-padded
+    operands — other schedules over one edge list, the same numbers."""
+    from hydragnn_tpu.ops.fused_mp import _chain_edge_block
+
+    assert _chain_edge_block(128, False) == _EB == _chain_edge_block(512, True)
+    assert (_chain_edge_block(640, False), _chain_edge_block(1024, True)) \
+        == (_EB // 2, _EB // 4)
+    args, s, r, valid = _chain_problem("straddle", seed=37, f=520)
+    ct = jnp.asarray(np.random.RandomState(38).randn(
+        *args[0].shape), jnp.float32)
+    got = jax.grad(lambda a: jnp.sum(ct * _chain_fused(a, s, r, valid)))(args)
+    want = jax.grad(lambda a: jnp.sum(ct * _chain_composed(a, s, r)))(args)
+    m = np.asarray(valid) != 0
+    for name, a, b in zip(("h", "rbf", "cut", "k0", "b0", "k1", "b1"),
+                          got, want):
+        a, b = np.asarray(a), np.asarray(b)
+        if name in ("rbf", "cut"):
+            a, b = a[m], b[m]
+        scale = float(np.abs(b).max()) + 1e-6
+        np.testing.assert_allclose(a / scale, b / scale, atol=2e-5,
+                                   err_msg=name)
+
+
+def test_chain_form_under_jit_scan_with_weights_only():
+    """The shape of both benchmark cells: ``lax.scan`` under ``jax.jit``,
+    gradients wrt the features and the filter weights, the geometry batch
+    data (not differentiated)."""
+    args, s, r, valid = _chain_problem("masked_tail", seed=33)
+    geo, rest = args[1:3], args[3:]
+    scales = jnp.asarray([1.0, -0.5], jnp.float32)
+
+    def grads(op):
+        def body(carry, scale):
+            g = jax.grad(lambda x_, w_: jnp.sum(
+                op((x_ * scale, *geo, *w_)) ** 2), argnums=(0, 1))(
+                    args[0], rest)
+            return carry, g
+        return jax.jit(lambda: jax.lax.scan(body, 0.0, scales)[1])()
+
+    got = grads(lambda a: _chain_fused(a, s, r, valid))
+    want = grads(lambda a: _chain_composed(a, s, r))
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        scale = float(np.abs(np.asarray(b)).max()) + 1e-6
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, atol=3e-5)
+
+
+def _kernel_eqns(fn, *args):
+    """``{kernel name: [pallas_call equations]}`` of ``fn``'s jaxpr."""
+    out = {}
+    for eq, _stack in _eqns_with_stacks(jax.make_jaxpr(fn)(*args).jaxpr):
+        if eq.primitive.name == "pallas_call":
+            out.setdefault(eq.params["name"], []).append(eq)
+    return out
+
+
+def _count_in_kernel(eqn, *prims):
+    return sum(e.primitive.name in prims
+               for e, _s in _eqns_with_stacks(eqn.params["jaxpr"]))
+
+
+def test_chain_backward_writes_dgeo_only_when_geometry_is_differentiated():
+    """``symbolic_zeros``: with rbf and the cutoff from batch data (both
+    cells) the backward kernel has NO edge-sized output; differentiating
+    them (positions: force training) adds exactly the ``dgeo`` stream."""
+    args, s, r, valid = _chain_problem("ragged", seed=34)
+    e_pad = -(-int(s.shape[0]) // _EB) * _EB
+
+    def edge_outputs(argnums):
+        def loss(*a):
+            return jnp.sum(_chain_fused(a, s, r, valid) ** 2)
+        (bwd,) = _kernel_eqns(jax.grad(loss, argnums=argnums),
+                              *args)["gather_mul_seg_bwd"]
+        return [v.aval.shape for v in bwd.outvars
+                if v.aval.shape[0] == e_pad]
+
+    assert edge_outputs((0, 3, 4, 5, 6)) == []
+    assert edge_outputs((0, 1, 2, 3, 4, 5, 6)) == [(e_pad, 128)]
+    assert edge_outputs((0, 2)) == [(e_pad, 128)]       # the cutoff alone
+
+
+@pytest.mark.parametrize("form", ["array", "wless"])
+def test_array_and_wless_kernels_keep_their_shape(form):
+    """The chain is a STATIC third source: the other callers' kernels
+    (GAT, DimeNet, GIN / SAGE / MFC, poly_mp's sum-only backward) trace as
+    before it existed — the same operands and streams, the same
+    contractions, no filter network."""
+    from hydragnn_tpu.ops.fused_mp import gather_segment_sum
+
+    x, w, s, r, valid = _synthetic_edges("straddle", 3, seed=35)
+    if form == "array":
+        def loss(x_, w_):
+            return jnp.sum(gather_mul_segment_sum(
+                x_, w_, s, r, edge_valid=valid) ** 2)
+        # tables + ids + multiplier (+ g) + windows -> outputs; dots
+        want = {"gather_mul_seg_fwd": (4 + 3 + 3, 1, 2),
+                "gather_mul_seg_bwd": (5 + 4 + 3, 2, 3)}
+    else:
+        def loss(x_, w_):
+            return jnp.sum(gather_segment_sum(x_, s, r, valid) ** 2)
+        want = {"gather_mul_seg_fwd": (4 + 3 + 3, 1, 2),
+                "gather_mul_seg_bwd": (5 + 4, 1, 2)}
+    kernels = _kernel_eqns(jax.grad(loss), x, w)
+    assert set(kernels) == set(want)
+    for name, (n_in, n_out, n_dot) in want.items():
+        (eq,) = kernels[name]
+        assert (len(eq.invars), len(eq.outvars)) == (n_in, n_out), name
+        assert _count_in_kernel(eq, "dot_general") == n_dot, name
+        assert _count_in_kernel(eq, "logistic", "log1p", "exp",
+                                "custom_jvp_call") == 0, name
+
+
+def test_schnet128_train_step_keeps_the_filter_inside_the_kernels(
+        monkeypatch):
+    """Structure of the benchmark's step, no chip needed: a fused SchNet
+    train step at 128 filters holds ONE ``gather_mul_seg_fwd`` and ONE
+    ``gather_mul_seg_bwd`` per conv layer, and outside the kernels no
+    contraction or reduction reads an ``[E, F]`` operand — the filter
+    network, its pre-activation and its cotangents exist only in VMEM."""
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.trainer import (
+        create_train_state, make_train_step)
+
+    monkeypatch.setenv("HYDRAGNN_AGGR_BACKEND", "fused")
+    layers, f = 3, 128
+    cfg = ModelConfig(
+        model_type="SchNet", input_dim=1, hidden_dim=32, output_dim=(1,),
+        output_type=("graph",), graph_head=GraphHeadCfg(1, 16, 1, (16,)),
+        node_head=None, task_weights=(1.0,), num_conv_layers=layers,
+        num_gaussians=50, num_filters=f, radius=1.4, max_neighbours=10)
+    model = create_model(cfg)
+    batch = _batch(seed=36)
+    e = batch.senders.shape[0]
+    e_sizes = {e, -(-e // _EB) * _EB}
+    assert batch.x.shape[0] not in e_sizes
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
+    state = jax.eval_shape(
+        lambda b: create_train_state(model, b, opt), batch)
+    step = make_train_step(model, cfg, opt, telemetry_metrics=True,
+                           nonfinite_guard=True)
+
+    outside = [eq for eq, _s in _eqns_with_stacks(
+        jax.make_jaxpr(step)(state, batch).jaxpr, into_kernels=False)]
+    kernels = [eq.params["name"] for eq in outside
+               if eq.primitive.name == "pallas_call"]
+    assert kernels.count("gather_mul_seg_fwd") == layers, kernels
+    assert kernels.count("gather_mul_seg_bwd") == layers, kernels
+    for eqn in outside:
+        name = eqn.primitive.name
+        if name != "dot_general" and not name.startswith("reduce_"):
+            continue
+        for v in eqn.invars:
+            shape = getattr(v.aval, "shape", ())
+            assert not (len(shape) == 2 and shape[0] in e_sizes
+                        and shape[1] == f), (name, shape)
 
 
 def test_segment_sum_dense_exact():

@@ -1,6 +1,6 @@
-"""Parity tests for the fused CFConv edge pipeline (ops/scf_mp.py):
-forward, all gradients, and the model-level SCFConv wiring vs the
-composed path — interpret mode on CPU."""
+"""Parity tests for the fused CFConv edge pipeline (ops/scf_mp.py, the
+packing front of fused_mp's chain form): forward, all gradients, and the
+model-level SCFConv wiring vs the composed path — interpret mode on CPU."""
 
 import os
 
@@ -113,33 +113,44 @@ def test_gradients_match_composed():
                                    err_msg=name)
 
 
-def test_model_level_fused_equals_composed(monkeypatch):
-    """SCFConv with the pipeline forced on vs off: same params (the
-    _DenseParams tree matches the composed path's), same forward, same
-    param grads."""
+def _schnet(num_filters=F, num_gaussians=G, layers=2):
     from hydragnn_tpu.models.base import GraphHeadCfg, ModelConfig
     from hydragnn_tpu.models.create import create_model
 
-    g = _batch(seed=5)
-    cfg = ModelConfig(
+    return create_model(ModelConfig(
         model_type="SchNet", input_dim=2, hidden_dim=F, output_dim=(1,),
         output_type=("graph",), graph_head=GraphHeadCfg(1, 8, 1, (8,)),
-        node_head=None, task_weights=(1.0,), num_conv_layers=2,
-        num_gaussians=G, num_filters=F, radius=1.4, max_neighbours=8)
-    model = create_model(cfg)
-    monkeypatch.setenv("HYDRAGNN_SCF_FUSED", "1")
+        node_head=None, task_weights=(1.0,), num_conv_layers=layers,
+        num_gaussians=num_gaussians, num_filters=num_filters, radius=1.4,
+        max_neighbours=8))
+
+
+def _unmarked(g):
+    """The same batch as the ``scatter`` backend collates it: without the
+    verified-invariants marker every op takes its composed path."""
+    return g.replace(extras={k: v for k, v in g.extras.items()
+                              if k != "edge_perm_sender"})
+
+
+def test_model_level_fused_equals_composed():
+    """SCFConv on the fused path (the filter made inside the kernels) vs
+    the composed path, chosen by the batch's marker as the backends do:
+    same params (the DenseParams tree matches), same forward, same param
+    grads."""
+    g = _batch(seed=5)
+    model = _schnet()
     variables = model.init({"params": jax.random.PRNGKey(0)}, g, train=False)
 
-    def loss(params, fused):
-        monkeypatch.setenv("HYDRAGNN_SCF_FUSED", "1" if fused else "0")
-        out = model.apply({"params": params}, g, train=False)
+    def loss(params, batch):
+        out = model.apply({"params": params}, batch, train=False)
         return sum(jnp.sum(o * o) for o in out)
 
-    lf, lg = loss(variables["params"], True), loss(variables["params"], False)
+    lf, lg = loss(variables["params"], g), loss(variables["params"],
+                                                _unmarked(g))
     np.testing.assert_allclose(float(lf), float(lg), rtol=2e-5)
 
-    gf = jax.grad(lambda p: loss(p, True))(variables["params"])
-    gp = jax.grad(lambda p: loss(p, False))(variables["params"])
+    gf = jax.grad(lambda p: loss(p, g))(variables["params"])
+    gp = jax.grad(lambda p: loss(p, _unmarked(g)))(variables["params"])
     flat_f = jax.tree_util.tree_leaves_with_path(gf)
     flat_p = dict(jax.tree_util.tree_leaves_with_path(gp))
     assert flat_f  # same tree structure both ways
@@ -149,29 +160,52 @@ def test_model_level_fused_equals_composed(monkeypatch):
             err_msg=str(path))
 
 
-def test_pipeline_gate_defaults(monkeypatch):
-    from hydragnn_tpu.models.schnet import _scf_pipeline_enabled
+def test_filter_dispatch_follows_shape_and_backend():
+    """Where the filter is made is chosen by what the code can observe —
+    the batch's marker and the structural limits — with no width floor and
+    no knob: narrow filters run in the kernels too; a basis wider than the
+    geometry tile or filters beyond the VMEM limit keep the composed
+    filter (and still ride the array kernel); the scatter backend
+    composes everything.  Each choice is tallied."""
+    from hydragnn_tpu.graph.segment import CFFilter
+    from hydragnn_tpu.telemetry import pipeline
 
-    # the defaults must be judged with the env override ABSENT — a
-    # developer's ambient HYDRAGNN_SCF_FUSED=1 would flip the first assert
-    monkeypatch.delenv("HYDRAGNN_SCF_FUSED", raising=False)
-    assert not _scf_pipeline_enabled(64, 50)       # narrow: composed wins
-    assert _scf_pipeline_enabled(256, 50)          # wide: pipeline on
-    assert not _scf_pipeline_enabled(2048, 50)     # beyond VMEM limit
-    assert not _scf_pipeline_enabled(512, 200)     # basis exceeds lanes
-    monkeypatch.setenv("HYDRAGNN_SCF_FUSED", "1")
-    assert _scf_pipeline_enabled(64, 50)           # forced on
-    monkeypatch.setenv("HYDRAGNN_SCF_FUSED", "0")
-    assert not _scf_pipeline_enabled(1024, 50)     # forced off
+    def fits(f, gauss):
+        z = jnp.zeros
+        return CFFilter(z((4, gauss)), z((4,)), z((gauss, f)), z((f,)),
+                        z((f, f)), z((f,))).fits_kernel()
+
+    assert fits(64, 50) and fits(128, 50) and fits(1024, 127)
+    assert not fits(2048, 50)       # [F, F] blocks beyond VMEM
+    assert not fits(512, 200)       # basis exceeds the geometry lanes
+
+    g = _batch(seed=12)
+
+    def tally(batch, **kw):
+        model = _schnet(**kw)
+        before = pipeline.dispatch_snapshot()
+        jax.eval_shape(lambda: model.init(
+            {"params": jax.random.PRNGKey(0)}, batch, train=False))
+        return pipeline.dispatch_delta(before, pipeline.dispatch_snapshot())
+
+    fused = tally(g)
+    assert fused["gather_mul:fused"] == 2 == fused["gather_mul_filter:fused"]
+    assert "gather_mul_filter:scatter" not in fused
+    wide_basis = tally(g, num_gaussians=200)
+    assert wide_basis["gather_mul:fused"] == 2
+    assert wide_basis["gather_mul_filter:scatter"] == 2
+    composed = tally(_unmarked(g))
+    assert composed["gather_mul:scatter"] == 2
+    assert composed["gather_mul_filter:scatter"] == 2
 
 
 def test_bf16_gradients_within_tolerance():
     """bf16 models run the fused filter MLP and ALL backward matmuls
     (incl. dW0/dW1 weight grads and drbf) with bf16 operands, while the
-    composed path they replace evaluates the filter chain in f32 — the
-    pipeline is default-on at num_filters >= 256, so switching widths
-    silently changes filter numerics.  This pins the bf16 gradient drift
-    against the f32 composed reference (round-4 advisor finding 1)."""
+    composed path they replace evaluates the filter chain in f32 — so
+    switching backends changes filter numerics beyond the stream dtype.
+    This pins the bf16 gradient drift against the f32 composed reference
+    (round-4 advisor finding 1)."""
     g = _batch(seed=9)
     h, rbf, cm, w0, b0, w1, b1 = _inputs(g, seed=10)
     perm = jnp.asarray(g.extras["edge_perm_sender"])

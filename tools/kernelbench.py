@@ -32,8 +32,9 @@ Four moment sets:
             the EGNN mainline-MFU claim.  The fused rows are Pallas
             (skipped off-TPU without --force-pallas); bf16 carries the
             same CPU-emulation caveat as matmul.
-  scf       SchNet's continuous-filter convolution (ops/scf_mp.py, a
-            spec on the fused-block builder): composed chain (filter MLP
+  scf       SchNet's continuous-filter convolution (ops/scf_mp.py: the
+            filter network made inside fused_mp's gather-multiply
+            kernels): composed chain (filter MLP
             on the rbf expansion -> cutoff multiply -> gather-multiply ->
             segment sum) vs the one fused pass, f32 and bf16.
   gatfused  GATv2 edge attention (ops/gat_mp.py): composed chain (two
@@ -257,7 +258,8 @@ def _backends(moments, receivers, mask, num_nodes, on_tpu, force_pallas,
         return out
 
     if moments == "scf":
-        # SchNet continuous-filter conv: composed vs the builder spec.
+        # SchNet continuous-filter conv: composed vs the filter network
+        # made inside the gather-multiply kernels (ops/scf_mp.py).
         from hydragnn_tpu.models.layers import shifted_softplus
         from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
 

@@ -10,9 +10,16 @@ not as a spent chip call.  It proves COMPILATION only: whether the numbers
 are right, and every time or rate, still needs the chip
 (``python chip_smoke.py`` through the chip tool).
 
-    python tools/mosaic_aot.py                       # every arch h128 f32
+    python tools/mosaic_aot.py                       # the default list
     python tools/mosaic_aot.py SchNet:1024:bfloat16  # ARCH:HIDDEN:DTYPE
     python tools/mosaic_aot.py PNA:512:float32:highest   # + matmul precision
+    python tools/mosaic_aot.py cfconv:128:float32:shard_map  # the CFConv
+        # op's gradient (filter made in the kernels) under shard_map on
+        # the 2x2 topology, as the four-chip DP step runs it
+
+The default list is every arch at h128 f32 plus the widths SchNet's
+in-kernel filter network chooses its edge blocks for (128 at ``highest``;
+256 / 512 / 1024 in f32 and bf16; 1024 at ``highest``) and the sharded op.
 
 Exit code 0 only if every target compiled.
 """
@@ -69,6 +76,53 @@ def compile_target(arch: str, hidden: int, dtype: str, precision, sharding):
     return calls, time.perf_counter() - t0
 
 
+def compile_cfconv_sharded(filters: int, dtype: str, devices):
+    """Lower + compile the gradient of ``scf_edge_pipeline`` (wrt the
+    features and the filter weights, all-reduced) under ``shard_map`` over
+    all of ``devices`` — one flagship-sized edge list per device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
+
+    mesh = Mesh(np.asarray(devices), ("dp",))
+    d, n, e, g = len(devices), 10240, 196608, 50
+    per_dev = NamedSharding(mesh, P("dp"))
+    shared = NamedSharding(mesh, P())
+
+    def arg(shape, dt, sharding):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    ids = arg((d, e), jnp.int32, per_dev)
+    args = (arg((d, n, filters), jnp.dtype(dtype), per_dev),
+            arg((d, e, g), jnp.float32, per_dev),
+            arg((d, e), jnp.float32, per_dev), ids,
+            arg((g, filters), jnp.float32, shared),
+            arg((filters,), jnp.float32, shared),
+            arg((filters, filters), jnp.float32, shared),
+            arg((filters,), jnp.float32, shared), ids, ids)
+
+    def per_device(h, rbf, cm, em, k0, b0, k1, b1, send, recv):
+        def loss(h_, w_):
+            out = scf_edge_pipeline(h_[0], rbf[0], cm[0], em[0], *w_,
+                                    send[0], recv[0])
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        dh, dw = jax.grad(loss, argnums=(0, 1))(h, (k0, b0, k1, b1))
+        return dh, jax.lax.psum(dw, "dp")
+
+    fn = jax.jit(jax.shard_map(
+        per_device, mesh=mesh,
+        in_specs=(P("dp"),) * 4 + (P(),) * 4 + (P("dp"),) * 2,
+        out_specs=(P("dp"), P()), check_vma=False))
+    lowered = fn.lower(*args)
+    calls = lowered.as_text().count("tpu_custom_call")
+    t0 = time.perf_counter()
+    lowered.compile()
+    return calls, time.perf_counter() - t0
+
+
 def main(argv) -> int:
     import jax
     from jax.experimental import topologies
@@ -85,15 +139,26 @@ def main(argv) -> int:
     # process backend is the CPU, so say "tpu" while tracing for the chip
     jax.default_backend = lambda: "tpu"
 
-    targets = argv or ([f"{a}:128:float32" for a in ALL_ARCHS]
-                       + ["SchNet:1024:bfloat16"])
+    targets = argv or (
+        [f"{a}:128:float32" for a in ALL_ARCHS]
+        + ["SchNet:128:float32:highest"]
+        + [f"SchNet:{w}:{dt}" for w in (256, 512, 1024)
+           for dt in ("float32", "bfloat16")]
+        # ``highest`` adds the f32 dots' multi-pass scratch: the widest
+        # kernels under it are the ones nearest the VMEM limit
+        + ["SchNet:1024:float32:highest", "SchNet:1024:bfloat16:highest",
+           "cfconv:128:float32:shard_map"])
     failed = 0
     for t in targets:
         arch, hidden, dtype, *rest = t.split(":")
         try:
-            calls, secs = compile_target(
-                arch, int(hidden), dtype, rest[0] if rest else None,
-                SingleDeviceSharding(dev))
+            if rest == ["shard_map"]:
+                calls, secs = compile_cfconv_sharded(
+                    int(hidden), dtype, topo.devices)
+            else:
+                calls, secs = compile_target(
+                    arch, int(hidden), dtype, rest[0] if rest else None,
+                    SingleDeviceSharding(dev))
         except Exception as e:  # noqa: BLE001 — the compiler's message IS the result; reported and counted
             failed += 1
             print(f"FAIL {t}: {type(e).__name__}: {str(e)[:4000]}",
