@@ -395,20 +395,6 @@ def stage_serve(args, sizes, config, fconfig, info, pred_ref):
 # -- kernel stage -----------------------------------------------------------
 
 
-@contextlib.contextmanager
-def _aggr_backend(name: str):
-    """Scoped HYDRAGNN_AGGR_BACKEND (collate and every trace read it)."""
-    prior = os.environ.get("HYDRAGNN_AGGR_BACKEND")
-    os.environ["HYDRAGNN_AGGR_BACKEND"] = name
-    try:
-        yield
-    finally:
-        if prior is None:
-            os.environ.pop("HYDRAGNN_AGGR_BACKEND", None)
-        else:
-            os.environ["HYDRAGNN_AGGR_BACKEND"] = prior
-
-
 def _kernel_samples(arch: str, samples, hidden: int, radius: float,
                     seed: int):
     """The kernel batch's graphs for ``arch``.  PNA and CGCNN consume edge
@@ -478,8 +464,9 @@ def _kernel_batch(arch: str, samples, backend: str):
     collate attach the sender-sort marker the kernels dispatch on)."""
     from hydragnn_tpu.graph.batch import HeadSpec, collate
     from hydragnn_tpu.data.dataloader import pad_spec_for
+    from hydragnn_tpu.ops.aggregate import backend_scope
 
-    with _aggr_backend(backend):
+    with backend_scope(backend):
         batch = collate(samples, pad_spec_for(samples, len(samples)),
                         [HeadSpec("energy_per_atom", "graph", 1)])
         if arch == "DimeNet":
@@ -508,13 +495,14 @@ def _one_step(model, cfg, opt_spec, state, batch, backend: str,
     dispatch tally of this trace)."""
     import jax
 
+    from hydragnn_tpu.ops.aggregate import backend_scope
     from hydragnn_tpu.telemetry import pipeline
     from hydragnn_tpu.train.trainer import make_train_step
 
     ctx = (jax.default_matmul_precision(precision) if precision
            else contextlib.nullcontext())
     before = pipeline.dispatch_snapshot()
-    with _aggr_backend(backend), ctx:
+    with backend_scope(backend), ctx:
         step = jax.jit(make_train_step(model, cfg, opt_spec,
                                        telemetry_metrics=True))
         lowered = step.lower(state, batch)
@@ -538,6 +526,7 @@ def _kernel_row(arch, hidden, dtype, layers, arch_sec, samples, seed,
     import jax
 
     from hydragnn_tpu.models.create import create_model
+    from hydragnn_tpu.ops.aggregate import backend_scope
     from hydragnn_tpu.telemetry import pipeline
     from hydragnn_tpu.train.optimizer import select_optimizer
     from hydragnn_tpu.train.trainer import create_train_state
@@ -557,7 +546,7 @@ def _kernel_row(arch, hidden, dtype, layers, arch_sec, samples, seed,
     b_plain = _kernel_batch(arch, ksamples, "scatter")
     check("edge_perm_sender" in b_fused.extras,
           "collate did not attach the fused-kernel marker")
-    with _aggr_backend("scatter"):
+    with backend_scope("scatter"):
         # f32 master params made on the composed path: init must not
         # depend on the path under test
         state = create_train_state(model_ref, b_plain, opt_spec, seed=seed)

@@ -159,7 +159,8 @@ _KNOB_LIST = [
     # -- kernels / fused-path gates --------------------------------------
     _k("HYDRAGNN_AGGR_BACKEND", "", "scatter",
        "hydragnn_tpu/ops/aggregate.py",
-       "aggregation backend: fused (Pallas) | scatter (XLA)"),
+       "aggregation backend, one of two: scatter (XLA) | fused (Pallas "
+       "where collate's marker and the widths allow)"),
     _k("HYDRAGNN_GAT_FUSED", "", "auto",
        "hydragnn_tpu/models/gat.py",
        "GAT fused edge-attention gate"),
@@ -180,9 +181,6 @@ _KNOB_LIST = [
     _k("HYDRAGNN_DN_ROW_MLP_OFF", "", "0",
        "hydragnn_tpu/models/dimenet.py",
        "disable the fused residual-MLP tail"),
-    _k("HYDRAGNN_DIMENET_REMAT", "", "0",
-       "hydragnn_tpu/models/dimenet.py",
-       "remat DimeNet interaction blocks"),
     # -- telemetry --------------------------------------------------------
     _k("HYDRAGNN_TELEMETRY", "Telemetry.enable", "0",
        "hydragnn_tpu/telemetry/logger.py",
@@ -738,10 +736,6 @@ _KERNEL_LIST = [
         "GAT edge attention backward, receiver order"),
     _kn("gat_attn_bwd_s", "hydragnn_tpu/ops/gat_mp.py",
         "GAT edge attention backward, sender order"),
-    _kn("seg_sum_pallas_fwd", "hydragnn_tpu/ops/aggregate.py",
-        "one-hot segment sum, any id order"),
-    _kn("seg_sum_sorted_fwd", "hydragnn_tpu/ops/aggregate.py",
-        "block-range segment sum over sorted ids"),
 ]
 
 KERNEL_NAMES: Dict[str, KernelName] = {k.name: k for k in _KERNEL_LIST}

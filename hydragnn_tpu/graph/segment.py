@@ -9,10 +9,11 @@ so message passing is expressed as gather (``x[senders]``) + segment reduce at
 All ops take an optional mask (1.0 = valid) so padded edges/nodes contribute
 nothing — this is what makes padded static-shape batching exact.
 
-``segment_sum`` (and everything built on it) honors HYDRAGNN_AGGR_BACKEND
-(parity: reference train_validate_test.py:373-378): ``scatter`` (default XLA
-scatter), ``onehot`` (MXU one-hot matmul), or ``pallas`` (blocked Pallas
-kernel) — see hydragnn_tpu/ops/aggregate.py.
+``segment_sum`` is the mask and ``jax.ops.segment_sum``, whatever the
+aggregation backend; HYDRAGNN_AGGR_BACKEND=fused (parity: reference
+train_validate_test.py:373-378; hydragnn_tpu/ops/aggregate.py) moves the
+gather-multiply-segment core below into one Pallas pass when the batch
+carries collate's marker.
 """
 
 from __future__ import annotations
@@ -38,17 +39,6 @@ def _count(op: str, fused: bool) -> None:
 def segment_sum(data, segment_ids, num_segments, mask=None):
     if mask is not None:
         data = data * _bcast(mask, data)
-    from hydragnn_tpu.ops.aggregate import (
-        aggr_backend,
-        segment_sum_onehot,
-        segment_sum_pallas,
-    )
-
-    backend = aggr_backend()
-    if backend == "onehot" and jnp.issubdtype(data.dtype, jnp.floating):
-        return segment_sum_onehot(data, segment_ids, num_segments)
-    if backend == "pallas" and jnp.issubdtype(data.dtype, jnp.floating):
-        return segment_sum_pallas(data, segment_ids, num_segments)
     return jax.ops.segment_sum(data, segment_ids, num_segments)
 
 

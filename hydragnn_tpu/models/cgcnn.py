@@ -24,6 +24,7 @@ import flax.linen as nn
 from hydragnn_tpu.graph import segment
 from hydragnn_tpu.models.base import Base
 from hydragnn_tpu.models.layers import DenseParams
+from hydragnn_tpu.ops.aggregate import aggr_backend
 from hydragnn_tpu.ops.fused_block import note_fallback
 
 
@@ -45,8 +46,7 @@ def _cgcnn_pipeline_enabled(dim: int, edge_dim: int) -> bool:
 
 
 def _cgcnn_fused_wanted() -> bool:
-    if os.environ.get("HYDRAGNN_AGGR_BACKEND", "").strip().lower() \
-            == "fused":
+    if aggr_backend() == "fused":
         return True
     v = os.environ.get("HYDRAGNN_CGCNN_FUSED")
     return v is not None and v.strip().lower() not in (

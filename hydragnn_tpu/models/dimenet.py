@@ -559,7 +559,7 @@ class InteractionPPBlock(nn.Module):
             # first) was measured 12 ms/step SLOWER on the v5e sweep
             # config.  The rbf->triplet gather in spherical_basis keeps the
             # perm: its backward only runs under pos-grad (force training),
-            # where the dense path halves the cost (tools/profile_dimenet2.py).
+            # where the dense path halves the cost.
             msg = x_kj[idx_kj] * sbf_emb * triplet_mask[:, None]
             # build_triplets emits idx_ji in nondecreasing order (outer
             # loop over edge ids) — the dense-schedule sorted scatter
@@ -737,20 +737,7 @@ class DIMEStack(Base):
 
     def make_conv(self, name, in_dim, out_dim, last_layer):
         c = self.cfg
-        # HYDRAGNN_DIMENET_REMAT=1 rematerializes each conv in the
-        # backward.  Measured and REJECTED as a default on the v5e sweep
-        # config (92.0 vs 65.0 ms/step): although the step moves ~9.4 GB
-        # of residuals (round-4 attribution), remat re-evaluates the
-        # spherical basis inside every layer's backward — losing the
-        # cross-layer CSE that normally computes it once — and the
-        # recompute costs more than the saved HBM round-trips.  Kept as an
-        # opt-in for memory-limited configs (wide OC20-scale batches).
-        from hydragnn_tpu.utils.env import env_flag
-
-        cls = DimeNetConv
-        if env_flag("HYDRAGNN_DIMENET_REMAT"):
-            cls = nn.remat(DimeNetConv, static_argnums=(3,))
-        return cls(
+        return DimeNetConv(
             in_dim=in_dim,
             out_dim=out_dim,
             num_radial=c.num_radial,

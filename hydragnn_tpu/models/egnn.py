@@ -24,6 +24,7 @@ import flax.linen as nn
 from hydragnn_tpu.graph import segment
 from hydragnn_tpu.models.base import Base
 from hydragnn_tpu.models.layers import DenseParams, edge_geometry
+from hydragnn_tpu.ops.aggregate import aggr_backend
 from hydragnn_tpu.ops.fused_block import note_fallback
 
 
@@ -51,8 +52,7 @@ def _egcl_pipeline_enabled(features: int, hidden: int, geo_dim: int) -> bool:
 def _egcl_fused_wanted() -> bool:
     """Did the operator ask for the fused data layout?  Either knob
     counts: the global aggregation backend or the EGCL-specific force."""
-    if os.environ.get("HYDRAGNN_AGGR_BACKEND", "").strip().lower() \
-            == "fused":
+    if aggr_backend() == "fused":
         return True
     v = os.environ.get("HYDRAGNN_EGCL_FUSED")
     return v is not None and v.strip().lower() not in (

@@ -1,8 +1,3 @@
-"""TPU kernels and backend-selectable aggregation primitives."""
+"""TPU kernels and the aggregation-backend decision."""
 
-from hydragnn_tpu.ops.aggregate import (  # noqa: F401
-    aggr_backend,
-    segment_sum_onehot,
-    segment_sum_pallas,
-    segment_sum_sorted,
-)
+from hydragnn_tpu.ops.aggregate import aggr_backend  # noqa: F401

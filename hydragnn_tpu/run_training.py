@@ -44,8 +44,8 @@ def _(config: dict, logs_dir: str = "./logs/", seed: int = 0):
     os.environ.setdefault("SERIALIZED_DATA_PATH", os.getcwd())
 
     # aggregation-backend plumbing: ``Architecture.aggregation_backend``
-    # pins the segment-op backend (scatter | onehot | pallas | fused) for
-    # config-driven runs; a USER-set HYDRAGNN_AGGR_BACKEND env knob wins.
+    # pins the message-passing backend (scatter | fused) for config-driven
+    # runs; a USER-set HYDRAGNN_AGGR_BACKEND env knob wins.
     # Must happen BEFORE data loading and tracing: collate attaches the
     # fused-kernel marker at batch-build time, and jitted steps pin
     # whichever backend was active when first traced
@@ -54,7 +54,7 @@ def _(config: dict, logs_dir: str = "./logs/", seed: int = 0):
     # (docs/TELEMETRY.md).
     backend = (config.get("NeuralNetwork", {}).get("Architecture", {})
                .get("aggregation_backend"))
-    from hydragnn_tpu.ops.aggregate import KNOWN_BACKENDS
+    from hydragnn_tpu.ops.aggregate import KNOWN_BACKENDS, backend_scope
 
     if backend and str(backend) not in KNOWN_BACKENDS:
         # a typo ('fusd') would otherwise silently degrade every op to
@@ -62,18 +62,11 @@ def _(config: dict, logs_dir: str = "./logs/", seed: int = 0):
         raise ValueError(
             f"Architecture.aggregation_backend {backend!r} is not one of "
             f"{KNOWN_BACKENDS}")
-    # SCOPED export: the config's choice applies only for the duration of
-    # this run (restored on every exit path), so it can never masquerade
-    # as a user-set knob for a later run in the same process (HPO loops,
-    # notebooks) — and a user-set value is never touched
-    exported = bool(backend) and "HYDRAGNN_AGGR_BACKEND" not in os.environ
-    if exported:
-        os.environ["HYDRAGNN_AGGR_BACKEND"] = str(backend)
-    try:
+    # scoped to this run, so the config's choice can never masquerade as a
+    # user-set knob for a later run in the same process (HPO loops,
+    # notebooks)
+    with backend_scope(backend, override=False):
         return _run_training_dict(config, logs_dir, seed)
-    finally:
-        if exported:
-            os.environ.pop("HYDRAGNN_AGGR_BACKEND", None)
 
 
 def _run_training_dict(config: dict, logs_dir: str, seed: int):

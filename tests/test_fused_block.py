@@ -21,8 +21,6 @@ Sections:
 Interpret mode on CPU, production collate invariants throughout.
 """
 
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -33,6 +31,7 @@ from hydragnn_tpu.graph.batch import GraphSample, HeadSpec, PadSpec, collate
 from hydragnn_tpu.graph.neighborlist import radius_graph
 from hydragnn_tpu.models.base import GraphHeadCfg, ModelConfig
 from hydragnn_tpu.models.create import ALL_ARCHS, create_model
+from hydragnn_tpu.ops.aggregate import backend_scope
 from hydragnn_tpu.ops.egcl_mp import egcl_block
 from hydragnn_tpu.ops.fused_mp import gather_mul_segment_sum
 from hydragnn_tpu.ops.poly_mp import gather_poly_segment, segment_poly_dense
@@ -397,15 +396,8 @@ def _egcl_batch(n_graphs=6, nodes=9, seed=0, isolate=False):
             graph_y=rng.rand(1).astype(np.float32)))
     pad = PadSpec.for_batch(n_graphs, nodes,
                             max(s.num_edges for s in samples))
-    prev = os.environ.get("HYDRAGNN_AGGR_BACKEND")
-    os.environ["HYDRAGNN_AGGR_BACKEND"] = "fused"
-    try:
+    with backend_scope("fused"):
         return collate(samples, pad, [HeadSpec("e", "graph", 1)])
-    finally:
-        if prev is None:
-            os.environ.pop("HYDRAGNN_AGGR_BACKEND", None)
-        else:
-            os.environ["HYDRAGNN_AGGR_BACKEND"] = prev
 
 
 def _egcl_inputs(g, seed=1, edge_attr_dim=0):

@@ -3,8 +3,6 @@ path: forward parity, gradient parity, dropout-bit parity, and model-level
 equivalence — interpret mode on CPU, same collate invariants as production.
 """
 
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -13,6 +11,7 @@ import pytest
 from hydragnn_tpu.graph import segment
 from hydragnn_tpu.graph.batch import GraphSample, HeadSpec, PadSpec, collate
 from hydragnn_tpu.graph.neighborlist import radius_graph
+from hydragnn_tpu.ops.aggregate import backend_scope
 from hydragnn_tpu.ops.gat_mp import gat_edge_attention
 
 
@@ -32,15 +31,8 @@ def _batch(n_graphs=6, nodes=9, seed=0):
     pad = PadSpec.for_batch(n_graphs, nodes,
                             max(s.num_edges for s in samples))
     # collate attaches edge_perm_sender only under the fused backend
-    prev = os.environ.get("HYDRAGNN_AGGR_BACKEND")
-    os.environ["HYDRAGNN_AGGR_BACKEND"] = "fused"
-    try:
+    with backend_scope("fused"):
         return collate(samples, pad, [HeadSpec("e", "graph", 1)])
-    finally:
-        if prev is None:
-            os.environ.pop("HYDRAGNN_AGGR_BACKEND", None)
-        else:
-            os.environ["HYDRAGNN_AGGR_BACKEND"] = prev
 
 
 def _inputs(g, seed=1):

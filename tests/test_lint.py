@@ -159,6 +159,32 @@ def test_knob_docs_generated_current():
         "`python tools/graftlint.py --emit-docs`")
 
 
+def test_aggr_backend_knob_has_one_reader():
+    """The aggregation-path decision is read in ONE place.  An
+    ``os.environ`` access needs the knob's name as a whole string constant
+    (prose mentions are longer strings): under hydragnn_tpu/ only
+    ops/aggregate.py holds one, and that module is what asks
+    ``os.environ``."""
+    knob = "HYDRAGNN_AGGR_BACKEND"
+    assert knob in KNOBS
+    holders = {}
+    for path in _iter_repo_py():
+        if (f"{os.sep}hydragnn_tpu{os.sep}" not in path
+                or path.endswith(os.path.join("analysis", "registry.py"))):
+            continue
+        tree = ast.parse(open(path, encoding="utf-8").read())
+        if any(isinstance(n, ast.Constant) and n.value == knob
+               for n in ast.walk(tree)):
+            holders[os.path.relpath(path, REPO)] = tree
+    reader = os.path.join("hydragnn_tpu", "ops", "aggregate.py")
+    assert sorted(holders) == [reader], (
+        f"{knob} is named outside {reader}: ask aggr_backend() / "
+        f"backend_scope() there instead — {sorted(holders)}")
+    assert any(isinstance(n, ast.Attribute) and n.attr == "environ"
+               and isinstance(n.value, ast.Name) and n.value.id == "os"
+               for n in ast.walk(holders[reader]))
+
+
 def test_health_kind_registry_exhaustive():
     """AST-extracted health(kind=...) literals are a subset of the
     declared registry; every declared kind is documented and emitted."""

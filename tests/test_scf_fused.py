@@ -2,8 +2,6 @@
 packing front of fused_mp's chain form): forward, all gradients, and the
 model-level SCFConv wiring vs the composed path — interpret mode on CPU."""
 
-import os
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -11,6 +9,7 @@ import pytest
 
 from hydragnn_tpu.graph.batch import GraphSample, HeadSpec, PadSpec, collate
 from hydragnn_tpu.graph.neighborlist import radius_graph
+from hydragnn_tpu.ops.aggregate import backend_scope
 from hydragnn_tpu.ops.scf_mp import scf_edge_pipeline
 from hydragnn_tpu.models.layers import shifted_softplus
 
@@ -28,15 +27,8 @@ def _batch(n_graphs=6, nodes=9, seed=0):
             graph_y=rng.rand(1).astype(np.float32)))
     pad = PadSpec.for_batch(n_graphs, nodes,
                             max(s.num_edges for s in samples))
-    prev = os.environ.get("HYDRAGNN_AGGR_BACKEND")
-    os.environ["HYDRAGNN_AGGR_BACKEND"] = "fused"
-    try:
+    with backend_scope("fused"):
         return collate(samples, pad, [HeadSpec("e", "graph", 1)])
-    finally:
-        if prev is None:
-            os.environ.pop("HYDRAGNN_AGGR_BACKEND", None)
-        else:
-            os.environ["HYDRAGNN_AGGR_BACKEND"] = prev
 
 
 def _inputs(g, seed=1):

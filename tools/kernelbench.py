@@ -6,8 +6,6 @@ Compares, at the documented sweep shapes, the backends the dispatchers in
 graph/segment.py choose between:
 
   scatter   jax.ops.segment_sum (XLA sort/scatter path)
-  onehot    one-hot x messages MXU matmul (ops/aggregate.py)
-  pallas    blocked one-hot Pallas contraction (ops/aggregate.py)
   dense     sorted dense-schedule scatter (ops/fused_mp.segment_sum_dense)
   poly      fused multi-moment pass (ops/poly_mp.segment_poly_dense)
 
@@ -171,8 +169,6 @@ def _backends(moments, receivers, mask, num_nodes, on_tpu, force_pallas,
     import jax
     import jax.numpy as jnp
 
-    from hydragnn_tpu.ops.aggregate import (
-        segment_sum_onehot, segment_sum_pallas)
     from hydragnn_tpu.ops.fused_mp import segment_sum_dense
     from hydragnn_tpu.ops.poly_mp import segment_poly_dense
 
@@ -384,11 +380,8 @@ def _backends(moments, receivers, mask, num_nodes, on_tpu, force_pallas,
         out = {
             "scatter": lambda d: jax.ops.segment_sum(
                 d * m[:, None], r, num_segments=n),
-            "onehot": lambda d: segment_sum_onehot(d * m[:, None], r, n),
         }
         if run_pallas:
-            out["pallas"] = lambda d: segment_sum_pallas(
-                d * m[:, None], r, n)
             out["dense"] = lambda d: segment_sum_dense(
                 d * m[:, None], r, n, valid=m)
             out["poly"] = lambda d: segment_poly_dense(
