@@ -37,9 +37,10 @@ the same for all three:
   * an ARRAY ``w[E, F]`` streamed from HBM (:func:`gather_mul_segment_sum`
     — GAT's composed path, DimeNet's ``tri_window``, any caller whose
     multiplier already exists);
-  * NOTHING: the ``[E, 1]`` edge-mask column rides in ``w``'s slot and the
-    messages are the gathered features (:func:`gather_segment_sum` — the
-    GIN / SAGE / MFC sums, ``poly_mp``'s sum-only backward);
+  * NOTHING: no multiplier operand at all, the messages are the gathered
+    features and the ``[E]`` edge mask rides with the ids, inside the
+    scatter's one-hot (:func:`gather_segment_sum` — the GIN / SAGE / MFC
+    sums, ``poly_mp``'s sum-only backward);
   * a CHAIN (:func:`gather_chain_segment_sum`): per edge block an
     ``[BE, GPW]`` geometry tile and constant-mapped weight blocks, from
     which a pure-JAX ``chain(w_vals, geo, dt)`` makes ``w`` in VMEM, forward
@@ -47,6 +48,22 @@ the same for all three:
     ``[E, F]`` operand or cotangent exists in HBM: the geometry stream takes
     the ``w`` stream's place, the backward keeps ``dw`` in VMEM and pulls it
     back through the chain there (below).
+
+The edge ids enter both kernels LANE-MAJOR (PR 29): ONE int32 operand
+``[E_pad / 128 * 8, 128]`` (:func:`_pack_ids`) holds, per granule of 128
+consecutive edges, one (8, 128) tile — sublane 0 the senders, 1 the
+receivers, 2 the bits of the w-less form's f32 mask, the rest spare — so an
+edge block's ids are 32 B an edge of HBM traffic (16 KiB a 512-edge step),
+and every edge block either pass chooses (512 / 256 / 128) is a whole number
+of granules of the same operand.  (Two ``[E_pad, 1]`` columns, the layout
+before, are 128 lanes wide each as Mosaic operands: 512 KiB of the 768 a
+step moved.)  In VMEM a sublane of the block is a ``[1, BE]`` row, and the
+one-hots are built TRANSPOSED from it, ``[W * bn, BE]`` and ``[bn, BE]``
+(:func:`_onehot_t`: an integer compare against a sublane iota): the gathers
+are the transposed-lhs contraction, the scatters plain matmuls.  Measured on
+the v5e this beat turning the id tile into columns in VMEM by 19 % forward
+and 22 % on the gradient (PERF.md, PR 29).  The schedule and the XLA-side
+selects read the flat ``[E_pad]`` receivers.
 
 The grid is a DENSE CSR-style schedule: scalar-prefetched step tables map
 each grid step to one populated (node-block, edge-block) pair, so no step
@@ -57,7 +74,8 @@ steps are unconditionally <= edge blocks + 2 * node blocks).
 Backward: ONE pass (``gather_mul_seg_bwd``) on the same schedule, over the
 edge list in the order collate ships it — nothing is sorted by sender and
 XLA gathers, permutes or pads nothing E-sized.  Per (node block i, edge
-block) step, with the two one-hots the forward builds:
+block) step, with the two one-hots the forward builds (written here edges
+by nodes; in VMEM they are the transposes, above):
 ``g_r = onehot_r @ g_i`` (block-local, zero rows for the edges of other
 node blocks — the gate that counts every edge once), ``x_s = onehot_s @
 x_window``, ``dw = x_s * g_r`` streamed out per edge (first accumulated
@@ -68,7 +86,8 @@ the overlap-add of the ``P_i`` in node space (slot k of block i lands on
 block i - hw + k; slots off either end are dropped).  Edge blocks holding
 only parked edges are never entered, so their ``dw`` rows are unwritten
 memory, selected to exact zero.  The w-less op runs the same body without
-the ``x`` window and the ``dw`` stream.  The chain form recomputes ``w`` from
+the ``x`` window, the ``w`` block and the ``dw`` stream (its mask sits in the
+window one-hot).  The chain form recomputes ``w`` from
 its geometry tile, keeps ``dw`` in VMEM and pulls it back through the chain
 inside the same step (``jax.vjp`` on the chain body): the weight
 cotangents accumulate into constant-mapped f32 blocks zeroed at step 0 —
@@ -96,23 +115,69 @@ from hydragnn_tpu.ops.fused_block import _window_maps
 _EDGE_BLOCK = 512   # edges per inner step
 
 
+_LANES = 128       # edges per id granule: one (8, 128) int32 tile of HBM
+_ID_ROWS = 8       # its sublanes, of which three are used:
+_SEND, _RECV, _MASK = 0, 1, 2   # (2: the w-less form's f32 mask bits)
+
+
+def _onehot_t(ids_ref, k, base, n, masked=False):
+    """``[n, BE]`` f32: the one-hot of id set ``k`` of the edge block,
+    relative to ``base``, TRANSPOSED — edges along the lanes, as the ids
+    arrive.  Sublane ``k`` of the block's granules, side by side, is a
+    ``[1, BE]`` row; comparing it (as integers) with a sublane iota
+    broadcasts it down the sublanes, the cheap direction.  An id outside
+    ``[base, base + n)`` (another block's edge, a parked edge) is an
+    all-zero column.  ``masked`` puts the w-less form's edge-mask values
+    where the ones are (0 / 1 from every caller, so exact in the MXU pass
+    as the ones are)."""
+    granules = ids_ref.shape[0] // _ID_ROWS
+
+    def row(j):
+        return jnp.concatenate(
+            [ids_ref[_ID_ROWS * g + j:_ID_ROWS * g + j + 1, :]
+             for g in range(granules)], axis=1)
+
+    hit = (row(k) - base) == jax.lax.broadcasted_iota(
+        jnp.int32, (n, granules * _LANES), 0)
+    if not masked:
+        return hit.astype(jnp.float32)
+    return jnp.where(
+        hit, jax.lax.bitcast_convert_type(row(_MASK), jnp.float32), 0.0)
+
+
+def _gather(onehot_t, data):
+    """``data[ids - base]`` -> ``[BE, F]``: the transposed-lhs contraction
+    (zero rows where the one-hot has zero columns)."""
+    return jax.lax.dot_general(
+        onehot_t, data, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def _scatter(onehot_t, m):
+    """``sum_e onehot[e] * m[e]`` -> ``[n, F]``: a plain matmul."""
+    return jax.lax.dot_general(
+        onehot_t, m, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _multiplier(chain, src_refs, dt):
     """The edge block's multiplier from its source (module docstring): the
-    ``w`` block / the mask column as stored, or the chain evaluated in
-    VMEM from its geometry block and constant weight blocks."""
+    ``w`` block as stored, or the chain evaluated in VMEM from its geometry
+    block and constant weight blocks."""
     if chain is None:
         return src_refs[0][:].astype(jnp.float32)
     geo_ref, *w_refs = src_refs
     return chain(tuple(r[:] for r in w_refs), geo_ref[:], dt)
 
 
-def _fwd_kernel(chain, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
-                recv_ref, *rest):
+def _fwd_kernel(chain, window, si_ref, se_ref, av_ref, fi_ref, ids_ref,
+                *rest):
     from jax.experimental import pallas as pl
 
-    # the multiplier's source: ONE ref for an array (``w``, or without it
-    # the scalar edge-mask column: messages are the gathered features
-    # themselves, GIN/MFC-style), geo + weight blocks for a chain
+    # the multiplier's source: ONE ref for an array ``w``, geo + weight
+    # blocks for a chain, NONE without ``w`` (the messages are the gathered
+    # features themselves, GIN/MFC-style, times the mask bits that ride
+    # with the ids)
     src_refs = rest[:-(window + 1)]
     xwin_refs = rest[-(window + 1):-1]
     out_ref = rest[-1]
@@ -127,34 +192,27 @@ def _fwd_kernel(chain, window, si_ref, se_ref, av_ref, fi_ref, send_ref,
     @pl.when(av_ref[s] == 1)
     def _acc():
         bn = out_ref.shape[0]
-        be = send_ref.shape[0]
         # window rows are blocks [i-hw .. i+hw]; at the boundaries the
         # clamped duplicate slots are unreachable because the base stays
         # (i-hw)*bn (negative at the low edge is fine — senders then map
         # into the later window rows, never the duplicated ones)
         hw = window // 2
-        base = (i - hw) * bn
-        sloc = send_ref[:] - base                       # [BE, 1]
-        onehot_s = (sloc == jax.lax.broadcasted_iota(
-            jnp.int32, (be, window * bn), 1)).astype(jnp.float32)
         xcat = jnp.concatenate(
             [r[:] for r in xwin_refs], axis=0).astype(jnp.float32)
-        msgs = jax.lax.dot_general(
-            onehot_s, xcat, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [BE, F]
-        msgs = msgs * _multiplier(chain, src_refs, xwin_refs[0].dtype)
-        rloc = recv_ref[:] - i * bn
-        onehot_r = (rloc == jax.lax.broadcasted_iota(
-            jnp.int32, (be, bn), 1)).astype(jnp.float32)
-        out_ref[:] += jax.lax.dot_general(
-            onehot_r, msgs, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [BN, F]
+        msgs = _gather(_onehot_t(ids_ref, _SEND, (i - hw) * bn,
+                                 window * bn), xcat)         # [BE, F]
+        if src_refs:
+            msgs = msgs * _multiplier(chain, src_refs, xwin_refs[0].dtype)
+        out_ref[:] += _scatter(_onehot_t(
+            ids_ref, _RECV, i * bn, bn, masked=not src_refs), msgs)
 
 
 def _pack(x, w, senders, receivers, mask=None, edge_valid=None):
-    """Zero-pad the operands to whole blocks — ``(x_p, w_p, send_p,
-    recv_p)``, the arrays both passes run on (the forward rule saves them
+    """Zero-pad the operands to whole blocks — ``(x_p, w_p, ids_p,
+    recv_f)``, the arrays both passes run on (the forward rule saves them
     as its residuals, so the backward pads nothing a second time).
+    Without ``w`` there is no multiplier operand (``w_p`` is None): the
+    ``[E]`` edge mask rides with the ids (:func:`_pack_ids`).
 
     Shape-padding edges are parked outside every block/window so they
     can't contribute even with nonzero data (their w rows are zero
@@ -174,31 +232,56 @@ def _pack(x, w, senders, receivers, mask=None, edge_valid=None):
     # lax.pad, not zeros().at[].set(): the same one copy forward, and its
     # transpose is a slice — the scatter's would be an [E, F] gather
     x_p = jnp.pad(x, ((0, n_pad - n), (0, f_pad - f)))
+    w_p = mask_bits = None
     if has_w:
         w_p = jnp.pad(w, ((0, e_pad - e), (0, f_pad - f)))
     else:
-        # w omitted: the [E, 1] edge-mask column rides in w's slot
-        m = (jnp.ones((e,), jnp.float32) if mask is None
-             else mask.astype(jnp.float32))
-        w_p = jnp.pad(m, (0, e_pad - e))[:, None]
-    send_p, recv_p = _pack_ids(senders, receivers, e_pad, n_pad, edge_valid)
-    return x_p, w_p, send_p, recv_p
+        mask_bits = jnp.ones((e,), jnp.float32) if mask is None else mask
+    ids_p, recv_f = _pack_ids(senders, receivers, e_pad, n_pad, edge_valid,
+                              mask_bits)
+    return x_p, w_p, ids_p, recv_f
 
 
-def _pack_ids(senders, receivers, e_pad, n_pad, edge_valid=None):
-    """The two ``[E_pad, 1]`` id columns, shape- and mask-padding edges
-    parked on ``n_pad`` (:func:`_pack`)."""
+def _pack_ids(senders, receivers, e_pad, n_pad, edge_valid=None, mask=None):
+    """``(ids_p, recv_f)``: the ids LANE-MAJOR, as the kernels read them,
+    and the flat ``[E_pad]`` receivers the schedule and the XLA-side
+    selects read.  Shape- and mask-padding edges are parked on ``n_pad``
+    (:func:`_pack`).
+
+    ``ids_p`` is ``[E_pad / 128 * 8, 128]`` int32: one (8, 128) tile — 4 KiB
+    of HBM, 32 B an edge — per granule of 128 consecutive edges, sublane
+    0 its senders, 1 its receivers, 2 the bits of the w-less form's f32
+    ``mask`` (zero otherwise), the rest spare.  Every edge block either
+    pass chooses (512 / 256 / 128) is a whole number of granules of the
+    ONE operand.  (An ``[E_pad, 1]`` column, the layout until PR 29, is
+    128 lanes wide in HBM: 512 B an edge and id set, 512 KiB of the 768
+    a 512-edge step moved.)"""
     e = senders.shape[0]
     if edge_valid is not None:
         ev = edge_valid != 0
         senders = jnp.where(ev, senders, n_pad)
         receivers = jnp.where(ev, receivers, n_pad)
 
-    def ids(v):
-        return jnp.pad(v.astype(jnp.int32), (0, e_pad - e),
-                       constant_values=n_pad)[:, None]
+    def lanes(v, fill):
+        return jnp.pad(v, (0, e_pad - e), constant_values=fill)
 
-    return ids(senders), ids(receivers)
+    send_f = lanes(senders.astype(jnp.int32), n_pad)
+    recv_f = lanes(receivers.astype(jnp.int32), n_pad)
+    rows = [send_f, recv_f]
+    if mask is not None:
+        rows.append(jax.lax.bitcast_convert_type(
+            lanes(mask.astype(jnp.float32), 0.0), jnp.int32))
+    tiles = jnp.concatenate(
+        [r.reshape(-1, 1, _LANES) for r in rows], axis=1)
+    tiles = jnp.pad(tiles, ((0, 0), (0, _ID_ROWS - len(rows)), (0, 0)))
+    return tiles.reshape(-1, _LANES), recv_f
+
+
+def _ids_spec(be, eix):
+    """BlockSpec of one ``be``-edge block of :func:`_pack_ids`' operand."""
+    from jax.experimental import pallas as pl
+
+    return pl.BlockSpec((be // _LANES * _ID_ROWS, _LANES), eix)
 
 
 def _chain_edge_block(f_pad, backward):
@@ -229,10 +312,10 @@ def _chain_vmem(f_pad):
         vmem_limit_bytes=64 * 1024 * 1024)}
 
 
-def _fwd_call(window, x_p, w_p, send_p, recv_p, chain=None, weights=()):
+def _fwd_call(window, x_p, w_p, ids_p, recv_f, chain=None, weights=()):
     """The forward pass on whole blocks.  With a ``chain`` the multiplier's
     slot ``w_p`` holds the ``[E_pad, GPW]`` geometry stream and
-    ``weights`` its constant-mapped blocks."""
+    ``weights`` its constant-mapped blocks; without ``w`` it is None."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -241,20 +324,19 @@ def _fwd_call(window, x_p, w_p, send_p, recv_p, chain=None, weights=()):
     n_pad, f_pad = x_p.shape
     if chain is not None:
         be = _chain_edge_block(f_pad, False)
-    n_blocks, n_eblocks = n_pad // bn, send_p.shape[0] // be
+    n_blocks, n_eblocks = n_pad // bn, recv_f.shape[0] // be
     step_i, step_eb, acc_valid, is_first, s_max = _dense_schedule(
-        recv_p[:, 0], n_blocks, bn, be, n_eblocks)
+        recv_f, n_blocks, bn, be, n_eblocks)
     eix, xoff, const, outx = _window_maps(n_blocks)
+    src = [] if w_p is None else [w_p]
 
     hw = window // 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(s_max,),
-        in_specs=[
-            pl.BlockSpec((be, 1), eix),
-            pl.BlockSpec((be, 1), eix),
-            pl.BlockSpec((be, w_p.shape[1]), eix),
-        ] + [pl.BlockSpec(w.shape, const) for w in weights]
+        in_specs=[_ids_spec(be, eix)]
+        + [pl.BlockSpec((be, w.shape[1]), eix) for w in src]
+        + [pl.BlockSpec(w.shape, const) for w in weights]
         + [pl.BlockSpec((bn, f_pad), xoff(o))
            for o in range(-hw, hw + 1)],
         out_specs=pl.BlockSpec((bn, f_pad), outx),
@@ -266,7 +348,7 @@ def _fwd_call(window, x_p, w_p, send_p, recv_p, chain=None, weights=()):
         interpret=jax.default_backend() != "tpu",
         name="gather_mul_seg_fwd",
         **({} if chain is None else _chain_vmem(f_pad)),
-    )(step_i, step_eb, acc_valid, is_first, send_p, recv_p, w_p, *weights,
+    )(step_i, step_eb, acc_valid, is_first, ids_p, *src, *weights,
       *([x_p] * window))
 
 
@@ -275,45 +357,37 @@ def _fwd_call(window, x_p, w_p, send_p, recv_p, chain=None, weights=()):
 # ---------------------------------------------------------------------------
 
 
-def _bwd_gathers(window, i, send_ref, recv_ref, g_ref, xwin_refs):
+def _bwd_gathers(window, i, ids_ref, g_ref, xwin_refs, masked=False):
     """What every form of the backward step starts from: ``(g_r,
-    onehot_s, x_s)`` — the cotangent at the receivers, the forward's
-    window one-hot, the features at the senders (None without windows)."""
+    onehot_st, x_s)`` — the cotangent at the receivers, the forward's
+    window one-hot (transposed, :func:`_onehot_t`; ``masked`` as there),
+    the features at the senders (None without windows)."""
     bn = g_ref.shape[0]
-    be = send_ref.shape[0]
     # g[recv]: block-local, an edge of another node block gets an
     # all-zero row — it gates every product below, so a boundary edge
     # block contributes each edge exactly once (on its own block's
     # visit)
-    rloc = recv_ref[:] - i * bn
-    onehot_r = (rloc == jax.lax.broadcasted_iota(
-        jnp.int32, (be, bn), 1)).astype(jnp.float32)
-    g_r = jax.lax.dot_general(
-        onehot_r, g_ref[:].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [BE, F]
+    g_r = _gather(_onehot_t(ids_ref, _RECV, i * bn, bn),
+                  g_ref[:].astype(jnp.float32))              # [BE, F]
     # the forward's window one-hot (same base, same clamped slots)
-    hw = window // 2
-    sloc = send_ref[:] - (i - hw) * bn
-    onehot_s = (sloc == jax.lax.broadcasted_iota(
-        jnp.int32, (be, window * bn), 1)).astype(jnp.float32)
+    onehot_st = _onehot_t(ids_ref, _SEND, (i - window // 2) * bn,
+                          window * bn, masked)
     if not xwin_refs:
-        return g_r, onehot_s, None
+        return g_r, onehot_st, None
     xcat = jnp.concatenate(
         [r[:] for r in xwin_refs], axis=0).astype(jnp.float32)
-    x_s = jax.lax.dot_general(
-        onehot_s, xcat, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)           # [BE, F]
-    return g_r, onehot_s, x_s
+    return g_r, onehot_st, _gather(onehot_st, xcat)
 
 
 def _bwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, fe_ref,
-                send_ref, recv_ref, w_ref, g_ref, *rest):
+                ids_ref, *rest):
     from jax.experimental import pallas as pl
 
-    xwin_refs = rest[:window] if has_w else ()
-    dw_ref = rest[window] if has_w else None
-    p_ref = rest[-1]
+    if has_w:
+        w_ref, g_ref, *xwin_refs, dw_ref, p_ref = rest
+    else:
+        g_ref, p_ref = rest
+        xwin_refs = ()
 
     s = pl.program_id(0)
     i = si_ref[s]
@@ -324,8 +398,10 @@ def _bwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, fe_ref,
 
     @pl.when(av_ref[s] == 1)
     def _acc():
-        g_r, onehot_s, x_s = _bwd_gathers(
-            window, i, send_ref, recv_ref, g_ref, xwin_refs)
+        # without ``w`` the window one-hot carries the edge mask
+        g_r, onehot_st, x_s = _bwd_gathers(
+            window, i, ids_ref, g_ref, xwin_refs, masked=not has_w)
+        m = g_r
         if has_w:
             dw = x_s * g_r
             # per-edge stream: overwrite on the edge block's first
@@ -333,13 +409,9 @@ def _bwd_kernel(has_w, window, si_ref, se_ref, av_ref, fi_ref, fe_ref,
             # never add), accumulate on a boundary block's later visits
             dw_ref[:] = jnp.where(fe_ref[s] == 1, dw, dw_ref[:] + dw)
             m = w_ref[:].astype(jnp.float32) * g_r
-        else:
-            m = w_ref[:] * g_r                            # mask column
         # dx contributions of node block i's edges, still in WINDOW
         # coordinates (blocks i-hw..i+hw); overlap-added outside
-        p_ref[:] += jax.lax.dot_general(
-            onehot_s, m, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [W*BN, F]
+        p_ref[:] += _scatter(onehot_st, m)                # [W*BN, F]
 
 
 def _bwd_edge_block(f_pad, window):
@@ -353,7 +425,7 @@ def _bwd_edge_block(f_pad, window):
     return _EDGE_BLOCK if f_pad * window <= 2560 else _EDGE_BLOCK // 2
 
 
-def _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks):
+def _bwd_schedule(recv_f, n_blocks, bn, be, n_eblocks):
     """The dense schedule of the backward pass plus ``first_e`` (an edge
     block's first accumulated visit: per-edge output streams overwrite on
     it).  Every step that accumulates nothing (the forced step of an empty
@@ -362,7 +434,7 @@ def _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks):
     output block — so such a block is entered exactly once, on consecutive
     steps, and initialised by its first accumulated visit."""
     step_i, step_eb, acc_valid, is_first, s_max = _dense_schedule(
-        recv_p[:, 0], n_blocks, bn, be, n_eblocks)
+        recv_f, n_blocks, bn, be, n_eblocks)
     held = jax.lax.cummax(jnp.where(acc_valid == 1, step_eb, -1))
     prev = jnp.concatenate([jnp.full(1, -1, jnp.int32), held[:-1]])
     first_e = ((acc_valid == 1) & (step_eb != prev)).astype(jnp.int32)
@@ -389,30 +461,29 @@ def _overlap_add(p, n_blocks, window, bn, f_pad):
     return dx_p.reshape(n_blocks * bn, f_pad)
 
 
-def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
-    """``(dx_p, dw_p)`` of the padded problem (``dw_p`` None without w)."""
+def _bwd_call(has_w, window, x_p, w_p, ids_p, recv_f, g_p):
+    """``(dx_p, dw_p)`` of the padded problem (``dw_p`` None without w,
+    whose ``x_p`` and ``w_p`` are not read)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bn = _NODE_BLOCK
     n_pad, f_pad = g_p.shape
-    e_pad = send_p.shape[0]
+    e_pad = recv_f.shape[0]
     be = _bwd_edge_block(f_pad, window)
     n_blocks, n_eblocks = n_pad // bn, e_pad // be
     hw = window // 2
-    tables, s_max = _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks)
+    tables, s_max = _bwd_schedule(recv_f, n_blocks, bn, be, n_eblocks)
     eix, xoff, _, outx = _window_maps(n_blocks)
 
-    in_specs = [
-        pl.BlockSpec((be, 1), eix),
-        pl.BlockSpec((be, 1), eix),
-        pl.BlockSpec((be, w_p.shape[1]), eix),
-        pl.BlockSpec((bn, f_pad), outx),
-    ]
+    src = [w_p] if has_w else []
+    in_specs = ([_ids_spec(be, eix)]
+                + [pl.BlockSpec((be, f_pad), eix) for _ in src]
+                + [pl.BlockSpec((bn, f_pad), outx)])
+    operands = [ids_p, *src, g_p]
     out_specs = [pl.BlockSpec((window * bn, f_pad), outx)]
     out_shape = [jax.ShapeDtypeStruct(
         (n_blocks * window * bn, f_pad), jnp.float32)]
-    operands = [send_p, recv_p, w_p, g_p]
     if has_w:
         in_specs += [pl.BlockSpec((bn, f_pad), xoff(o))
                      for o in range(-hw, hw + 1)]
@@ -438,12 +509,12 @@ def _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p):
         return dx_p, None
     # edge blocks the schedule never accumulates (parked edges only) are
     # UNINITIALISED memory: select, never multiply (0 * NaN = NaN)
-    dw_p = jnp.where(recv_p < n_pad, outs[0], 0.0)
+    dw_p = jnp.where(recv_f[:, None] < n_pad, outs[0], 0.0)
     return dx_p, dw_p
 
 
 def _bwd_chain_kernel(chain, nw, window, want_dgeo, si_ref, se_ref, av_ref,
-                      fi_ref, fe_ref, send_ref, recv_ref, geo_ref, *rest):
+                      fi_ref, fe_ref, ids_ref, geo_ref, *rest):
     """:func:`_bwd_kernel` with the multiplier recomputed from its chain:
     ``dw = x_s * g_r`` stays in VMEM and is pulled back through the chain
     here (``jax.vjp`` on its body; weight VALUES upcast to f32 so their
@@ -478,8 +549,8 @@ def _bwd_chain_kernel(chain, nw, window, want_dgeo, si_ref, se_ref, av_ref,
         dt = xwin_refs[0].dtype
         # g_r's zero rows gate dw, hence the whole (linear) pullback:
         # every edge feeds the weight gradients exactly once
-        g_r, onehot_s, x_s = _bwd_gathers(
-            window, i, send_ref, recv_ref, g_ref, xwin_refs)
+        g_r, onehot_st, x_s = _bwd_gathers(
+            window, i, ids_ref, g_ref, xwin_refs)
         dw = x_s * g_r
         w_vals = tuple(r[:].astype(jnp.float32) for r in w_refs)
         geo = geo_ref[:]
@@ -493,12 +564,10 @@ def _bwd_chain_kernel(chain, nw, window, want_dgeo, si_ref, se_ref, av_ref,
             (dws,) = pull(dw)
         for r, d in zip(dws_refs, dws):
             r[:] += d
-        p_ref[:] += jax.lax.dot_general(
-            onehot_s, w * g_r, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # [W*BN, F]
+        p_ref[:] += _scatter(onehot_st, w * g_r)          # [W*BN, F]
 
 
-def _bwd_chain_call(chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
+def _bwd_chain_call(chain, window, x_p, geo_p, weights, ids_p, recv_f, g_p,
                     want_dgeo):
     """``(dx_p, dgeo_p, dweights)`` of the padded chain-form problem, one
     pass; ``dgeo_p`` is None (no E-sized output exists) unless asked."""
@@ -512,12 +581,11 @@ def _bwd_chain_call(chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
     n_blocks, n_eblocks = n_pad // bn, e_pad // be
     hw = window // 2
     nw = len(weights)
-    tables, s_max = _bwd_schedule(recv_p, n_blocks, bn, be, n_eblocks)
+    tables, s_max = _bwd_schedule(recv_f, n_blocks, bn, be, n_eblocks)
     eix, xoff, const, outx = _window_maps(n_blocks)
 
     in_specs = [
-        pl.BlockSpec((be, 1), eix),
-        pl.BlockSpec((be, 1), eix),
+        _ids_spec(be, eix),
         pl.BlockSpec((be, gpw), eix),
     ] + [pl.BlockSpec(w.shape, const) for w in weights] \
       + [pl.BlockSpec((bn, f_pad), outx)] \
@@ -543,32 +611,32 @@ def _bwd_chain_call(chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
         interpret=jax.default_backend() != "tpu",
         name="gather_mul_seg_bwd",
         **_chain_vmem(f_pad),
-    )(*tables, send_p, recv_p, geo_p, *weights, g_p, *([x_p] * window))
+    )(*tables, ids_p, geo_p, *weights, g_p, *([x_p] * window))
 
     dx_p = _overlap_add(outs[-1], n_blocks, window, bn, f_pad)
     # never-accumulated edge blocks are uninitialised memory: select
-    dgeo_p = (jnp.where(recv_p < n_pad, outs[nw], 0.0) if want_dgeo
+    dgeo_p = (jnp.where(recv_f[:, None] < n_pad, outs[nw], 0.0) if want_dgeo
               else None)
     return dx_p, dgeo_p, tuple(outs[:nw])
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _gms_padded(has_w, window, x_p, w_p, send_p, recv_p):
+def _gms_padded(has_w, window, x_p, w_p, ids_p, recv_f):
     """The op on whole blocks: ``[N_pad, F_pad]`` f32 segment sums.  The
     public ops pad and slice round it in plain jnp, so AD zero-pads the
     cotangent and slices ``dx`` / ``dw`` by itself."""
-    return _fwd_call(window, x_p, w_p, send_p, recv_p)
+    return _fwd_call(window, x_p, w_p, ids_p, recv_f)
 
 
-def _gms_fwd(has_w, window, x_p, w_p, send_p, recv_p):
-    out = _fwd_call(window, x_p, w_p, send_p, recv_p)
+def _gms_fwd(has_w, window, x_p, w_p, ids_p, recv_f):
+    out = _fwd_call(window, x_p, w_p, ids_p, recv_f)
     # the w-less backward reads no x: hold its dtype, none of its rows
-    return out, (x_p if has_w else x_p[:0], w_p, send_p, recv_p)
+    return out, (x_p if has_w else x_p[:0], w_p, ids_p, recv_f)
 
 
 def _gms_bwd(has_w, window, res, g_p):
-    x_p, w_p, send_p, recv_p = res
-    dx_p, dw_p = _bwd_call(has_w, window, x_p, w_p, send_p, recv_p, g_p)
+    x_p, w_p, ids_p, recv_f = res
+    dx_p, dw_p = _bwd_call(has_w, window, x_p, w_p, ids_p, recv_f, g_p)
     return (dx_p.astype(x_p.dtype),
             None if dw_p is None else dw_p.astype(w_p.dtype), None, None)
 
@@ -577,32 +645,32 @@ _gms_padded.defvjp(_gms_fwd, _gms_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _gcs_padded(chain, window, x_p, geo_p, weights, send_p, recv_p):
+def _gcs_padded(chain, window, x_p, geo_p, weights, ids_p, recv_f):
     """:func:`_gms_padded` with the multiplier made in VMEM: ``geo_p``
     ``[E_pad, GPW]`` and the constant ``weights`` blocks take ``w_p``'s
     place, and ``chain(w_vals, geo, dt) -> [BE, F_pad]`` f32 (pure JAX,
     static) turns one block of them into one block of ``w``."""
-    return _fwd_call(window, x_p, geo_p, send_p, recv_p, chain,
+    return _fwd_call(window, x_p, geo_p, ids_p, recv_f, chain,
                      tuple(weights))
 
 
-def _gcs_fwd(chain, window, x_p, geo_p, weights, send_p, recv_p):
+def _gcs_fwd(chain, window, x_p, geo_p, weights, ids_p, recv_f):
     # symbolic_zeros: every argument arrives as (value, perturbed).  The
     # geometry's flag decides whether the backward writes a dgeo stream at
     # all; it reaches the backward rule as the residuals' STRUCTURE
     # (a pytree with no leaves), the one static channel the two rules share
     want_dgeo = () if geo_p.perturbed else None
-    x_p, geo_p, send_p, recv_p = (
-        a.value for a in (x_p, geo_p, send_p, recv_p))
+    x_p, geo_p, ids_p, recv_f = (
+        a.value for a in (x_p, geo_p, ids_p, recv_f))
     weights = tuple(w.value for w in weights)
-    out = _fwd_call(window, x_p, geo_p, send_p, recv_p, chain, weights)
-    return out, (x_p, geo_p, weights, send_p, recv_p, want_dgeo)
+    out = _fwd_call(window, x_p, geo_p, ids_p, recv_f, chain, weights)
+    return out, (x_p, geo_p, weights, ids_p, recv_f, want_dgeo)
 
 
 def _gcs_bwd(chain, window, res, g_p):
-    x_p, geo_p, weights, send_p, recv_p, want_dgeo = res
+    x_p, geo_p, weights, ids_p, recv_f, want_dgeo = res
     dx_p, dgeo_p, dws = _bwd_chain_call(
-        chain, window, x_p, geo_p, weights, send_p, recv_p, g_p,
+        chain, window, x_p, geo_p, weights, ids_p, recv_f, g_p,
         want_dgeo is not None)
     return (dx_p.astype(x_p.dtype),
             None if dgeo_p is None else dgeo_p.astype(geo_p.dtype),
@@ -668,10 +736,10 @@ def gather_chain_segment_sum(x, geo_p, weights, chain, senders, receivers,
     n, f = x.shape
     n_pad = _round_up(n, _NODE_BLOCK)
     x_p = jnp.pad(x, ((0, n_pad - n), (0, _round_up(max(f, 1), 128) - f)))
-    send_p, recv_p = _pack_ids(senders, receivers, geo_p.shape[0], n_pad,
-                               edge_valid)
-    out = _gcs_padded(chain, window, x_p, geo_p, tuple(weights), send_p,
-                      recv_p)
+    ids_p, recv_f = _pack_ids(senders, receivers, geo_p.shape[0], n_pad,
+                              edge_valid)
+    out = _gcs_padded(chain, window, x_p, geo_p, tuple(weights), ids_p,
+                      recv_f)
     return out[:n, :f].astype(x.dtype)
 
 

@@ -470,10 +470,10 @@ def _gps_bwd(moments, res, g):
         # sum-only (cnt has no x-grad): dx[n] = sum_{e: send=n} m_e
         # g_sum[recv_e] — fused_mp's w-less backward pass over the edges
         # in receiver order, no [E, F] intermediate and no permutation
-        g_p, m_p, send_p, recv_p = _pack(
+        g_p, _, ids_p, recv_f = _pack(
             moms["sum"].astype(jnp.float32), None, senders, receivers,
             m, m)
-        dx_p, _ = _bwd_call(False, 3, None, m_p, send_p, recv_p, g_p)
+        dx_p, _ = _bwd_call(False, 3, None, None, ids_p, recv_f, g_p)
         return dx_p[:n, :f].astype(x.dtype), None, None, None, None
     if sender_perm is None:
         sender_perm = jnp.argsort(senders, stable=True)

@@ -1005,6 +1005,10 @@ _LAYOUTS = {
     "empty_blocks": ([400, 0, 600, 200, 0], 0),
     # (d) a masked tail longer than one edge block (plus a ragged end)
     "masked_tail": ([350, 500, 220], 2 * _EB + 77),
+    # (e) node ids above 8,192 (66 node blocks, edges in the last three):
+    # the kernels compare the lane-major ids as INTEGERS, so no id is
+    # rounded on its way to a one-hot (bf16, one MXU pass, holds 8 bits)
+    "high_ids": ([0] * 63 + [300, 700, 411], _EB + 5),
 }
 
 
@@ -1072,16 +1076,20 @@ def test_receiver_order_backward_exact(window, layout):
     _check_bwd_against_composed(x, w, s, r, valid, window)
 
 
-def test_receiver_order_backward_wide_f_halves_edge_block():
+@pytest.mark.parametrize("f,window", [(520, 5), (1000, 3)],
+                         ids=["f640w5", "f1024w3"])
+def test_receiver_order_backward_wide_f_halves_edge_block(f, window):
     """The one thing that adapts: at wide F the backward halves its edge
     block (VMEM), on the forward's 512-padded operands — a second schedule
-    over the same edge list, same gradients."""
+    over the same edge list AND the same packed id operand (two granules
+    of it a step where the forward took four), same gradients."""
     from hydragnn_tpu.ops.fused_mp import _EDGE_BLOCK, _bwd_edge_block
 
     assert _bwd_edge_block(128, 3) == _EDGE_BLOCK == _bwd_edge_block(512, 5)
     assert _bwd_edge_block(640, 5) == _EDGE_BLOCK // 2
-    x, w, s, r, valid = _synthetic_edges("straddle", 5, seed=28, f=520)
-    _check_bwd_against_composed(x, w, s, r, valid, 5)
+    assert _bwd_edge_block(1024, 3) == _EDGE_BLOCK // 2
+    x, w, s, r, valid = _synthetic_edges("straddle", window, seed=28, f=f)
+    _check_bwd_against_composed(x, w, s, r, valid, window)
 
 
 @pytest.mark.parametrize("layout", list(_LAYOUTS))
@@ -1163,11 +1171,16 @@ def test_dense_schedule_revisits_only_consecutively(layout):
     from hydragnn_tpu.ops.fused_mp import _pack
 
     x, w, s, r, valid = _synthetic_edges(layout, 3, seed=25)
-    _, _, _, recv_p = _pack(x, w, s, r, None, valid)
+    _, _, ids_p, recv_f = _pack(x, w, s, r, None, valid)
     n_blocks = -(-x.shape[0] // _NB)
-    n_eblocks = recv_p.shape[0] // _EB
+    n_eblocks = recv_f.shape[0] // _EB
+    # the receivers the kernels read (sublane 1 of every 128-edge granule
+    # of the packed operand) are the flat ones the schedule is made from
+    np.testing.assert_array_equal(
+        np.asarray(ids_p).reshape(-1, 8, 128)[:, 1].ravel(),
+        np.asarray(recv_f))
     si, se, av, fi, s_max = _dense_schedule(
-        recv_p[:, 0], n_blocks, _NB, _EB, n_eblocks)
+        recv_f, n_blocks, _NB, _EB, n_eblocks)
     si, se, av, fi = (np.asarray(t) for t in (si, se, av, fi))
     assert si.shape == (s_max,) == se.shape
     for table in (si, se):
@@ -1176,7 +1189,7 @@ def test_dense_schedule_revisits_only_consecutively(layout):
     # each node block is entered once, every real edge is scheduled with
     # its own node block exactly once, and no masked edge ever is
     assert (si[fi == 1] == np.arange(n_blocks)).all()
-    recv = np.asarray(recv_p[:, 0]).reshape(n_eblocks, _EB)
+    recv = np.asarray(recv_f).reshape(n_eblocks, _EB)
     hits = np.zeros(recv.shape, np.int64)
     for i, eb in zip(si[av == 1], se[av == 1]):
         hits[eb] += recv[eb] // _NB == i
@@ -1281,17 +1294,20 @@ def test_chain_form_matches_composed(layout):
         np.testing.assert_allclose(a, b, rtol=3e-4, atol=3e-4, err_msg=name)
 
 
-def test_chain_form_wide_f_shrinks_edge_blocks():
+@pytest.mark.parametrize("f", [520, 1000], ids=["f640", "f1024"])
+def test_chain_form_wide_f_shrinks_edge_blocks(f):
     """What adapts in the chain form: past F_pad 512 the ``[F, F]`` weight
     block and its gradient accumulator crowd VMEM, so the forward runs
     256-edge and the backward 128-edge blocks on the same 512-padded
-    operands — other schedules over one edge list, the same numbers."""
+    operands — other schedules over one edge list and ONE packed id
+    operand (two granules a forward step, one a backward step), the same
+    numbers."""
     from hydragnn_tpu.ops.fused_mp import _chain_edge_block
 
     assert _chain_edge_block(128, False) == _EB == _chain_edge_block(512, True)
     assert (_chain_edge_block(640, False), _chain_edge_block(1024, True)) \
         == (_EB // 2, _EB // 4)
-    args, s, r, valid = _chain_problem("straddle", seed=37, f=520)
+    args, s, r, valid = _chain_problem("straddle", seed=37, f=f)
     ct = jnp.asarray(np.random.RandomState(38).randn(
         *args[0].shape), jnp.float32)
     got = jax.grad(lambda a: jnp.sum(ct * _chain_fused(a, s, r, valid)))(args)
@@ -1379,14 +1395,15 @@ def test_array_and_wless_kernels_keep_their_shape(form):
         def loss(x_, w_):
             return jnp.sum(gather_mul_segment_sum(
                 x_, w_, s, r, edge_valid=valid) ** 2)
-        # tables + ids + multiplier (+ g) + windows -> outputs; dots
-        want = {"gather_mul_seg_fwd": (4 + 3 + 3, 1, 2),
-                "gather_mul_seg_bwd": (5 + 4 + 3, 2, 3)}
+        # tables + packed ids + multiplier (+ g) + windows -> outputs; dots
+        want = {"gather_mul_seg_fwd": (4 + 2 + 3, 1, 2),
+                "gather_mul_seg_bwd": (5 + 3 + 3, 2, 3)}
     else:
         def loss(x_, w_):
             return jnp.sum(gather_segment_sum(x_, s, r, valid) ** 2)
-        want = {"gather_mul_seg_fwd": (4 + 3 + 3, 1, 2),
-                "gather_mul_seg_bwd": (5 + 4, 1, 2)}
+        # no multiplier operand: the mask rides with the ids
+        want = {"gather_mul_seg_fwd": (4 + 1 + 3, 1, 2),
+                "gather_mul_seg_bwd": (5 + 2, 1, 2)}
     kernels = _kernel_eqns(jax.grad(loss), x, w)
     assert set(kernels) == set(want)
     for name, (n_in, n_out, n_dot) in want.items():
@@ -1395,6 +1412,71 @@ def test_array_and_wless_kernels_keep_their_shape(form):
         assert _count_in_kernel(eq, "dot_general") == n_dot, name
         assert _count_in_kernel(eq, "logistic", "log1p", "exp",
                                 "custom_jvp_call") == 0, name
+
+
+def _tiled_bytes(block, dtype):
+    """HBM bytes of one block of a Mosaic operand: the two minor
+    dimensions round up to (8, 128) tiles."""
+    *lead, r, c = block
+    return (int(np.prod(lead, dtype=np.int64)) * -(-r // 8) * 8
+            * -(-c // 128) * 128 * np.dtype(dtype).itemsize)
+
+
+@pytest.mark.parametrize("kernel", ["gather_mul_seg_fwd",
+                                    "gather_mul_seg_bwd"])
+@pytest.mark.parametrize("form", ["chain", "array", "wless"])
+def test_edge_ids_cost_at_most_32_bytes_an_edge(form, kernel):
+    """The id operands' HBM layout, counted from the gradient's jaxpr (no
+    chip needed): per edge block, every INPUT stream indexed by the edge
+    block is reckoned in tiled bytes.  The ids are ONE int32 operand of
+    (8, 128) granules, <= 32 B an edge (two ``[E_pad, 1]`` columns, 128
+    lanes wide each, were 1,024 B), and at F_pad 128 a step fetches <= 272
+    KiB of edge streams (768 KiB then): the multiplier's 256 + 16."""
+    from hydragnn_tpu.ops.fused_mp import gather_segment_sum
+
+    f = 100                                           # F_pad 128
+    if form == "chain":
+        args, s, r, valid = _chain_problem("masked_tail", seed=41, f=f)
+
+        def loss(*a):
+            return jnp.sum(_chain_fused(a, s, r, valid) ** 2)
+        grad, targs = jax.grad(loss, argnums=(0, 3, 4, 5, 6)), args
+    else:
+        x, w, s, r, valid = _synthetic_edges("masked_tail", 3, seed=41, f=f)
+        if form == "array":
+            def loss(x_, w_):
+                return jnp.sum(gather_mul_segment_sum(
+                    x_, w_, s, r, edge_valid=valid) ** 2)
+        else:
+            def loss(x_, w_):
+                return jnp.sum(gather_segment_sum(x_, s, r, valid) ** 2)
+        grad, targs = jax.grad(loss, argnums=(0, 1)), (x, w)
+    e_pad = -(-int(s.shape[0]) // _EB) * _EB
+    granule_rows = e_pad // 128 * 8
+    (eq,) = _kernel_eqns(grad, *targs)[kernel]
+    gm = eq.params["grid_mapping"]
+    id_bytes = stream_bytes = 0
+    edges = set()
+    for bm in gm.block_mappings[:gm.num_inputs]:
+        aval = bm.array_aval
+        block = tuple(b.block_size for b in bm.block_shape)
+        if aval.shape[0] == granule_rows and aval.dtype == jnp.int32:
+            assert aval.shape[1] == 128 and block[1] == 128, aval
+            edges.add(block[0] // 8 * 128)
+            id_bytes += _tiled_bytes(block, aval.dtype)
+        elif aval.shape[0] == e_pad:
+            edges.add(block[0])
+        else:
+            continue
+        stream_bytes += _tiled_bytes(block, aval.dtype)
+    # every edge stream of the kernel moves the same edges a step
+    (be,) = edges
+    assert be == _EB
+    assert 0 < id_bytes <= 32 * be, (id_bytes, be)
+    assert stream_bytes <= 272 * 1024, stream_bytes
+    # no id (or mask) column is left: nothing one lane wide is an operand
+    assert not [bm for bm in gm.block_mappings
+                if bm.array_aval.shape[-1] == 1]
 
 
 def test_schnet128_train_step_keeps_the_filter_inside_the_kernels(

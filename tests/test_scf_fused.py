@@ -52,8 +52,14 @@ def _composed(h, rbf, cm, w0, b0, w1, b1, senders, receivers, num_nodes):
     return jax.ops.segment_sum(msgs, receivers, num_segments=num_nodes)
 
 
-def test_forward_matches_composed():
-    g = _batch()
+# one node block (54 nodes), and collated molecules across four of them
+# with ids above 256 and an edge list of several 128-edge id granules
+_SIZES = pytest.mark.parametrize("n_graphs", [6, 48], ids=["1blk", "4blk"])
+
+
+@_SIZES
+def test_forward_matches_composed(n_graphs):
+    g = _batch(n_graphs)
     h, rbf, cm, w0, b0, w1, b1 = _inputs(g)
     perm = jnp.asarray(g.extras["edge_perm_sender"])
     em = jnp.asarray(g.edge_mask).astype(jnp.int32)
@@ -65,8 +71,9 @@ def test_forward_matches_composed():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_gradients_match_composed():
-    g = _batch(seed=3)
+@_SIZES
+def test_gradients_match_composed(n_graphs):
+    g = _batch(n_graphs, seed=3)
     inputs = _inputs(g, seed=4)
     perm = jnp.asarray(g.extras["edge_perm_sender"])
     n = inputs[0].shape[0]
