@@ -679,6 +679,32 @@ _SCOPE_LIST = [
         "ZeRO stage-2 param all_gather before the forward"),
     _sc("comm.halo_exchange", "hydragnn_tpu/parallel/mesh.py",
         "halo-row exchange assembling the extended graph shard"),
+    # the language-model stack's own parts, below step.loss / step.eval
+    _sc("lm.embed", "hydragnn_tpu/models/laguna.py",
+        "embedding lookup of the node ids and each node's position "
+        "inside its graph"),
+    _sc("attn.proj", "hydragnn_tpu/models/laguna.py",
+        "attention's norm, q/k/v/gate products, rotary, gate and output "
+        "product"),
+    _sc("attn.core", "hydragnn_tpu/ops/attention.py",
+        "scores, softmax and values over each graph's nodes (the splash "
+        "kernels, or the dense twin)"),
+    _sc("ffn.dense", "hydragnn_tpu/models/laguna.py",
+        "the dense gated feed-forward, node slice by node slice"),
+    _sc("moe.route", "hydragnn_tpu/ops/moe.py",
+        "router product, softmax, top-k, the held experts' loads"),
+    _sc("moe.experts", "hydragnn_tpu/ops/moe.py",
+        "sort by expert, dispatch gather, grouped products, combine "
+        "(or the dense path)"),
+    _sc("moe.gmm", "hydragnn_tpu/ops/moe.py",
+        "the grouped matrix products alone (megablox gmm/tgmm, or "
+        "ragged_dot), inside moe.experts"),
+    _sc("moe.shared", "hydragnn_tpu/models/laguna.py",
+        "the shared expert's gated feed-forward"),
+    _sc("lm.head", "hydragnn_tpu/models/laguna.py",
+        "final norm and the untied head product"),
+    _sc("lm.xent", "hydragnn_tpu/models/layers.py",
+        "softmax cross-entropy against the next node's id"),
 ]
 
 SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}

@@ -74,6 +74,7 @@ ALL_MODEL_TYPES = [
     "SchNet",
     "DimeNet",
     "EGNN",
+    "Laguna",
 ]
 
 
@@ -131,6 +132,10 @@ def finalize(
         raise ValueError('"mlp_per_node" is not allowed for variable graph size')
 
     arch["input_dim"] = len(var["input_node_features"])
+    if arch["model_type"] == "Laguna":
+        # no graph is longer than this: it bands the full-attention
+        # layers' kernel (ops/attention.py)
+        arch["max_graph_nodes"] = int(dataset_stats.max_nodes)
 
     if arch["model_type"] == "PNA":
         deg = dataset_stats.pna_deg

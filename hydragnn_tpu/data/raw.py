@@ -375,7 +375,33 @@ ATOMIC_NUMBERS: Dict[str, int] = {
     )
 }
 
+class TokenDataset(AbstractRawDataset):
+    """Token documents: one file a document, its token ids as
+    whitespace-separated integers.  Node features are ``[id, next id]``
+    (-1 where a token has no successor in its document), positions are
+    zeros, and nothing is scaled: an id is a category, and min-max
+    normalisation would make it a fraction.  The language-model stack
+    (models/laguna.py) reads column 0 and is trained against column 1."""
+
+    def transform_file(self, filepath: str) -> Optional[RawSample]:
+        with open(filepath, "r", encoding="utf-8") as f:
+            ids = np.asarray(f.read().split(), dtype=np.float64)
+        if not len(ids):
+            return None
+        nxt = np.concatenate([ids[1:], [-1.0]])
+        return RawSample(x=np.stack([ids, nxt], axis=1),
+                         pos=np.zeros((len(ids), 3)),
+                         y=np.zeros((sum(self.graph_feature_dim),)))
+
+    def normalize_dataset(self) -> None:
+        # the headers of the serialized layout, as an identity map
+        n_nf, n_gf = len(self.node_feature_dim), len(self.graph_feature_dim)
+        self.minmax_node_feature = np.stack([np.zeros(n_nf), np.ones(n_nf)])
+        self.minmax_graph_feature = np.stack([np.zeros(n_gf), np.ones(n_gf)])
+
+
 RAW_FORMATS = {
+    "tokens": TokenDataset,
     "LSMS": LSMSDataset,
     "unit_test": LSMSDataset,
     "XYZ": XYZDataset,

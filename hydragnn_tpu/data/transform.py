@@ -39,6 +39,9 @@ def select_feature_columns(
     return cols
 
 
+EDGE_FREE_MODELS = ("Laguna",)
+
+
 def transform_raw_samples(
     records: Sequence[RawSample],
     config: Dict[str, Any],
@@ -76,13 +79,21 @@ def transform_raw_samples(
         else list(var["input_node_features"])
     )
 
+    # a stack whose edge set is implicit (attention over each graph's
+    # nodes, models/laguna.py) gets no edge list: a document of 8192
+    # tokens would be 33 M of them
+    edge_free = arch.get("model_type") in EDGE_FREE_MODELS
+
     built = []
     max_len = 0.0
     for rec in records:
         pos = np.asarray(rec.pos, dtype=np.float64)
         if rot:
             pos = normalize_rotation(pos).astype(np.float64)
-        if pbc:
+        if edge_free:
+            edge_index = np.zeros((2, 0), np.int32)
+            lengths = np.zeros((0, 1))
+        elif pbc:
             assert rec.cell is not None, "PBC requires a cell per sample"
             edge_index, lengths = radius_graph_pbc(
                 pos, rec.cell, radius, max_neighbours=max_neigh)

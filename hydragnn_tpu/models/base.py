@@ -109,6 +109,10 @@ class ModelConfig:
     basis_emb_size: Optional[int] = None
     int_emb_size: Optional[int] = None
     out_emb_size: Optional[int] = None
+    # the language-model stack (models/laguna.py): the sizes held on this
+    # chip and the chip's share of the layer (parallel/share.py)
+    lm: Optional[Any] = None
+    share: Optional[Any] = None
 
     def __post_init__(self):
         # validate HERE so every construction path (from_config, direct
@@ -168,6 +172,14 @@ class ModelConfig:
             avg_log = float((np.log(bins + 1) * hist).sum() / total)
             avg_lin = float((bins * hist).sum() / total)
         hidden_dim = arch["hidden_dim"]
+        lm = share = None
+        if arch["model_type"] == "Laguna":
+            from hydragnn_tpu.models.laguna import LagunaConfig
+            from hydragnn_tpu.parallel.share import LayerShare
+
+            lm = LagunaConfig.from_arch(arch)
+            share = LayerShare.from_arch(arch["laguna"],
+                                         arch.get("share") or {})
         if arch["model_type"] == "CGCNN":
             # CGConv preserves feature dims (reference CGCNNStack.py:30-40)
             hidden_dim = arch["input_dim"]
@@ -204,6 +216,8 @@ class ModelConfig:
             basis_emb_size=arch.get("basis_emb_size"),
             int_emb_size=arch.get("int_emb_size"),
             out_emb_size=arch.get("out_emb_size"),
+            lm=lm,
+            share=share,
             # extension over the reference schema (its Base hardcodes
             # dropout=0.25 with a FIXME about config exposure,
             # reference Base.py:40): Architecture.dropout overrides the

@@ -22,6 +22,7 @@ from hydragnn_tpu.models.cgcnn import CGCNNStack
 from hydragnn_tpu.models.schnet import SCFStack
 from hydragnn_tpu.models.egnn import EGCLStack
 from hydragnn_tpu.models.dimenet import DIMEStack
+from hydragnn_tpu.models.laguna import LagunaStack
 
 _STACKS = {
     "SAGE": SAGEStack,
@@ -40,6 +41,11 @@ _STACKS = {
 # a newly registered stack cannot miss bench or parity coverage.
 ALL_ARCHS = tuple(_STACKS)
 
+# Stacks that are not message passing over an edge list and so take no part
+# in the per-arch sweeps above: a language model over each graph's nodes
+# (models/laguna.py; its own reference and tests, tests/test_laguna.py).
+_SEQUENCE_STACKS = {"Laguna": LagunaStack}
+
 
 def create_model_config(config: Dict[str, Any]) -> Base:
     """Build the (uninitialized) flax module from a finalized config dict."""
@@ -48,6 +54,11 @@ def create_model_config(config: Dict[str, Any]) -> Base:
 
 
 def create_model(cfg: ModelConfig) -> Base:
+    if cfg.model_type in _SEQUENCE_STACKS:
+        if cfg.lm is None or cfg.share is None:
+            raise ValueError(
+                f"{cfg.model_type} requires Architecture.laguna")
+        return _SEQUENCE_STACKS[cfg.model_type](cfg=cfg)
     if cfg.model_type not in _STACKS:
         raise ValueError(f"Unknown model_type: {cfg.model_type}")
     if (cfg.model_type == "GAT" and cfg.dropout > 0

@@ -79,6 +79,23 @@ def loss_function(name: str) -> Callable[[jax.Array, jax.Array, jax.Array], jax.
         return _sl1
     if name == "rmse":
         return lambda p, t, m: jnp.sqrt(_masked_mean((p - t) ** 2, m) + 1e-16)
+    if name == "softmax_xent":
+
+        def _xent(p, t, m):
+            # integer node labels in a float column (exact to 2^24); a
+            # negative label marks a node with nothing to predict (the
+            # last node of its graph), left out like padding
+            from hydragnn_tpu.utils.scope import phase
+
+            with phase("lm.xent"):
+                ids = t[..., 0].astype(jnp.int32)
+                p = p.astype(jnp.float32)
+                picked = jnp.take_along_axis(
+                    p, jnp.maximum(ids, 0)[..., None], axis=-1)
+                nll = jax.nn.logsumexp(p, axis=-1, keepdims=True) - picked
+                return _masked_mean(nll, m * (ids >= 0))
+
+        return _xent
     raise ValueError(f"Unknown loss function: {name}")
 
 

@@ -1,0 +1,69 @@
+"""One chip's share of a layer that a deployment divides over many chips.
+
+A layer of a sparse language model is shared by ``R`` chips: its routed
+experts over expert-parallel ranks, its attention heads over
+tensor-parallel ranks (one group of query heads per key/value head), its
+vocabulary over vocabulary-parallel ranks.  ``LayerShare`` says what THIS
+chip holds; the layer reads it (models/laguna.py, ops/moe.py): the router
+keeps its published width and routes over all the experts, and the chip
+computes the part of the result its own experts give.  On one chip the
+layer runs without its exchange: what the absent experts and heads would
+add is left out, nothing stands in for the absent chips, and the partial
+result goes on to the next layer (the plain reference is given the same
+share, models/laguna_reference.py).  The parameters a share holds ARE the
+slice (``wq`` has the held heads' columns only), so only the experts and
+the vocabulary need an offset: router ids and token ids are global.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerShare:
+    num_experts_total: int      # the router's width
+    experts_held: int
+    expert_offset: int          # first held expert's global id
+    kv_heads_total: int
+    kv_heads_held: int
+    kv_head_offset: int
+    vocab_total: int
+    vocab_rows: int
+    vocab_offset: int           # first held row's global token id
+
+    def __post_init__(self):
+        for held, off, total, what in (
+                (self.experts_held, self.expert_offset,
+                 self.num_experts_total, "experts"),
+                (self.kv_heads_held, self.kv_head_offset,
+                 self.kv_heads_total, "key/value heads"),
+                (self.vocab_rows, self.vocab_offset, self.vocab_total,
+                 "vocabulary rows")):
+            if not (held >= 1 and off >= 0 and off + held <= total):
+                raise ValueError(
+                    f"share holds {what} [{off}, {off + held}) of {total}")
+
+    @staticmethod
+    def from_arch(lm: Dict[str, Any], share: Dict[str, Any]) -> "LayerShare":
+        """From ``Architecture.laguna`` (the sizes held here) and
+        ``Architecture.share`` (the published totals and this chip's
+        offsets; absent = the uncut model)."""
+        return LayerShare(
+            num_experts_total=int(share.get("num_experts_total",
+                                            lm["num_experts"])),
+            experts_held=int(lm["num_experts"]),
+            expert_offset=int(share.get("expert_offset", 0)),
+            kv_heads_total=int(share.get("kv_heads_total",
+                                         lm["num_key_value_heads"])),
+            kv_heads_held=int(lm["num_key_value_heads"]),
+            kv_head_offset=int(share.get("kv_head_offset", 0)),
+            vocab_total=int(share.get("vocab_total", lm["vocab_size"])),
+            vocab_rows=int(lm["vocab_size"]),
+            vocab_offset=int(share.get("vocab_offset", 0)))
+
+    def local_expert(self, expert_ids):
+        """(local id, held?) of global router ids (any array)."""
+        local = expert_ids - self.expert_offset
+        return local, (local >= 0) & (local < self.experts_held)

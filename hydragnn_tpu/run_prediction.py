@@ -62,7 +62,8 @@ def _(config: dict, logs_dir: str = "./logs/", seed: int = 0):
     eval_step = jax.jit(make_eval_step(model, cfg))
     error, tasks_error, true_values, predicted_values = test(
         eval_step, state, test_loader, cfg.num_heads,
-        world_size=world_size, output_types=cfg.output_type)
+        world_size=world_size, output_types=cfg.output_type,
+        classify=cfg.loss_fn == "softmax_xent")
 
     if config["NeuralNetwork"]["Variables_of_interest"].get(
             "denormalize_output"):

@@ -87,6 +87,26 @@ def _source_lines(lines) -> Dict[str, str]:
     return out
 
 
+def _whole_instructions(lines):
+    """``lines`` with an instruction's continuation lines joined to it.  A
+    kernel that carries a multi-line attribute (the splash attention
+    kernels' ``xprof_metadata``) prints its custom call over several
+    lines, the last of which begins with ``}}``: read as the end of the
+    computation, it hid every instruction after it (PERF.md, PR 30)."""
+    whole = None
+    for line in lines:
+        starts = (_INSTRUCTION.match(line) or _COMPUTATION.match(line)
+                  or line.rstrip() == "}" or not line.strip())
+        if whole is not None and not starts:
+            whole += " " + line
+            continue
+        if whole is not None:
+            yield whole
+        whole = line
+    if whole is not None:
+        yield whole
+
+
 def instruction_scopes(hlo_text: str) -> Dict[str, List[Any]]:
     """``{instruction: [first result shape, scope, inherited, source]}``
     for every instruction of ``hlo_text`` (a compiled module's
@@ -97,12 +117,12 @@ def instruction_scopes(hlo_text: str) -> Dict[str, List[Any]]:
     computation = None
     lines = hlo_text.splitlines()
     sources = _source_lines(lines)
-    for line in lines:
+    for line in _whole_instructions(lines):
         m = _COMPUTATION.match(line)
         if m:
             computation = m.group(1)
             continue
-        if line.startswith("}"):
+        if line.rstrip() == "}":
             computation = None
             continue
         m = _INSTRUCTION.match(line) if computation else None

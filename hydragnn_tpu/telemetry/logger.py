@@ -310,15 +310,19 @@ class MetricsLogger:
         if writer is not None and self.rank == 0:
             self.sinks.append(TensorBoardSink(writer))
 
-    def bind_step(self, step_fn, state, steps_per_item: int = 1) -> None:
+    def bind_step(self, step_fn, state, steps_per_item: int = 1,
+                  cost_model: bool = True) -> None:
         """Remember the jitted step and the train state's avals (captured
         BEFORE the first donated call, while buffers are alive) for the
-        in-run MFU flops basis."""
+        in-run MFU flops basis.  ``cost_model=False`` leaves the estimate
+        out: a stack whose products run inside Pallas kernels that XLA's
+        cost model cannot see (models/laguna.py) would pay a second
+        compile of a large program for a number that is wrong."""
         self._steps_per_item = max(1, int(steps_per_item))
         # the flops basis costs a second XLA compile of the step (per
         # PadSpec bucket) — only the rank that actually writes records
         # (sinks exist) should pay it
-        if not (self.enabled and self.cfg.mfu and self.sinks):
+        if not (self.enabled and self.cfg.mfu and self.sinks and cost_model):
             return
         self._step_fn = step_fn
         try:
@@ -576,6 +580,16 @@ class MetricsLogger:
                 "edges_waste_pct": waste_pct(edges_real, pad["padded_edges"]),
                 "graphs_waste_pct": waste_pct(ng, pad["padded_graphs"]),
             }
+            if "moe_slots_all" in m:
+                # the expert layers' routing, summed over layers and over
+                # the steps of the dispatch (ops/moe.py stats)
+                rec["moe"] = {
+                    "slots_held": float(m["moe_slots_held"]),
+                    "slots_all": float(m["moe_slots_all"]),
+                    "load_max_over_mean": float(
+                        m["moe_load_max_over_mean"]),
+                    "dense_steps": float(m["moe_dense_steps"]),
+                }
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

@@ -40,6 +40,7 @@ from hydragnn_tpu.train.optimizer import (
     set_learning_rate,
 )
 from hydragnn_tpu.utils import tracer as tr
+from hydragnn_tpu.utils.scope import phase  # noqa: F401 (parallel/ imports it from here)
 
 
 @struct.dataclass
@@ -57,12 +58,18 @@ def create_train_state(
     opt_spec: OptimizerSpec,
     seed: int = 0,
 ) -> TrainState:
-    variables = model.init(
+    init = lambda batch: model.init(  # noqa: E731
         {"params": jax.random.PRNGKey(seed),
          "dropout": jax.random.PRNGKey(seed + 1)},
-        example_batch,
+        batch,
         train=False,
     )
+    if getattr(model, "jit_init", False):
+        # a stack whose forward is far too large to run op by op just to
+        # shape its parameters (models/laguna.py): under jit the forward
+        # is dead code and only the initialisers are compiled
+        init = jax.jit(init)
+    variables = init(example_batch)
     params = variables["params"]
     if getattr(model, "cfg", None) is not None and model.cfg.initial_bias is not None:
         from hydragnn_tpu.models.base import set_initial_bias
@@ -126,6 +133,10 @@ def _loss_and_metrics(
     pre-policy build."""
     compute_dtype = (jnp.bfloat16 if (getattr(cfg, "compute_dtype", "float32")
                      == "bfloat16" or dtype_policy == "bf16") else None)
+    if not getattr(model, "casts_at_boundary", True):
+        # a stack that casts for itself (models/laguna.py: integer ids in
+        # x, a float32 router) reads cfg.compute_dtype on its own
+        compute_dtype = None
     if dtype_policy == "int8_edge":
         # int8 edge-MLP pilot: fake-quantize the edge-MLP kernels (int8
         # round-trip, straight-through grad) at this one boundary — the
@@ -224,17 +235,12 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
     }
 
 
-def phase(name: str):
-    """One phase of a step: a ``jax.named_scope``, so every op traced
-    inside carries ``name`` in its HLO ``op_name`` and the device trace can
-    be split by it (docs/TELEMETRY.md "Tracing").  Every step builder,
-    here and in parallel/, takes its phases from this one helper; the
-    names are declared in analysis/registry.py SCOPE_NAMES (lint REG006).
-    Metadata only: the executed program is the same with or without it.
-    ``step.loss`` goes round the ``value_and_grad`` call: JAX then writes
-    ``jvp(...)`` below it for the forward and ``transpose(jvp(...))`` for
-    the backward, and the flax module path follows."""
-    return jax.named_scope(name)
+def model_counters(batch_stats) -> Dict[str, jax.Array]:
+    """What the model counted in this step for the step records: the
+    top-level ``moe_*`` scalars a stack keeps in ``batch_stats``
+    (models/laguna.py LagunaStack._count); {} for every other stack."""
+    return {k: v for k, v in batch_stats.items()
+            if k.startswith("moe_") and getattr(v, "ndim", None) == 0}
 
 
 def make_train_step(
@@ -299,6 +305,7 @@ def make_train_step(
             with phase("step.metrics"):
                 metrics.update(
                     step_telemetry_metrics(g, grads, new_params, updates))
+                metrics.update(model_counters(new_stats))
         if nonfinite_guard:
             from hydragnn_tpu.resilience.guards import (
                 apply_step_guard,
@@ -317,7 +324,8 @@ def make_train_step(
 # metric keys that are COUNTS over the dispatch (summed across the K
 # scanned steps); every other scalar merges as a graph-weighted mean
 # ("skipped" counts guard-suppressed steps within the dispatch)
-_COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped")
+_COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
+                      "moe_slots_held", "moe_slots_all", "moe_dense_steps")
 
 
 def merge_scanned_metrics(ms):
@@ -1402,7 +1410,9 @@ def train_validate_test(
     profiler = Profiler(profile_config, log_name, logs_dir)
 
     telemetry.attach_tensorboard(writer)
-    telemetry.bind_step(train_step, state, steps_per_dispatch)
+    telemetry.bind_step(
+        train_step, state, steps_per_dispatch,
+        cost_model=getattr(model, "cost_model_sees_flops", True))
 
     history: Dict[str, Any] = {
         "train": [], "val": [], "test": [], "lr": [], "epoch_time": [],
@@ -1841,10 +1851,13 @@ def test(
     world_size: int = 1,
     *,
     output_types: Sequence[str],
+    classify: bool = False,
 ) -> Tuple[float, np.ndarray, List[np.ndarray], List[np.ndarray]]:
     """Full-dataset evaluation returning (error, per-task error, true, pred)
     per head with padding stripped (parity: reference test(),
-    train_validate_test.py:565-664)."""
+    train_validate_test.py:565-664).  ``classify`` (a ``softmax_xent``
+    model): a head's output is logits over classes and its prediction the
+    class of the largest, beside the integer label."""
     total = 0.0
     n = 0.0
     tasks = np.zeros(num_heads)
@@ -1881,7 +1894,11 @@ def test(
             true_values[ih].append(lab[mask])
             # gaussian_nll heads emit [mean, log_sigma] at 2x the label
             # width — the prediction is the mean block
-            pred_values[ih].append(out[mask][:, : lab.shape[-1]])
+            if classify:
+                pred_values[ih].append(np.argmax(
+                    out[mask], axis=-1)[:, None].astype(lab.dtype))
+            else:
+                pred_values[ih].append(out[mask][:, : lab.shape[-1]])
         if dump_file is not None:
             pickle.dump(
                 {f"head{ih}": {"true": true_values[ih][-1],

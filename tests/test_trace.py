@@ -813,3 +813,26 @@ def test_slo_config_env_overrides(monkeypatch):
         == (250.0, 0.02, 30.0, 4.0)
     monkeypatch.setenv("HYDRAGNN_SLO_BURN", "not-a-float")
     assert SloConfig(burn=3.0).burn == 3.0  # malformed env falls back
+
+
+def test_instruction_scopes_reads_past_a_multi_line_custom_call():
+    """A kernel with a multi-line attribute prints its custom call over
+    several lines, the last beginning with ``}}``: it is one instruction,
+    not the end of the computation (the splash attention kernels; before
+    the fix every later instruction of the computation went unnamed)."""
+    from hydragnn_tpu.telemetry.hlo_scopes import instruction_scopes
+
+    text = """HloModule jit_step
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %splash_fwd.1 = f32[8]{0} custom-call(%a), custom_call_target="tpu_custom_call", backend_config={"custom_call_config": {"body": "x",
+"xprof_metadata":"{\\"block_q\\": 512}"
+}}, metadata={op_name="jit(step)/step.loss/jvp(M)/attn.core/pallas_call"}
+  ROOT %add.1 = f32[8]{0} add(%splash_fwd.1, %a), metadata={op_name="jit(step)/step.optimizer/add"}
+}
+"""
+    got = instruction_scopes(text)
+    assert got["splash_fwd.1"][:3] == [
+        "f32[8]", "jit(step)/step.loss/jvp(M)/attn.core/pallas_call", 0]
+    assert got["add.1"][:3] == ["f32[8]", "jit(step)/step.optimizer/add", 0]
