@@ -694,11 +694,15 @@ _SCOPE_LIST = [
     _sc("moe.route", "hydragnn_tpu/ops/moe.py",
         "router product, softmax, top-k, the held experts' loads"),
     _sc("moe.experts", "hydragnn_tpu/ops/moe.py",
-        "sort by expert, dispatch gather, grouped products, combine "
-        "(or the dense path)"),
+        "the held slots' rows, grouped products and the nodes' sums (or "
+        "the dense path)"),
     _sc("moe.gmm", "hydragnn_tpu/ops/moe.py",
         "the grouped matrix products alone (megablox gmm/tgmm, or "
         "ragged_dot), inside moe.experts"),
+    _sc("moe.rows", "hydragnn_tpu/ops/moe.py",
+        "the row movement alone, inside moe.experts: the held slots' "
+        "index bookkeeping, nodes -> rows and rows -> nodes (the two "
+        "row-walk kernels, or take / segment_sum)"),
     _sc("moe.shared", "hydragnn_tpu/models/laguna.py",
         "the shared expert's gated feed-forward"),
     _sc("lm.head", "hydragnn_tpu/models/laguna.py",
@@ -762,6 +766,12 @@ _KERNEL_LIST = [
         "GAT edge attention backward, receiver order"),
     _kn("gat_attn_bwd_s", "hydragnn_tpu/ops/gat_mp.py",
         "GAT edge attention backward, sender order"),
+    _kn("moe_nodes_to_rows", "hydragnn_tpu/ops/moe.py",
+        "routed experts: the held slots' node rows into expert-sorted "
+        "rows, zeros past the load"),
+    _kn("moe_rows_to_nodes", "hydragnn_tpu/ops/moe.py",
+        "routed experts: each node's weighted sum over its held slots' "
+        "rows, node tile by node tile"),
 ]
 
 KERNEL_NAMES: Dict[str, KernelName] = {k.name: k for k in _KERNEL_LIST}

@@ -253,6 +253,119 @@ def test_no_held_slot_is_dropped_under_a_skewed_router():
     assert float(stats["slots_held"]) <= 300.0
 
 
+def _held_case(name, key):
+    """(local, held, capacity) [n, k] for one shape of held slots."""
+    k1, k2 = jax.random.split(key)
+    if name == "several_slots_a_node":      # up to 3 held slots on a node
+        n, k, cap = 150, 3, 512
+        local = jax.random.randint(k1, (n, k), 0, 4)
+        held = jax.random.bernoulli(k2, 0.5, (n, k))
+    elif name == "one_node_in_two_row_tiles":
+        # every node holds experts 0 and 3: rows r and 200 + r, which lie
+        # in different tiles of the nodes -> rows kernel
+        n, k, cap = 200, 3, 512
+        local = jnp.tile(jnp.asarray([0, 1, 3]), (n, 1))
+        held = jnp.tile(jnp.asarray([True, False, True]), (n, 1))
+        assert 200 // moe.GATHER_TILE != 0
+    elif name == "load_zero":
+        n, k, cap = 150, 3, 512
+        local = jax.random.randint(k1, (n, k), 0, 4)
+        held = jnp.zeros((n, k), bool)
+    elif name == "load_is_the_capacity":
+        n, k, cap = 256, 2, 512
+        local = jax.random.randint(k1, (n, k), 0, 4)
+        held = jnp.ones((n, k), bool)
+    else:
+        assert name == "padding_nodes"      # three node tiles, the last
+        n, k, cap = 300, 3, 512             # ragged and all padding
+        local = jax.random.randint(k1, (n, k), 0, 4)
+        held = jax.random.bernoulli(k2, 0.4, (n, k)) & (
+            jnp.arange(n) < 250)[:, None]
+        assert n % moe.NODE_TILE and n > 2 * moe.NODE_TILE
+    return local, held, cap
+
+
+def _grouped_value_and_grads(backend, interpret, dtype, local, held, cap,
+                             key):
+    n, k = held.shape
+    d, f = 32, 16
+    ks = jax.random.split(key, 6)
+    u = jax.random.normal(ks[0], (n, d)).astype(dtype)
+    weights = jax.random.uniform(ks[1], (n, k), minval=0.1)
+    w1, w3 = (jax.random.normal(ks[i], (4, d, f)).astype(dtype) * 0.3
+              for i in (2, 3))
+    w2 = jax.random.normal(ks[4], (4, f, d)).astype(dtype) * 0.3
+    target = jax.random.normal(ks[5], (n, d))
+    loads = jnp.sum((local[..., None] == jnp.arange(4)) & held[..., None],
+                    axis=(0, 1), dtype=jnp.int32)
+
+    def f_(u, weights):
+        y = moe._grouped_path(u, weights, local, held, loads, w1, w3, w2,
+                              cap, backend, interpret)
+        return jnp.sum(y * target), y
+
+    return jax.value_and_grad(f_, argnums=(0, 1), has_aux=True), (u, weights)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", [
+    "several_slots_a_node", "one_node_in_two_row_tiles", "load_zero",
+    "load_is_the_capacity", "padding_nodes"])
+def test_row_kernels_interpreted_match_the_twin(case, dtype):
+    """The two row-walk kernels (interpreted) against the XLA twin through
+    the grouped path itself: the sum, and the gradients with respect to
+    the nodes' rows and to the routing weights."""
+    dtype = jnp.dtype(dtype)
+    local, held, cap = _held_case(case, jax.random.PRNGKey(7))
+    got, want = (
+        fn(*args) for fn, args in (
+            _grouped_value_and_grads(backend, interpret, dtype, local, held,
+                                     cap, jax.random.PRNGKey(8))
+            for backend, interpret in (("gmm", True), ("ragged_dot", False))))
+    ((_, ya), (dua, dwa)), ((_, yb), (dub, dwb)) = got, want
+    assert ya.dtype == jnp.float32 and dua.dtype == dtype
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    for a, b in ((ya, yb), (dua, dub), (dwa, dwb)):
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+        assert float(jnp.max(jnp.abs(a - b))) <= tol * max(
+            float(jnp.max(jnp.abs(b))), 1e-6)
+    # a slot that is not held moves nothing and learns nothing
+    assert not np.any(np.asarray(dwa)[~np.asarray(held)])
+    if case == "load_zero":
+        assert not np.any(np.asarray(ya)) and not np.any(np.asarray(dua))
+    else:
+        assert np.any(np.asarray(dwa)) and np.any(np.asarray(dua))
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_grouped_path_moves_no_index_array_of_all_the_slots():
+    """After the top-k nothing gathers, sorts or scatters over N x k: the
+    index (or key) arrays of the grouped path are ``capacity`` long."""
+    n, k, cap = 400, 3, 512
+    local, held = (jnp.zeros((n, k), jnp.int32), jnp.ones((n, k), bool))
+    fn, args = _grouped_value_and_grads(
+        "ragged_dot", False, jnp.float32, local, held, cap,
+        jax.random.PRNGKey(0))
+    moved = {"gather": [], "sort": [], "scatter": []}
+    for eqn in _eqns(jax.make_jaxpr(fn)(*args).jaxpr):
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            idx = eqn.invars[1].aval.shape
+            moved[name[:7]].append(int(np.prod(idx[:-1])))
+        elif name == "sort":
+            moved["sort"].append(
+                eqn.invars[0].aval.shape[eqn.params["dimension"]])
+    assert moved["gather"] and moved["sort"] and moved["scatter"]
+    assert all(m <= cap for ms in moved.values() for m in ms), moved
+    assert moved["sort"] == [cap]
+
+
 def test_softmax_xent_skips_padding_and_last_nodes():
     from hydragnn_tpu.models.layers import loss_function
 

@@ -16,10 +16,14 @@ are right, and every time or rate, still needs the chip
     python tools/mosaic_aot.py cfconv:128:float32:shard_map  # the CFConv
         # op's gradient (filter made in the kernels) under shard_map on
         # the 2x2 topology, as the four-chip DP step runs it
+    python tools/mosaic_aot.py moe_rows:20000:3072:bfloat16  # the routed
+        # experts' value-and-grad (N nodes of width D, products in DTYPE)
+        # with the two row-walk kernels and the megablox products
 
 The default list is every arch at h128 f32 plus the widths SchNet's
 in-kernel filter network chooses its edge blocks for (128 at ``highest``;
-256 / 512 / 1024 in f32 and bf16; 1024 at ``highest``) and the sharded op.
+256 / 512 / 1024 in f32 and bf16; 1024 at ``highest``), the sharded op,
+and the routed experts at the language-model cell's shapes in both dtypes.
 
 Exit code 0 only if every target compiled.
 """
@@ -123,6 +127,39 @@ def compile_cfconv_sharded(filters: int, dtype: str, devices):
     return calls, time.perf_counter() - t0
 
 
+def compile_moe_rows(nodes: int, width: int, dtype: str, sharding):
+    """Lower + compile the value-and-grad of ``ops/moe.py:routed_experts``
+    at the language-model cell's routing (8 of 256 experts held, top-10,
+    expert width 1024, the default capacity).  ``backend="gmm"`` is said
+    here: ``default_backend()`` asks the process, which runs on the CPU."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops import moe
+    from hydragnn_tpu.parallel.share import LayerShare
+
+    held, total, f = 8, 256, 1024
+    share = LayerShare(total, held, 0, 8, 1, 0, 100352, 12544, 0)
+
+    def loss(u, router, w1, w3, w2):
+        y, stats = moe.routed_experts(
+            u, router, w1, w3, w2, share, top_k=10, scale=2.5,
+            compute_dtype=jnp.dtype(dtype), backend="gmm")
+        return jnp.sum(y * y), stats
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+
+    lowered = jax.jit(jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3, 4), has_aux=True)).lower(
+        arg(nodes, width), arg(width, total), arg(held, width, f),
+        arg(held, width, f), arg(held, f, width))
+    calls = lowered.as_text().count("tpu_custom_call")
+    t0 = time.perf_counter()
+    lowered.compile()
+    return calls, time.perf_counter() - t0
+
+
 def main(argv) -> int:
     import jax
     from jax.experimental import topologies
@@ -147,7 +184,8 @@ def main(argv) -> int:
         # ``highest`` adds the f32 dots' multi-pass scratch: the widest
         # kernels under it are the ones nearest the VMEM limit
         + ["SchNet:1024:float32:highest", "SchNet:1024:bfloat16:highest",
-           "cfconv:128:float32:shard_map"])
+           "cfconv:128:float32:shard_map",
+           "moe_rows:20000:3072:bfloat16", "moe_rows:20000:3072:float32"])
     failed = 0
     for t in targets:
         arch, hidden, dtype, *rest = t.split(":")
@@ -155,6 +193,10 @@ def main(argv) -> int:
             if rest == ["shard_map"]:
                 calls, secs = compile_cfconv_sharded(
                     int(hidden), dtype, topo.devices)
+            elif arch == "moe_rows":
+                calls, secs = compile_moe_rows(
+                    int(hidden), int(dtype), rest[0],
+                    SingleDeviceSharding(dev))
             else:
                 calls, secs = compile_target(
                     arch, int(hidden), dtype, rest[0] if rest else None,
