@@ -705,10 +705,34 @@ _SCOPE_LIST = [
         "row-walk kernels, or take / segment_sum)"),
     _sc("moe.shared", "hydragnn_tpu/models/laguna.py",
         "the shared expert's gated feed-forward"),
+    _sc("moe.bias", "hydragnn_tpu/ops/moe.py",
+        "a router under a correction bias: the real nodes' slots on each "
+        "of ALL the experts, and the bias's step after a train step "
+        "(models/glm_moe_lite.py)"),
     _sc("lm.head", "hydragnn_tpu/models/laguna.py",
         "final norm and the untied head product"),
     _sc("lm.xent", "hydragnn_tpu/models/layers.py",
         "softmax cross-entropy against the next node's id"),
+    # latent attention and multi-token prediction (models/glm_moe_lite.py)
+    _sc("mla.down", "hydragnn_tpu/models/glm_moe_lite.py",
+        "latent attention's input norm, the query and key/value "
+        "bottlenecks (Wdq, Wdkv) and the two latent norms"),
+    _sc("mla.up", "hydragnn_tpu/models/glm_moe_lite.py",
+        "queries, keys and values rebuilt from the latents (Wuq, Wukv), "
+        "rotary, the one rotary key joined to every head's key"),
+    _sc("mla.core", "hydragnn_tpu/models/glm_moe_lite.py",
+        "latent attention's scores, softmax and values: graph_attention, "
+        "so attn.core lies inside it"),
+    _sc("mla.out", "hydragnn_tpu/models/glm_moe_lite.py",
+        "latent attention's output product (Wo)"),
+    _sc("mtp.proj", "hydragnn_tpu/models/glm_moe_lite.py",
+        "multi-token prediction: the next id's embedding, the two norms "
+        "and eh_proj"),
+    _sc("mtp.layer", "hydragnn_tpu/models/glm_moe_lite.py",
+        "multi-token prediction's own expert layer (its mla.* and moe.* "
+        "lie inside it)"),
+    _sc("mtp.head", "hydragnn_tpu/models/glm_moe_lite.py",
+        "multi-token prediction's final norm and the main head's product"),
 ]
 
 SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}

@@ -63,6 +63,10 @@ def _telemetry_defaults() -> Dict[str, Any]:
 
 
 EDGE_MODELS = ["PNA", "CGCNN", "SchNet", "EGNN"]
+# language models over each graph's nodes: the edge set is implicit, so no
+# edge list is built (data/transform.py) and the longest graph bands the
+# attention kernel (finalize)
+SEQUENCE_MODELS = ("Laguna", "GlmMoeLite")
 EQUIVARIANT_MODELS = ["EGNN", "SchNet"]
 ALL_MODEL_TYPES = [
     "SAGE",
@@ -75,6 +79,7 @@ ALL_MODEL_TYPES = [
     "DimeNet",
     "EGNN",
     "Laguna",
+    "GlmMoeLite",
 ]
 
 
@@ -132,7 +137,7 @@ def finalize(
         raise ValueError('"mlp_per_node" is not allowed for variable graph size')
 
     arch["input_dim"] = len(var["input_node_features"])
-    if arch["model_type"] == "Laguna":
+    if arch["model_type"] in SEQUENCE_MODELS:
         # no graph is longer than this: it bands the full-attention
         # layers' kernel (ops/attention.py)
         arch["max_graph_nodes"] = int(dataset_stats.max_nodes)

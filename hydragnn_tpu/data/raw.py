@@ -378,18 +378,23 @@ ATOMIC_NUMBERS: Dict[str, int] = {
 class TokenDataset(AbstractRawDataset):
     """Token documents: one file a document, its token ids as
     whitespace-separated integers.  Node features are ``[id, next id]``
-    (-1 where a token has no successor in its document), positions are
-    zeros, and nothing is scaled: an id is a category, and min-max
-    normalisation would make it a fraction.  The language-model stack
-    (models/laguna.py) reads column 0 and is trained against column 1."""
+    (-1 where a token has no successor in its document) and, where the
+    config names a third (a fourth ...) node feature, the id two (three
+    ...) tokens on; positions are zeros, and nothing is scaled: an id is
+    a category, and min-max normalisation would make it a fraction.  The
+    language-model stacks read column 0 and are trained against column 1
+    (models/laguna.py), the multi-token-prediction head against column 2
+    (models/glm_moe_lite.py)."""
 
     def transform_file(self, filepath: str) -> Optional[RawSample]:
         with open(filepath, "r", encoding="utf-8") as f:
             ids = np.asarray(f.read().split(), dtype=np.float64)
         if not len(ids):
             return None
-        nxt = np.concatenate([ids[1:], [-1.0]])
-        return RawSample(x=np.stack([ids, nxt], axis=1),
+        ahead = max(len(self.node_feature_dim), 2)
+        cols = [np.concatenate([ids[j:], np.full(min(j, len(ids)), -1.0)])
+                for j in range(ahead)]
+        return RawSample(x=np.stack(cols, axis=1),
                          pos=np.zeros((len(ids), 3)),
                          y=np.zeros((sum(self.graph_feature_dim),)))
 

@@ -17,6 +17,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from hydragnn_tpu.config.config import SEQUENCE_MODELS
 from hydragnn_tpu.data.raw import RawSample
 from hydragnn_tpu.graph.batch import GraphSample
 from hydragnn_tpu.graph.neighborlist import (
@@ -39,7 +40,7 @@ def select_feature_columns(
     return cols
 
 
-EDGE_FREE_MODELS = ("Laguna",)
+EDGE_FREE_MODELS = SEQUENCE_MODELS
 
 
 def transform_raw_samples(

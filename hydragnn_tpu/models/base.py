@@ -180,6 +180,14 @@ class ModelConfig:
             lm = LagunaConfig.from_arch(arch)
             share = LayerShare.from_arch(arch["laguna"],
                                          arch.get("share") or {})
+        if arch["model_type"] == "GlmMoeLite":
+            from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteConfig
+            from hydragnn_tpu.parallel.share import LayerShare
+
+            lm = GlmMoeLiteConfig.from_arch(arch)
+            share = LayerShare.from_arch(
+                arch["glm_moe_lite"], arch.get("share") or {},
+                experts_key="n_routed_experts")
         if arch["model_type"] == "CGCNN":
             # CGConv preserves feature dims (reference CGCNNStack.py:30-40)
             hidden_dim = arch["input_dim"]

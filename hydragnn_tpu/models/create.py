@@ -22,6 +22,7 @@ from hydragnn_tpu.models.cgcnn import CGCNNStack
 from hydragnn_tpu.models.schnet import SCFStack
 from hydragnn_tpu.models.egnn import EGCLStack
 from hydragnn_tpu.models.dimenet import DIMEStack
+from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteStack
 from hydragnn_tpu.models.laguna import LagunaStack
 
 _STACKS = {
@@ -42,9 +43,12 @@ _STACKS = {
 ALL_ARCHS = tuple(_STACKS)
 
 # Stacks that are not message passing over an edge list and so take no part
-# in the per-arch sweeps above: a language model over each graph's nodes
-# (models/laguna.py; its own reference and tests, tests/test_laguna.py).
-_SEQUENCE_STACKS = {"Laguna": LagunaStack}
+# in the per-arch sweeps above: language models over each graph's nodes,
+# each with its own reference and tests (models/laguna.py,
+# tests/test_laguna.py; models/glm_moe_lite.py, tests/test_glm_moe_lite.py).
+# The value's second entry is the model's own section of ``Architecture``.
+_SEQUENCE_STACKS = {"Laguna": (LagunaStack, "laguna"),
+                    "GlmMoeLite": (GlmMoeLiteStack, "glm_moe_lite")}
 
 
 def create_model_config(config: Dict[str, Any]) -> Base:
@@ -55,10 +59,11 @@ def create_model_config(config: Dict[str, Any]) -> Base:
 
 def create_model(cfg: ModelConfig) -> Base:
     if cfg.model_type in _SEQUENCE_STACKS:
+        stack, section = _SEQUENCE_STACKS[cfg.model_type]
         if cfg.lm is None or cfg.share is None:
             raise ValueError(
-                f"{cfg.model_type} requires Architecture.laguna")
-        return _SEQUENCE_STACKS[cfg.model_type](cfg=cfg)
+                f"{cfg.model_type} requires Architecture.{section}")
+        return stack(cfg=cfg)
     if cfg.model_type not in _STACKS:
         raise ValueError(f"Unknown model_type: {cfg.model_type}")
     if (cfg.model_type == "GAT" and cfg.dropout > 0

@@ -32,7 +32,7 @@ activations, not for five layers'.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, ClassVar, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -72,6 +72,7 @@ class LagunaConfig:
     num_attention_heads_per_layer: Tuple[int, ...]
     rope: Tuple[Tuple[str, Tuple[Tuple[str, Any], ...]], ...]
     max_graph_nodes: Optional[int] = None
+    router_scoring: ClassVar[str] = "softmax"     # ops/moe.py route
 
     @staticmethod
     def from_arch(arch: Dict[str, Any]) -> "LagunaConfig":
@@ -255,7 +256,9 @@ class MoE(nn.Module):
     interpret: bool
 
     @nn.compact
-    def __call__(self, h, node_mask):
+    def __call__(self, h, node_mask, bias=None):
+        """``bias`` [E]: the router's correction bias, where the model has
+        one (models/glm_moe_lite.py); the stats then carry ``counts_all``."""
         lm, share, d = self.lm, self.share, self.lm.hidden_size
         f, fs = lm.moe_intermediate_size, lm.shared_expert_intermediate_size
         e = share.experts_held
@@ -272,8 +275,9 @@ class MoE(nn.Module):
         y, stats = routed_experts(
             u, router, w1, w3, w2, share, node_mask=node_mask,
             top_k=lm.num_experts_per_tok, norm_topk=lm.norm_topk_prob,
-            scale=lm.moe_routed_scaling_factor, compute_dtype=self.dtype,
-            backend=self.backend, interpret=self.interpret)
+            scale=lm.moe_routed_scaling_factor, scoring=lm.router_scoring,
+            bias=bias, compute_dtype=self.dtype, backend=self.backend,
+            interpret=self.interpret)
         with phase("moe.shared"):
             return y + _gated_mlp(u, s1, s3, s2, self.dtype), stats
 
@@ -301,19 +305,11 @@ class LagunaStack(nn.Module):
         lm, share = self.cfg.lm, self.cfg.share
         dtype = (jnp.bfloat16 if self.cfg.compute_dtype == "bfloat16"
                  else jnp.float32)
-        n = g.num_nodes
         embed = self.param("embed", nn.initializers.normal(stddev=1.0),
                            (share.vocab_rows, lm.hidden_size))
         with phase("lm.embed"):
-            ids = jnp.clip(g.x[:, 0].astype(jnp.int32) - share.vocab_offset,
-                           0, share.vocab_rows - 1)
+            ids, positions = ids_and_positions(g, share)
             x = jnp.take(embed, ids, axis=0)
-            # a node's position is its index inside its graph: graphs are
-            # contiguous, so it is the distance to the graph's first node
-            idx = jnp.arange(n, dtype=jnp.int32)
-            first = jax.ops.segment_min(idx, g.node_gid, g.num_graphs,
-                                        indices_are_sorted=True)
-            positions = idx - jnp.take(first, g.node_gid)
         stats = []
         for layer in range(lm.num_layers):
             x, s = LagunaLayer(lm, share, layer, dtype, self.attention_backend,
@@ -329,24 +325,39 @@ class LagunaStack(nn.Module):
         with phase("lm.head"):
             logits = _dot(_rms_norm(x, final_norm, lm.rms_norm_eps), head,
                           dtype)
-        self._count(stats, train)
+        count_routing(self, stats, train)
         return (logits,)
 
-    def _count(self, stats, train):
-        """Routing counters of this step, summed over the expert layers
-        (the imbalance averaged), kept in ``batch_stats`` so that the
-        train step's metrics can carry them out (trainer.model_counters)."""
-        names = ("moe_slots_held", "moe_slots_all", "moe_dense_steps",
-                 "moe_load_max_over_mean")
-        cells = [self.variable("batch_stats", k,
-                               lambda: jnp.zeros((), jnp.float32))
-                 for k in names]
-        if not stats or not train or self.is_initializing():
-            return
-        total = {k: sum(s[k] for s in stats)
-                 for k in ("slots_held", "slots_all", "dense_steps")}
-        values = (total["slots_held"], total["slots_all"],
-                  total["dense_steps"],
-                  sum(s["load_max_over_mean"] for s in stats) / len(stats))
-        for cell, v in zip(cells, values):
-            cell.value = v
+
+def ids_and_positions(g: GraphBatch, share: LayerShare):
+    """(row of the held embedding slice, position) of every node."""
+    ids = jnp.clip(g.x[:, 0].astype(jnp.int32) - share.vocab_offset,
+                   0, share.vocab_rows - 1)
+    # a node's position is its index inside its graph: graphs are
+    # contiguous, so it is the distance to the graph's first node
+    idx = jnp.arange(g.num_nodes, dtype=jnp.int32)
+    first = jax.ops.segment_min(idx, g.node_gid, g.num_graphs,
+                                indices_are_sorted=True)
+    return ids, idx - jnp.take(first, g.node_gid)
+
+
+def count_routing(stack: nn.Module, stats, train, **more):
+    """Routing counters of this step, summed over the expert layers (the
+    imbalance averaged), kept in ``stack``'s ``batch_stats`` so that the
+    train step's metrics can carry them out (trainer.model_counters);
+    ``more``: further scalars of the stack's own, stored as ``moe_<key>``."""
+    names = ("slots_held", "slots_all", "dense_steps", "load_max_over_mean",
+             *more)
+    cells = [stack.variable("batch_stats", f"moe_{k}",
+                            lambda: jnp.zeros((), jnp.float32))
+             for k in names]
+    if not stats or not train or stack.is_initializing():
+        return
+    total = {k: sum(s[k] for s in stats)
+             for k in ("slots_held", "slots_all", "dense_steps")}
+    values = (total["slots_held"], total["slots_all"],
+              total["dense_steps"],
+              sum(s["load_max_over_mean"] for s in stats) / len(stats),
+              *more.values())
+    for cell, v in zip(cells, values):
+        cell.value = v

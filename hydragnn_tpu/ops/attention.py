@@ -7,12 +7,18 @@ collate), so node ``i`` sees node ``j`` iff they share ``node_gid`` and
 packed node axis, cut per graph by the ids.
 
 Two backends, one contract (q [N, H, d], k and v [N, KV, d] -> [N, H, d];
-query head ``a`` reads key/value head ``a // (H / KV)``; scores
-``q . k / sqrt(d)``, softmax in float32):
+``KV`` divides ``H``, query head ``a`` reads key/value head
+``a // (H / KV)``; scores ``q . k / sqrt(d)``, softmax in float32).  Run
+so far: grouped queries over ONE key/value head at ``d`` 128
+(models/laguna.py), and as many key/value heads as query heads, 20 of
+each, at ``d`` 256 (latent attention's rebuilt keys and values,
+models/glm_moe_lite.py).
 
 ``splash``  JAX's segment-masked banded flash kernels
     (``jax.experimental.pallas.ops.tpu.splash_attention``: forward, dq and
-    dkv kernels, multi-query per key/value head), the TPU path.  Its block
+    dkv kernels), the TPU path: ONE multi-head call where every query
+    head has its own key/value head, else one multi-query call per
+    key/value head.  Its block
     skipping follows the STATIC band only: a full-attention layer is
     banded to ``max_span`` (no graph is longer, so nothing visible is
     cut), and every block of that band is computed whatever the graphs'
@@ -60,9 +66,10 @@ def _dense(q, k, v, node_gid, window):
 
 
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(n, heads, band, interpret):
-    """The multi-query kernel for ``heads`` query heads over one key/value
-    head on a node axis of ``n``, causal and banded to ``band`` back."""
+def _splash_kernel(n, heads, band, interpret, multi_head=False):
+    """The kernel for ``heads`` query heads on a node axis of ``n``, causal
+    and banded to ``band`` back: multi-query over ONE key/value head, or
+    (``multi_head``) each query head over a key/value head of its own."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
         splash_attention_mask as sm,
@@ -76,7 +83,8 @@ def _splash_kernel(n, heads, band, interpret):
     # the mask tables are constants: made outside whatever trace calls us,
     # or the cache would hand one trace's tracers to the next
     with jax.ensure_compile_time_eval():
-        return sk.make_splash_mqa(
+        make = sk.make_splash_mha if multi_head else sk.make_splash_mqa
+        return make(
             sm.MultiHeadMask([mask] * heads), block_sizes=blocks,
             head_shards=1, q_seq_shards=1, interpret=interpret)
 
@@ -98,6 +106,10 @@ def _splash(q, k, v, node_gid, window, max_span, interpret):
     gid = jnp.pad(node_gid.astype(jnp.int32), (0, n_pad - n),
                   constant_values=jnp.iinfo(jnp.int32).max)
     seg = sk.SegmentIds(q=gid, kv=gid)
+    if kv == h and h > 1:
+        out = _splash_kernel(n_pad, h, band, bool(interpret), True)(
+            q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), seg)
+        return out.swapaxes(0, 1)[:n].astype(q.dtype)
     kernel = _splash_kernel(n_pad, h // kv, band, bool(interpret))
     out = [kernel(q[:, a * (h // kv):(a + 1) * (h // kv)].swapaxes(0, 1),
                   k[:, a], v[:, a], seg) for a in range(kv)]

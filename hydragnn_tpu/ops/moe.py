@@ -2,10 +2,12 @@
 
 The layer is TOLD which experts it holds (parallel/share.py LayerShare).
 It routes every node over ALL the experts (the router keeps its published
-width), keeps the slots that fall on its own experts, sorts them by expert
-and runs the gated feed-forward as grouped matrix products over the ragged
-groups; the weighted outputs are added up per node.  What the other
-ranks' experts would add is left out: on one chip there is no exchange.
+width; ``route``: softmax scores and their k largest, or sigmoid scores
+selected under a correction bias that carries no gradient), keeps the
+slots that fall on its own experts, sorts them by expert and runs the
+gated feed-forward as grouped matrix products over the ragged groups; the
+weighted outputs are added up per node.  What the other ranks' experts
+would add is left out: on one chip there is no exchange.
 
 No slot of a held expert is dropped.  The grouped path works on a static
 number of rows, ``capacity`` (a multiple of the kernel's row tile, by
@@ -78,17 +80,35 @@ def default_capacity(num_nodes, top_k, experts_held, num_experts_total,
     return -(-rows // ROW_TILE) * ROW_TILE
 
 
-def route(u, router_w, top_k, norm_topk=True, scale=1.0):
-    """(expert ids [N, k], weights [N, k]) over all the experts: softmax
-    scores in float32, the k largest, renormalised, times ``scale``.  The
-    product runs at HIGHEST precision whatever the step's default is: a
-    bf16 pass moves scores by 2^-9, enough to swap the 10th and 11th
-    expert of a node, and a swapped expert is a different function."""
+def route(u, router_w, top_k, norm_topk=True, scale=1.0, scoring="softmax",
+          bias=None):
+    """(expert ids [N, k], weights [N, k]) over all the experts, scores in
+    float32.  ``scoring`` "softmax": softmax scores, the k largest,
+    renormalised, times ``scale``.  ``scoring`` "sigmoid" (DeepSeek-V3's
+    ``noaux_tc``, one group): sigmoid scores; with ``bias`` [E] the k
+    largest of ``score + bias`` are SELECTED and the weights are the
+    selected experts' UNbiased scores, renormalised (+ 1e-20), times
+    ``scale``: the bias moves load and never a weight, and no gradient
+    reaches it.  The product runs at HIGHEST precision whatever the step's
+    default is: a bf16 pass moves scores by 2^-9, enough to swap the last
+    selected expert of a node with the first one left out, and a swapped
+    expert is a different function."""
     logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    top, ids = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if scoring == "softmax":
+        scores, eps = jax.nn.softmax(logits, axis=-1), None
+    elif scoring == "sigmoid":
+        scores, eps = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    if bias is None:
+        top, ids = lax.top_k(scores, top_k)
+    else:
+        _, ids = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk:
-        top = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total if eps is None else total + eps)
     return ids, top * scale
 
 
@@ -456,20 +476,25 @@ def _dense_path(u, weights, local, held, loads, w1, w3, w2):
 
 
 def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
-                   norm_topk=True, scale=1.0, compute_dtype=jnp.float32,
-                   capacity=None, backend=None, interpret=False):
+                   norm_topk=True, scale=1.0, scoring="softmax", bias=None,
+                   compute_dtype=jnp.float32, capacity=None, backend=None,
+                   interpret=False):
     """The held experts' part of the routed sum for nodes ``u`` [N, D].
 
-    ``w1``/``w3`` [held, D, F], ``w2`` [held, F, D], ``router_w`` [D, E].
-    Padding nodes (``node_mask`` 0) are routed nowhere: they all carry the
-    same input, and would land on one expert together.  Returns (float32
-    [N, D], stats): ``slots_held`` routed to held experts, ``slots_all``
-    of the real nodes, ``load_max_over_mean`` over the held experts,
-    ``dense_steps`` (1.0 when the dense path ran)."""
+    ``w1``/``w3`` [held, D, F], ``w2`` [held, F, D], ``router_w`` [D, E];
+    ``scoring`` and ``bias`` [E] as ``route`` takes them.  Padding nodes
+    (``node_mask`` 0) are routed nowhere: they all carry the same input,
+    and would land on one expert together.  Returns (float32 [N, D],
+    stats): ``slots_held`` routed to held experts, ``slots_all`` of the
+    real nodes, ``load_max_over_mean`` over the held experts,
+    ``dense_steps`` (1.0 when the dense path ran) and, with a ``bias``,
+    ``counts_all`` [E]: the real nodes' slots on each of ALL the experts,
+    what the bias's update reads."""
     backend = backend or default_backend()
     n = u.shape[0]
     with phase("moe.route"):
-        ids, weights = route(u, router_w, top_k, norm_topk, scale)
+        ids, weights = route(u, router_w, top_k, norm_topk, scale, scoring,
+                             bias)
         local, held = share.local_expert(ids)
         real = (jnp.ones((n,), bool) if node_mask is None
                 else node_mask > 0)
@@ -497,4 +522,9 @@ def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
             load / share.experts_held, 1.0),
         "dense_steps": 1.0 - fits.astype(jnp.float32),
     }
+    if bias is not None:
+        with phase("moe.bias"):
+            stats["counts_all"] = jnp.sum(
+                (ids[..., None] == jnp.arange(share.num_experts_total)) &
+                real[:, None, None], axis=(0, 1), dtype=jnp.float32)
     return y, stats

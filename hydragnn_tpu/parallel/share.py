@@ -2,11 +2,12 @@
 
 A layer of a sparse language model is shared by ``R`` chips: its routed
 experts over expert-parallel ranks, its attention heads over
-tensor-parallel ranks (one group of query heads per key/value head), its
-vocabulary over vocabulary-parallel ranks.  ``LayerShare`` says what THIS
-chip holds; the layer reads it (models/laguna.py, ops/moe.py): the router
-keeps its published width and routes over all the experts, and the chip
-computes the part of the result its own experts give.  On one chip the
+tensor-parallel ranks (one group of query heads per key/value head; or
+none cut, where attention is data-parallel), its vocabulary over
+vocabulary-parallel ranks.  ``LayerShare`` says what THIS chip holds; the
+layer reads it (models/laguna.py, models/glm_moe_lite.py, ops/moe.py): the
+router keeps its published width and routes over all the experts, and the
+chip computes the part of the result its own experts give.  On one chip the
 layer runs without its exchange: what the absent experts and heads would
 add is left out, nothing stands in for the absent chips, and the partial
 result goes on to the next layer (the plain reference is given the same
@@ -46,14 +47,18 @@ class LayerShare:
                     f"share holds {what} [{off}, {off + held}) of {total}")
 
     @staticmethod
-    def from_arch(lm: Dict[str, Any], share: Dict[str, Any]) -> "LayerShare":
-        """From ``Architecture.laguna`` (the sizes held here) and
-        ``Architecture.share`` (the published totals and this chip's
-        offsets; absent = the uncut model)."""
+    def from_arch(lm: Dict[str, Any], share: Dict[str, Any],
+                  experts_key: str = "num_experts") -> "LayerShare":
+        """From the model's own section of ``Architecture`` (the sizes held
+        here; ``experts_key`` is its name for the routed experts' count)
+        and ``Architecture.share`` (the published totals and this chip's
+        offsets; absent = the uncut model).  A deployment that cuts nothing
+        from the heads (attention data-parallel: models/glm_moe_lite.py)
+        gives no ``kv_heads_total``, and every head is held."""
         return LayerShare(
             num_experts_total=int(share.get("num_experts_total",
-                                            lm["num_experts"])),
-            experts_held=int(lm["num_experts"]),
+                                            lm[experts_key])),
+            experts_held=int(lm[experts_key]),
             expert_offset=int(share.get("expert_offset", 0)),
             kv_heads_total=int(share.get("kv_heads_total",
                                          lm["num_key_value_heads"])),

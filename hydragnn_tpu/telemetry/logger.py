@@ -590,6 +590,10 @@ class MetricsLogger:
                         m["moe_load_max_over_mean"]),
                     "dense_steps": float(m["moe_dense_steps"]),
                 }
+                # a router under a correction bias (models/glm_moe_lite.py)
+                for k in ("load_all_max_over_mean", "bias_abs_max"):
+                    if f"moe_{k}" in m:
+                        rec["moe"][k] = float(m[f"moe_{k}"])
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

@@ -58,18 +58,17 @@ def create_train_state(
     opt_spec: OptimizerSpec,
     seed: int = 0,
 ) -> TrainState:
-    init = lambda batch: model.init(  # noqa: E731
-        {"params": jax.random.PRNGKey(seed),
-         "dropout": jax.random.PRNGKey(seed + 1)},
-        batch,
-        train=False,
-    )
+    init = lambda key, drop, batch: model.init(  # noqa: E731
+        {"params": key, "dropout": drop}, batch, train=False)
     if getattr(model, "jit_init", False):
         # a stack whose forward is far too large to run op by op just to
         # shape its parameters (models/laguna.py): under jit the forward
-        # is dead code and only the initialisers are compiled
+        # is dead code and only the initialisers are compiled.  The keys
+        # are arguments: built inside, the seed would be a constant of the
+        # program, and every seed would compile its own
         init = jax.jit(init)
-    variables = init(example_batch)
+    variables = init(jax.random.PRNGKey(seed), jax.random.PRNGKey(seed + 1),
+                     example_batch)
     params = variables["params"]
     if getattr(model, "cfg", None) is not None and model.cfg.initial_bias is not None:
         from hydragnn_tpu.models.base import set_initial_bias
@@ -238,7 +237,7 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
 def model_counters(batch_stats) -> Dict[str, jax.Array]:
     """What the model counted in this step for the step records: the
     top-level ``moe_*`` scalars a stack keeps in ``batch_stats``
-    (models/laguna.py LagunaStack._count); {} for every other stack."""
+    (models/laguna.py count_routing); {} for every other stack."""
     return {k: v for k, v in batch_stats.items()
             if k.startswith("moe_") and getattr(v, "ndim", None) == 0}
 
@@ -455,18 +454,25 @@ def make_scan_train_step(
 
 
 def make_eval_step(
-    model: Base, cfg: ModelConfig
+    model: Base, cfg: ModelConfig, outputs: bool = True
 ) -> Callable[[TrainState, GraphBatch], Dict[str, Any]]:
+    """``outputs=False``: the losses alone, for a caller that reads nothing
+    else (the epoch loop's val and test passes).  A head's output can be
+    large (a language model's logits: 1.5 GB a head and batch), and a
+    returned array lives until its metrics dict is dropped, beside the
+    next dispatch's temporaries."""
     def eval_step(state: TrainState, g: GraphBatch):
         with phase("step.eval"):
-            loss, (per_head, _, outputs) = _loss_and_metrics(
+            loss, (per_head, _, outs) = _loss_and_metrics(
                 model, cfg, state.params, state.batch_stats, g, False)
-        return {
+        metrics = {
             "loss": loss,
             "num_graphs": g.n_real_graphs,
             "per_head": per_head,
-            "outputs": outputs,
         }
+        if outputs:
+            metrics["outputs"] = outs
+        return metrics
 
     return eval_step
 
@@ -1336,7 +1342,8 @@ def train_validate_test(
             train_loader = ResidentDeviceLoader(train_loader)
             val_loader = ResidentDeviceLoader(val_loader)
             test_loader = ResidentDeviceLoader(test_loader)
-        eval_step = jax.jit(make_eval_step(model, cfg))
+        # _run_epoch reads the losses only
+        eval_step = jax.jit(make_eval_step(model, cfg, outputs=False))
 
     # the launched world shape as the elastic machinery defines it:
     # dp_extent is the number of batch shards per step — the extent the
