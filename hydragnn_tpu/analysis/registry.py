@@ -688,7 +688,8 @@ _SCOPE_LIST = [
         "product"),
     _sc("attn.core", "hydragnn_tpu/ops/attention.py",
         "scores, softmax and values over each graph's nodes (the splash "
-        "kernels, or the dense twin)"),
+        "kernels and the block schedule made from node_gid, or the dense "
+        "twin)"),
     _sc("ffn.dense", "hydragnn_tpu/models/laguna.py",
         "the dense gated feed-forward, node slice by node slice"),
     _sc("moe.route", "hydragnn_tpu/ops/moe.py",

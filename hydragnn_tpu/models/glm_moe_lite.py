@@ -53,10 +53,11 @@ from hydragnn_tpu.models.laguna import (
     _dot,
     _init,
     _rms_norm,
+    count_blocks,
     count_routing,
     ids_and_positions,
 )
-from hydragnn_tpu.ops.attention import graph_attention
+from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
 
@@ -139,7 +140,7 @@ class LatentAttention(nn.Module):
     interpret: bool
 
     @nn.compact
-    def __call__(self, x, node_gid, positions):
+    def __call__(self, x, node_gid, node_mask, positions):
         lm, d, heads = self.lm, self.lm.hidden_size, self.lm.num_attention_heads
         nope, rope, dv = lm.qk_nope_head_dim, lm.qk_rope_head_dim, lm.v_head_dim
         rq, rkv = lm.q_lora_rank, lm.kv_lora_rank
@@ -172,12 +173,14 @@ class LatentAttention(nn.Module):
                 axis=-1).astype(self.dtype)
             v = kv[..., nope:].astype(self.dtype)
         with phase("mla.core"):
-            o = graph_attention(q, k, v, node_gid,
+            o = graph_attention(q, k, v, node_gid, node_mask,
                                 max_span=lm.max_graph_nodes,
                                 backend=self.backend,
                                 interpret=self.interpret)
+            blocks = scheduled_blocks(node_gid, node_mask,
+                                      max_span=lm.max_graph_nodes)
         with phase("mla.out"):
-            return _dot(o.reshape(n, heads * dv), wo, self.dtype)
+            return _dot(o.reshape(n, heads * dv), wo, self.dtype), blocks
 
 
 class GlmLayer(nn.Module):
@@ -192,15 +195,16 @@ class GlmLayer(nn.Module):
     @nn.compact
     def __call__(self, x, node_gid, node_mask, positions, bias):
         lm = self.lm
-        h = x + nn.remat(LatentAttention)(
+        a, blocks = nn.remat(LatentAttention)(
             lm, self.dtype, self.attention_backend, self.interpret,
-            name="attn")(x, node_gid, positions)
+            name="attn")(x, node_gid, node_mask, positions)
+        h = x + a
         if self.dense:
-            return h + DenseFFN(lm, self.dtype, name="ffn")(h), None
+            return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
         y, stats = nn.remat(MoE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask, bias)
-        return h + y, stats
+        return h + y, stats, blocks
 
 
 class NextNextToken(nn.Module):
@@ -234,13 +238,13 @@ class NextNextToken(nn.Module):
                 [_rms_norm(jnp.take(embed, after, axis=0), enorm, eps),
                  _rms_norm(h, hnorm, eps)], axis=-1), eh_proj, self.dtype)
         with phase("mtp.layer"):
-            x, stats = GlmLayer(
+            x, stats, blocks = GlmLayer(
                 lm, self.share, False, self.dtype, self.attention_backend,
                 self.moe_backend, self.interpret, name="layer")(
                     x, g.node_gid, g.node_mask * has_next, positions, bias)
         with phase("mtp.head"):
             return _dot(_rms_norm(x, final_norm, eps), head,
-                        self.dtype), stats
+                        self.dtype), stats, blocks
 
 
 class GlmMoeLiteStack(nn.Module):
@@ -276,13 +280,14 @@ class GlmMoeLiteStack(nn.Module):
         with phase("lm.embed"):
             ids, positions = ids_and_positions(g, share)
             x = jnp.take(embed, ids, axis=0)
-        stats = {}
+        stats, blocks = {}, []
         for layer in range(lm.num_hidden_layers):
             name = f"layer_{layer}"
-            x, s = GlmLayer(lm, share, layer < lm.first_k_dense_replace,
-                            dtype, *backends, name=name)(
+            x, s, b = GlmLayer(lm, share, layer < lm.first_k_dense_replace,
+                               dtype, *backends, name=name)(
                 x, g.node_gid, g.node_mask, positions,
                 biases[name].value if name in biases else None)
+            blocks.append(b)
             if s is not None:
                 stats[name] = s
         final_norm = self.param("final_norm", nn.initializers.ones,
@@ -294,12 +299,14 @@ class GlmMoeLiteStack(nn.Module):
                           dtype)
         outputs = (logits,)
         if "mtp" in biases:
-            logits2, stats["mtp"] = NextNextToken(
+            logits2, stats["mtp"], b = NextNextToken(
                 lm, share, dtype, *backends, name="mtp")(
                     x, ids, embed, head, g, positions, biases["mtp"].value)
+            blocks.append(b)
             outputs = (logits, logits2)
         if biases:
             self._balance(biases, stats, train)
+        count_blocks(self, blocks, train)
         return outputs
 
     def _balance(self, biases, stats, train):

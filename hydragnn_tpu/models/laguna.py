@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.laguna_reference import apply_rotary
-from hydragnn_tpu.ops.attention import graph_attention
+from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
 from hydragnn_tpu.ops.moe import routed_experts
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
@@ -175,15 +175,16 @@ class LagunaLayer(nn.Module):
         # the attention half and the feed-forward half are each recomputed
         # in the backward pass from their input alone (the dense
         # feed-forward slice by slice, DenseFFN)
-        h = x + nn.remat(Attention)(
+        a, blocks = nn.remat(Attention)(
             lm, kind, heads, kv, self.dtype, self.attention_backend,
-            self.interpret, name="attn")(x, node_gid, positions)
+            self.interpret, name="attn")(x, node_gid, node_mask, positions)
+        h = x + a
         if lm.mlp_layer_types[self.layer] == "dense":
-            return h + DenseFFN(lm, self.dtype, name="ffn")(h), None
+            return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
         y, stats = nn.remat(MoE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask)
-        return h + y, stats
+        return h + y, stats, blocks
 
 
 class Attention(nn.Module):
@@ -196,7 +197,7 @@ class Attention(nn.Module):
     interpret: bool
 
     @nn.compact
-    def __call__(self, x, node_gid, positions):
+    def __call__(self, x, node_gid, node_mask, positions):
         lm, d, hd = self.lm, self.lm.hidden_size, self.lm.head_dim
         n = x.shape[0]
         norm = self.param("norm", nn.initializers.ones, (d,))
@@ -221,12 +222,15 @@ class Attention(nn.Module):
             gate = jax.nn.sigmoid(_dot(u, wg, self.dtype))
         window = (lm.sliding_window if self.kind == "sliding_attention"
                   else None)
-        o = graph_attention(q, k, v, node_gid, window=window,
+        o = graph_attention(q, k, v, node_gid, node_mask, window=window,
                             max_span=lm.max_graph_nodes,
                             backend=self.backend, interpret=self.interpret)
+        blocks = scheduled_blocks(node_gid, node_mask, window=window,
+                                  max_span=lm.max_graph_nodes)
         with phase("attn.proj"):
             o = o.astype(jnp.float32) * gate[:, :, None]
-            return _dot(o.reshape(n, self.heads * hd), wo, self.dtype)
+            return _dot(o.reshape(n, self.heads * hd), wo,
+                        self.dtype), blocks
 
 
 class DenseFFN(nn.Module):
@@ -310,12 +314,13 @@ class LagunaStack(nn.Module):
         with phase("lm.embed"):
             ids, positions = ids_and_positions(g, share)
             x = jnp.take(embed, ids, axis=0)
-        stats = []
+        stats, blocks = [], []
         for layer in range(lm.num_layers):
-            x, s = LagunaLayer(lm, share, layer, dtype, self.attention_backend,
-                         self.moe_backend, self.interpret,
-                         name=f"layer_{layer}")(
-                             x, g.node_gid, g.node_mask, positions)
+            x, s, b = LagunaLayer(
+                lm, share, layer, dtype, self.attention_backend,
+                self.moe_backend, self.interpret, name=f"layer_{layer}")(
+                    x, g.node_gid, g.node_mask, positions)
+            blocks.append(b)
             if s is not None:
                 stats.append(s)
         final_norm = self.param("final_norm", nn.initializers.ones,
@@ -326,6 +331,7 @@ class LagunaStack(nn.Module):
             logits = _dot(_rms_norm(x, final_norm, lm.rms_norm_eps), head,
                           dtype)
         count_routing(self, stats, train)
+        count_blocks(self, blocks, train)
         return (logits,)
 
 
@@ -361,3 +367,17 @@ def count_routing(stack: nn.Module, stats, train, **more):
               *more.values())
     for cell, v in zip(cells, values):
         cell.value = v
+
+
+def count_blocks(stack: nn.Module, blocks, train):
+    """The attention kernels' block schedule of this step, summed over the
+    attending layers' forward calls (``blocks``: one
+    ops/attention.py ``scheduled_blocks`` each), kept as
+    ``count_routing`` keeps its counters."""
+    cells = [stack.variable("batch_stats", f"attn_{k}",
+                            lambda: jnp.zeros((), jnp.float32))
+             for k in ("blocks_run", "blocks_band")]
+    if not train or stack.is_initializing():
+        return
+    for cell, values in zip(cells, zip(*blocks)):
+        cell.value = jnp.asarray(sum(values), jnp.float32)

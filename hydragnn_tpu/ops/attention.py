@@ -14,15 +14,23 @@ so far: grouped queries over ONE key/value head at ``d`` 128
 each, at ``d`` 256 (latent attention's rebuilt keys and values,
 models/glm_moe_lite.py).
 
+A padding node (``node_mask`` 0; graph/batch.py puts them all on ONE
+trailing graph) is a graph of its own to both backends: it sees itself, so
+its row stays finite, and nothing else.  Nothing reads a padding row.
+
 ``splash``  JAX's segment-masked banded flash kernels
     (``jax.experimental.pallas.ops.tpu.splash_attention``: forward, dq and
     dkv kernels), the TPU path: ONE multi-head call where every query
     head has its own key/value head, else one multi-query call per
-    key/value head.  Its block
-    skipping follows the STATIC band only: a full-attention layer is
-    banded to ``max_span`` (no graph is longer, so nothing visible is
-    cut), and every block of that band is computed whatever the graphs'
-    lengths are (PERF.md, Open questions).
+    key/value head.  The kernel is built once per shape from the STATIC
+    band (a full-attention layer is banded to ``max_span``: no graph is
+    longer, so nothing visible is cut), which sizes its grid; which blocks
+    of that band RUN follows the batch: ``_needed`` marks, from two
+    strided reads of the ids, the blocks in which some graph has a
+    visible pair, and each call hands the three kernels block tables with
+    the others switched off (``_follow``).  A skipped block held only
+    masked pairs, whose terms were exact zeros; it costs a grid step and
+    no copy.
 ``dense``   the masked [N, N] composition in ``jax.numpy``: the CPU path
     and the twin the tests hold the kernels to; quadratic in memory.
 """
@@ -34,6 +42,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from hydragnn_tpu.utils.scope import phase
 
@@ -65,6 +74,72 @@ def _dense(q, k, v, node_gid, window):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+def _padded(n, window, max_span):
+    """(the node axis padded to whole blocks, the band in nodes)."""
+    n_pad = -(-n // _BLOCK) * _BLOCK
+    return n_pad, min(window or max_span or n_pad, n_pad)
+
+
+def _own_ids(node_gid, node_mask, n_pad):
+    """``node_gid`` over the padded axis, every padding node and every row
+    past N under an id of its own, past the real ones."""
+    n = node_gid.shape[0]
+    real = (jnp.ones((n,), bool) if node_mask is None else node_mask > 0)
+    own = jnp.iinfo(jnp.int32).max - jnp.arange(n_pad, dtype=jnp.int32)
+    return jnp.where(jnp.pad(real, (0, n_pad - n)),
+                     jnp.pad(node_gid.astype(jnp.int32), (0, n_pad - n)),
+                     own)
+
+
+def _needed(gid, band):
+    """(needed, in_band), both [blocks, blocks] over (query, key) blocks:
+    the static band, and inside it the blocks where some graph has a
+    visible pair.  Nodes of a graph are contiguous, so a graph reaches
+    from block ``j`` into block ``i > j`` iff it holds ``j``'s last node
+    and ``i``'s first, and that pair is also the nearest one; the
+    diagonal always runs."""
+    i = np.arange(gid.shape[0] // _BLOCK)[:, None]
+    j = i.T
+    in_band = (j <= i) & ((i - j - 1) * _BLOCK + 1 < band)
+    spans = gid[::_BLOCK][:, None] == gid[_BLOCK - 1::_BLOCK][None, :]
+    return in_band & ((i == j) | spans), in_band
+
+
+def scheduled_blocks(node_gid, node_mask=None, *, window=None,
+                     max_span=None):
+    """(blocks one forward call of the splash backend runs on this batch,
+    blocks of its static band): the non-zero entries of the forward table
+    after and before ``_follow``, whichever backend runs."""
+    n_pad, band = _padded(node_gid.shape[0], window, max_span)
+    with phase("attn.core"):
+        needed, in_band = _needed(_own_ids(node_gid, node_mask, n_pad), band)
+        return jnp.sum(needed), int(in_band.sum())
+
+
+def _follow(info, needed, dkv):
+    """``info`` (a kernel's static tables, [1, query blocks, positions], or
+    for dkv [1, positions, key blocks]: the grid is shrunk to the band, and
+    ``data_next`` says which key block (dkv: query block) a position
+    means) with the blocks outside ``needed`` switched off.  A position
+    switched off names the block the next running position of its row
+    (dkv: column) reads, or past the last one the block it read, so it
+    moves no data."""
+    if dkv:     # the same walk with the two block axes exchanged
+        needed = needed.T
+    lay = (lambda a: a.swapaxes(1, 2)) if dkv else (lambda a: a)
+    blk = lay(info.data_next).astype(jnp.int32)
+    rows, width = blk.shape[1:]
+    run = (lay(info.block_mask) != 0) & needed[jnp.arange(rows)[:, None], blk]
+    pos = jnp.arange(width)
+    ahead = jax.lax.cummin(jnp.where(run, pos, width), axis=2, reverse=True)
+    behind = jax.lax.cummax(jnp.where(run, pos, 0), axis=2)
+    data = jnp.take_along_axis(
+        blk, jnp.where(ahead < width, ahead, behind), axis=2)
+    return info._replace(
+        data_next=lay(data).astype(info.data_next.dtype),
+        block_mask=jnp.where(lay(run), info.block_mask, 0))
+
+
 @functools.lru_cache(maxsize=None)
 def _splash_kernel(n, heads, band, interpret, multi_head=False):
     """The kernel for ``heads`` query heads on a node axis of ``n``, causal
@@ -89,44 +164,52 @@ def _splash_kernel(n, heads, band, interpret, multi_head=False):
             head_shards=1, q_seq_shards=1, interpret=interpret)
 
 
-def _splash(q, k, v, node_gid, window, max_span, interpret):
+def _splash(q, k, v, gid, band, interpret):
+    """``gid``: ``_own_ids`` over the padded axis."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
 
     n, h, d = q.shape
     kv = k.shape[1]
-    n_pad = -(-n // _BLOCK) * _BLOCK
-    band = min(window or max_span or n_pad, n_pad)
+    n_pad = gid.shape[0]
     pad = ((0, n_pad - n), (0, 0), (0, 0))
-    # the kernel takes the scale with q; rows past N form a graph of their
-    # own, one id past the batch's padding graph
+    # the kernel takes the scale with q
     q = jnp.pad(q * (1.0 / math.sqrt(d)), pad)
     k, v = jnp.pad(k, pad), jnp.pad(v, pad)
-    gid = jnp.pad(node_gid.astype(jnp.int32), (0, n_pad - n),
-                  constant_values=jnp.iinfo(jnp.int32).max)
     seg = sk.SegmentIds(q=gid, kv=gid)
-    if kv == h and h > 1:
-        out = _splash_kernel(n_pad, h, band, bool(interpret), True)(
-            q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1), seg)
+    multi_head = kv == h and h > 1
+    static = _splash_kernel(n_pad, h if multi_head else h // kv, band,
+                            bool(interpret), multi_head)
+    needed, _ = _needed(gid, band)
+    kernel = sk.SplashAttentionKernel(
+        _follow(static.fwd_mask_info, needed, False),
+        _follow(static.dq_mask_info, needed, False),
+        _follow(static.dkv_mask_info, needed, True), **static.kwargs)
+    if multi_head:
+        out = kernel(q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1),
+                     seg)
         return out.swapaxes(0, 1)[:n].astype(q.dtype)
-    kernel = _splash_kernel(n_pad, h // kv, band, bool(interpret))
     out = [kernel(q[:, a * (h // kv):(a + 1) * (h // kv)].swapaxes(0, 1),
                   k[:, a], v[:, a], seg) for a in range(kv)]
     return jnp.concatenate(out, axis=0).swapaxes(0, 1)[:n].astype(q.dtype)
 
 
-def graph_attention(q, k, v, node_gid, *, window=None, max_span=None,
-                    backend=None, interpret=False):
+def graph_attention(q, k, v, node_gid, node_mask=None, *, window=None,
+                    max_span=None, backend=None, interpret=False):
     """Causal attention inside each graph over the packed node axis.
 
-    ``window``: nodes a node sees back, itself included (None = the whole
-    graph so far); ``max_span``: an upper bound of a graph's node count,
-    which bands a full layer's kernel (None = the node axis)."""
+    ``node_mask``: the batch's, 0 on padding nodes (None = every node is
+    real); ``window``: nodes a node sees back, itself included (None = the
+    whole graph so far); ``max_span``: an upper bound of a graph's node
+    count, which bands a full layer's kernel (None = the node axis)."""
     backend = backend or default_backend()
+    n = q.shape[0]
     with phase("attn.core"):
         if backend == "dense":
-            return _dense(q, k, v, node_gid, window)
+            return _dense(q, k, v, _own_ids(node_gid, node_mask, n), window)
         if backend == "splash":
-            return _splash(q, k, v, node_gid, window, max_span, interpret)
+            n_pad, band = _padded(n, window, max_span)
+            return _splash(q, k, v, _own_ids(node_gid, node_mask, n_pad),
+                           band, interpret)
     raise ValueError(f"unknown attention backend {backend!r}")

@@ -594,6 +594,14 @@ class MetricsLogger:
                 for k in ("load_all_max_over_mean", "bias_abs_max"):
                     if f"moe_{k}" in m:
                         rec["moe"][k] = float(m[f"moe_{k}"])
+            if "attn_blocks_band" in m:
+                # the attention kernels' block schedule, summed over the
+                # layers' forward calls and the steps of the dispatch
+                # (ops/attention.py scheduled_blocks)
+                rec["attention"] = {
+                    "blocks_run": float(m["attn_blocks_run"]),
+                    "blocks_band": float(m["attn_blocks_band"]),
+                }
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

@@ -236,10 +236,12 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
 
 def model_counters(batch_stats) -> Dict[str, jax.Array]:
     """What the model counted in this step for the step records: the
-    top-level ``moe_*`` scalars a stack keeps in ``batch_stats``
-    (models/laguna.py count_routing); {} for every other stack."""
+    top-level ``moe_*`` and ``attn_*`` scalars a stack keeps in
+    ``batch_stats`` (models/laguna.py count_routing, count_blocks); {} for
+    every other stack."""
     return {k: v for k, v in batch_stats.items()
-            if k.startswith("moe_") and getattr(v, "ndim", None) == 0}
+            if k.startswith(("moe_", "attn_"))
+            and getattr(v, "ndim", None) == 0}
 
 
 def make_train_step(
@@ -324,7 +326,8 @@ def make_train_step(
 # scanned steps); every other scalar merges as a graph-weighted mean
 # ("skipped" counts guard-suppressed steps within the dispatch)
 _COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
-                      "moe_slots_held", "moe_slots_all", "moe_dense_steps")
+                      "moe_slots_held", "moe_slots_all", "moe_dense_steps",
+                      "attn_blocks_run", "attn_blocks_band")
 
 
 def merge_scanned_metrics(ms):

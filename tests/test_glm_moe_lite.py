@@ -236,29 +236,30 @@ def test_attention_kernels_match_the_dense_twin_per_head_layout(heads, kv, d):
 
 
 def test_one_multi_head_kernel_call_where_every_head_has_its_own_kv():
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
     n, gid = 40, jnp.zeros((40,), jnp.int32)
     q = jnp.ones((n, 20, 8))
     calls = {}
-    real = attention._splash_kernel
+    real = sk.SplashAttentionKernel.__call__
 
-    def counting(*args):
-        kernel = real(*args)
+    def counting(self, q, *a):
+        key = (q.shape[0], self.kwargs["is_mqa"])
+        calls[key] = calls.get(key, 0) + 1
+        return real(self, q, *a)
 
-        def call(*a):
-            calls[args] = calls.get(args, 0) + 1
-            return kernel(*a)
-        return call
-
-    attention._splash_kernel = counting
+    sk.SplashAttentionKernel.__call__ = counting
     try:
         attention.graph_attention(q, q, q, gid, backend="splash",
                                   interpret=True)
         attention.graph_attention(q, q[:, :1], q[:, :1], gid,
                                   backend="splash", interpret=True)
     finally:
-        attention._splash_kernel = real
-    # (node axis, heads, band, interpret[, multi_head]) -> calls
-    assert calls == {(512, 20, 512, True, True): 1, (512, 20, 512, True): 1}
+        sk.SplashAttentionKernel.__call__ = real
+    # (query heads of the call, multi-query) -> calls
+    assert calls == {(20, False): 1, (20, True): 1}
 
 
 def test_route_selects_under_the_bias_and_weighs_without_it():

@@ -173,9 +173,13 @@ def test_merge_scanned_metrics_counts_vs_means():
         "edges_real": jnp.asarray([4.0, 8.0]),
         "grad_norm": jnp.asarray([1.0, 2.0]),
         "task_0": jnp.asarray([1.0, 3.0]),
+        "attn_blocks_run": jnp.asarray([109.0, 98.0]),
+        "attn_blocks_band": jnp.asarray([324.0, 324.0]),
     }
     merged = merge_scanned_metrics(ms)
     # counts SUM across the scanned steps
+    assert float(merged["attn_blocks_run"]) == 207.0
+    assert float(merged["attn_blocks_band"]) == 648.0
     assert float(merged["num_graphs"]) == 8.0
     assert float(merged["nodes_real"]) == 30.0
     assert float(merged["edges_real"]) == 12.0
@@ -331,6 +335,54 @@ def test_training_smoke_emits_full_jsonl(tmp_path, capsys):
     rendered = capsys.readouterr().out
     assert "mfu%" in rendered and "epochs:" in rendered
     assert "aggr dispatch:" in rendered
+
+
+@pytest.mark.parametrize("stack", ["laguna", "glm_moe_lite", "sage"])
+def test_step_records_carry_the_attention_schedule_of_a_language_model(
+        stack, tmp_path):
+    """A language-model stack's step record has an ``attention`` block
+    (ops/attention.py scheduled_blocks, summed over the attending layers'
+    forward calls); a message-passing stack's has none."""
+    if stack == "sage":
+        cfg, (batch, _pad, _s), layers = _cfg(), _batch(), 0
+    else:
+        import test_glm_moe_lite
+        import test_laguna
+
+        T = test_laguna if stack == "laguna" else test_glm_moe_lite
+        cfg = ModelConfig.from_config(T.nn_section())
+        rng = np.random.default_rng(0)
+        docs = [T.sample(rng.integers(0, 64, size=n)) for n in (5, 20, 3, 12)]
+        heads = [HeadSpec(f"next{i}", "node", 1)
+                 for i in range(len(cfg.output_dim))]
+        batch = collate(docs, PadSpec(48, 8, 5), heads)
+        # three layers, and the multi-token-prediction module's own
+        layers = 3 + (stack == "glm_moe_lite")
+    model = create_model(cfg)
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
+    state = create_train_state(model, batch, opt)
+    step = jax.jit(make_train_step(model, cfg, opt, telemetry_metrics=True))
+    out_dir = str(tmp_path / "telemetry")
+    tele = MetricsLogger(TelemetryConfig(enable=True, sinks=("jsonl",)),
+                         run_name=f"attn_{stack}", out_dir=out_dir)
+    tele.begin_epoch(0)
+    for _ in range(2):
+        state, metrics = step(state, batch)
+        tele.on_step(metrics, batch)
+    tele.flush_steps()
+    tele.finalize()
+    steps = [r for r in map(json.loads, open(
+        os.path.join(out_dir, "events.jsonl"))) if r["event"] == "step"]
+    assert len(steps) == 2
+    for r in steps:
+        if not layers:
+            assert "attention" not in r and "moe" not in r
+            continue
+        # 48 nodes are one block of the kernels' 512: every layer's band
+        # is its diagonal block, and that always runs
+        assert r["attention"] == {"blocks_run": float(layers),
+                                  "blocks_band": float(layers)}
+        assert "moe" in r
 
 
 def test_disabled_logger_writes_nothing(tmp_path):

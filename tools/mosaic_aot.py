@@ -19,11 +19,16 @@ are right, and every time or rate, still needs the chip
     python tools/mosaic_aot.py moe_rows:20000:3072:bfloat16  # the routed
         # experts' value-and-grad (N nodes of width D, products in DTYPE)
         # with the two row-walk kernels and the megablox products
+    python tools/mosaic_aot.py attention:20136:20x20x256:4096  # attention's
+        # value-and-grad over N nodes, HEADSxKVxD, full layers banded to
+        # SPAN (a fifth field: the window of a sliding layer), the splash
+        # kernels under block tables made from traced node ids
 
 The default list is every arch at h128 f32 plus the widths SchNet's
 in-kernel filter network chooses its edge blocks for (128 at ``highest``;
 256 / 512 / 1024 in f32 and bf16; 1024 at ``highest``), the sharded op,
-and the routed experts at the language-model cell's shapes in both dtypes.
+the routed experts at the language-model cell's shapes in both dtypes, and
+attention at the two language-model cells' shapes.
 
 Exit code 0 only if every target compiled.
 """
@@ -160,6 +165,34 @@ def compile_moe_rows(nodes: int, width: int, dtype: str, sharding):
     return calls, time.perf_counter() - t0
 
 
+def compile_attention(nodes: int, shape: str, span: int, window, sharding):
+    """Lower + compile the value-and-grad of ``ops/attention.py:
+    graph_attention`` on the splash backend in bfloat16, ids and mask
+    traced: the three kernels take their block tables from the batch."""
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.ops import attention
+
+    heads, kv, d = map(int, shape.split("x"))
+
+    def loss(q, k, v, gid, mask):
+        o = attention.graph_attention(q, k, v, gid, mask, window=window,
+                                      max_span=span, backend="splash")
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        arg((nodes, heads, d)), arg((nodes, kv, d)), arg((nodes, kv, d)),
+        arg((nodes,), jnp.int32), arg((nodes,), jnp.float32))
+    calls = lowered.as_text().count("tpu_custom_call")
+    t0 = time.perf_counter()
+    lowered.compile()
+    return calls, time.perf_counter() - t0
+
+
 def main(argv) -> int:
     import jax
     from jax.experimental import topologies
@@ -185,7 +218,9 @@ def main(argv) -> int:
         # kernels under it are the ones nearest the VMEM limit
         + ["SchNet:1024:float32:highest", "SchNet:1024:bfloat16:highest",
            "cfconv:128:float32:shard_map",
-           "moe_rows:20000:3072:bfloat16", "moe_rows:20000:3072:float32"])
+           "moe_rows:20000:3072:bfloat16", "moe_rows:20000:3072:float32",
+           "attention:20136:20x20x256:4096", "attention:20000:6x1x128:5580",
+           "attention:20000:9x1x128:5580:512"])
     failed = 0
     for t in targets:
         arch, hidden, dtype, *rest = t.split(":")
@@ -193,6 +228,11 @@ def main(argv) -> int:
             if rest == ["shard_map"]:
                 calls, secs = compile_cfconv_sharded(
                     int(hidden), dtype, topo.devices)
+            elif arch == "attention":
+                calls, secs = compile_attention(
+                    int(hidden), dtype, int(rest[0]),
+                    int(rest[1]) if rest[1:] else None,
+                    SingleDeviceSharding(dev))
             elif arch == "moe_rows":
                 calls, secs = compile_moe_rows(
                     int(hidden), int(dtype), rest[0],
