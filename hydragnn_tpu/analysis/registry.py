@@ -194,9 +194,6 @@ _KNOB_LIST = [
     _k("HYDRAGNN_TELEMETRY_HEARTBEAT", "Telemetry.heartbeat", "50",
        "hydragnn_tpu/telemetry/logger.py",
        "stdout heartbeat cadence (steps)"),
-    _k("HYDRAGNN_TELEMETRY_SYNC", "Telemetry.sync_steps", "0",
-       "hydragnn_tpu/telemetry/logger.py",
-       "block per step for true device step times"),
     _k("HYDRAGNN_TRACE", "Telemetry.trace", "0",
        "hydragnn_tpu/telemetry/trace.py",
        "flight recorder: record request/train-phase spans (JSONL "
@@ -643,6 +640,13 @@ _SPAN_LIST = [
        "create_train_state: model.init and optimizer init"),
     _s("setup.mfu_cost", "hydragnn_tpu/telemetry/logger.py",
        "the cost-analysis compile of the step behind mfu_est_pct"),
+    _s("telemetry.step_programs", "hydragnn_tpu/telemetry/hlo_scopes.py",
+       "StepPrograms.write in epoch 0's tail, while regions are annotated: "
+       "each step program compiled again from its shapes (a cache read), "
+       "its HLO text parsed into hlo_scopes.json"),
+    _s("telemetry.program_memory", "hydragnn_tpu/telemetry/hlo_scopes.py",
+       "inside telemetry.step_programs: one executable's "
+       "memory_analysis() read into its program_memory record"),
 ]
 
 SPAN_NAMES: Dict[str, SpanName] = {s.name: s for s in _SPAN_LIST}

@@ -55,7 +55,6 @@ def _telemetry_defaults() -> Dict[str, Any]:
         "dir": d.dir or "",
         "heartbeat": d.heartbeat,
         "ring": d.ring,
-        "sync_steps": int(d.sync_steps),
         "mfu": int(d.mfu),
         "trace": int(d.trace),
         "trace_ring": d.trace_ring,

@@ -9,6 +9,9 @@ Entry points:
   - :mod:`~hydragnn_tpu.telemetry.pipeline` — input-pipeline counters
     (queue depth, H2D transfer bytes, collate volume)
   - sinks (sinks.py): JSONL event log, CSV, stdout heartbeat, TensorBoard
+  - :mod:`~hydragnn_tpu.telemetry.programs` — one ``program`` record per
+    program JAX builds; its listeners are installed by this import, so
+    that what is built before any logger exists is kept for the first
 
 See docs/TELEMETRY.md for the record schema and knobs, and
 tools/teleview.py for the JSONL summarizer.

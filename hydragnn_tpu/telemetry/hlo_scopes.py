@@ -25,6 +25,12 @@ innermost frame of the instruction's ``stack_frame_id`` in the text's own
 frame tables: the line of this package that bound the primitive.
 Instructions inside fused computations never run on their own and are left
 out; a fusion without ``op_name`` takes its root's.
+
+The same executables state what they need on a device
+(``memory_analysis()``: arguments, outputs, what aliases, temporaries,
+code), which the allocator's ``memory_stats()`` does not show of a
+program's temporaries: :meth:`StepPrograms.write` hands those bytes on,
+one ``program_memory`` record a program.
 """
 
 from __future__ import annotations
@@ -32,7 +38,9 @@ from __future__ import annotations
 import json
 import os
 import re
-from typing import Any, Callable, Dict, List, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from hydragnn_tpu.utils import tracer
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(ROOT )?%([\w.\-]+) = (.*)$")
@@ -174,6 +182,25 @@ def instruction_scopes(hlo_text: str) -> Dict[str, List[Any]]:
     return out
 
 
+_MEMORY_FIELDS = (
+    ("argument_bytes", "argument_size_in_bytes"),
+    ("output_bytes", "output_size_in_bytes"),
+    ("alias_bytes", "alias_size_in_bytes"),
+    ("temp_bytes", "temp_size_in_bytes"),
+    ("generated_code_bytes", "generated_code_size_in_bytes"),
+    ("peak_bytes", "peak_memory_in_bytes"))
+
+
+def _tell_memory(compiled, name: str, on_memory) -> None:
+    try:
+        stats = compiled.memory_analysis()
+        fields = {ours: int(getattr(stats, theirs))
+                  for ours, theirs in _MEMORY_FIELDS}
+    except Exception:  # graftlint: disable=ROB001 (a backend without the analysis leaves no record, the run is unaffected)
+        return
+    on_memory(name, **fields)
+
+
 class StepPrograms:
     """Notes each step program as the trainer dispatches it — the jitted
     function and the shapes (and shardings) it was called with, one note
@@ -210,24 +237,31 @@ class StepPrograms:
 
         return watched
 
-    def write(self, path: str) -> None:
+    def write(self, path: str,
+              on_memory: Optional[Callable[..., None]] = None) -> None:
         """Compile each noted program again from its shapes (a read of the
         persistent compile cache where that is on), parse its HLO text,
-        write ``path``.  Best effort: a backend that cannot give the text
-        leaves no file, and training goes on."""
+        write ``path``; ``on_memory(name, argument_bytes=...)`` gets each
+        executable's ``memory_analysis()``, per device.  Best effort: a
+        backend that cannot give the text leaves no file, and training
+        goes on."""
         self._done = True
         programs = []
-        for fn, avals in self._seen.values():
-            try:
-                compiled = fn.lower(*avals).compile()
-                text = compiled.as_text()
-            except Exception:  # graftlint: disable=ROB001 (a trace aid: without the text there is no file, the run is unaffected)
-                continue
-            name = re.search(r"^HloModule ([\w.\-]+)", text, re.M)
-            programs.append({
-                "name": name.group(1) if name else getattr(
-                    fn, "__name__", "step"),
-                "instructions": instruction_scopes(text)})
+        with tracer.timer("telemetry.step_programs"):
+            for fn, avals in self._seen.values():
+                try:
+                    compiled = fn.lower(*avals).compile()
+                    text = compiled.as_text()
+                except Exception:  # graftlint: disable=ROB001 (a trace aid: without the text there is no file, the run is unaffected)
+                    continue
+                name = re.search(r"^HloModule ([\w.\-]+)", text, re.M)
+                name = name.group(1) if name else getattr(
+                    fn, "__name__", "step")
+                programs.append({"name": name,
+                                 "instructions": instruction_scopes(text)})
+                if on_memory is not None:
+                    with tracer.timer("telemetry.program_memory"):
+                        _tell_memory(compiled, name, on_memory)
         self._seen.clear()
         if programs:
             os.makedirs(os.path.dirname(path), exist_ok=True)

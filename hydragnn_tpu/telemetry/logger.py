@@ -11,8 +11,8 @@ Design constraints (why this is not a naive per-step print):
   then.  Consequence: per-step ``step_time_s`` is dispatch-to-dispatch host
   wall time (under async dispatch that is queue-feed time, not device
   execution time; the epoch record's ``epoch_time_s`` is the authoritative
-  wall clock).  ``sync_steps=1`` opts into a per-step block for true device
-  step times, at the known throughput cost.
+  wall clock).  What the device spends inside a step is a profiler
+  trace's to say (docs/TELEMETRY.md "Tracing").
 
 - Rank-0-gated sinks, all-rank collectives.  Every rank runs the logger
   (cross-rank reductions via ``parallel/comm.py`` host collectives must be
@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from hydragnn_tpu.telemetry import pipeline
+from hydragnn_tpu.telemetry import pipeline, programs
 from hydragnn_tpu.telemetry.flops import (
     mfu_pct,
     peak_flops,
@@ -56,7 +56,6 @@ class TelemetryConfig:
     Knobs: HYDRAGNN_TELEMETRY (enable), HYDRAGNN_TELEMETRY_SINKS
     (comma list: jsonl,csv,stdout), HYDRAGNN_TELEMETRY_DIR,
     HYDRAGNN_TELEMETRY_HEARTBEAT (stdout cadence, steps),
-    HYDRAGNN_TELEMETRY_SYNC (block per step for true step times),
     HYDRAGNN_TRACE (span flight recorder, docs/TELEMETRY.md "Tracing"),
     HYDRAGNN_TRACE_RING (span ring/reservoir capacity).
     """
@@ -66,7 +65,6 @@ class TelemetryConfig:
     dir: Optional[str] = None
     heartbeat: int = 50
     ring: int = 256
-    sync_steps: bool = False
     mfu: bool = True
     trace: bool = False
     trace_ring: int = 512
@@ -84,7 +82,6 @@ class TelemetryConfig:
             dir=s.get("dir"),
             heartbeat=int(s.get("heartbeat", d.heartbeat)),
             ring=int(s.get("ring", d.ring)),
-            sync_steps=bool(int(s.get("sync_steps", d.sync_steps))),
             mfu=bool(int(s.get("mfu", d.mfu))),
             trace=bool(int(s.get("trace", d.trace))),
             trace_ring=int(s.get("trace_ring", d.trace_ring)),
@@ -100,8 +97,6 @@ class TelemetryConfig:
         cfg.dir = env_str("HYDRAGNN_TELEMETRY_DIR", cfg.dir or "") or cfg.dir
         if "HYDRAGNN_TELEMETRY_HEARTBEAT" in os.environ:
             cfg.heartbeat = env_int("HYDRAGNN_TELEMETRY_HEARTBEAT", 50)
-        if "HYDRAGNN_TELEMETRY_SYNC" in os.environ:
-            cfg.sync_steps = env_flag("HYDRAGNN_TELEMETRY_SYNC")
         if "HYDRAGNN_TRACE" in os.environ:
             cfg.trace = env_flag("HYDRAGNN_TRACE")
         if "HYDRAGNN_TRACE_RING" in os.environ:
@@ -209,8 +204,10 @@ class MetricsLogger:
         self._pending: List[Tuple[Any, Dict[str, int], float, tuple]] = []
         self._pending_avals: Dict[tuple, Any] = {}
         self._epoch = 0
+        self._in_epochs = False     # the trainer has begun an epoch
         self._epoch_t0 = time.perf_counter()
         self._global_step = 0
+        self._steps_dispatched = 0  # on_step's count: ahead of the flush
         self._dispatch = 0
         self._steps_per_item = 1
         self._step_fn = None
@@ -251,7 +248,7 @@ class MetricsLogger:
             from hydragnn_tpu.telemetry.trace import SpanRecorder
 
             self.spans = SpanRecorder(ring=self.cfg.trace_ring,
-                                      emit=self._emit_span)
+                                      emit=self.emit_threadsafe)
         if self.enabled and self.rank == 0:
             self.sinks = build_sinks(
                 self.cfg.sinks, self.out_dir, self.run_id,
@@ -278,9 +275,12 @@ class MetricsLogger:
                 **self._device,
                 "peak_flops_basis": self._peak,
                 "sinks": list(self.cfg.sinks),
-                "sync_steps": self.cfg.sync_steps,
                 "aggr_backend": aggr_backend(),
             })
+        if self.enabled and self.sinks:
+            # the programs built so far (create_train_state, resident
+            # staging: before this logger) and every later one
+            programs.RECORDER.attach(self)
 
     # -- construction helpers ------------------------------------------------
 
@@ -467,30 +467,43 @@ class MetricsLogger:
                 **self._comms,
             })
 
+    def log_program_memory(self, name: str, **fields: int) -> None:
+        """One step program's ``memory_analysis()``, per device, as the
+        compiler states it (telemetry/hlo_scopes.py StepPrograms.write):
+        a ``program_memory`` event."""
+        if self.enabled:
+            self.emit_threadsafe({"event": "program_memory", "name": name,
+                                  **fields})
+
     def resume_counts(self, global_step: int) -> None:
         """Continue the step/dispatch numbering of a preempted run so the
         resumed JSONL stream's ``step`` axis doesn't restart at zero."""
         # trainer main thread only (resume happens before any serving
         # thread exists); the step counters are never shared cross-thread
         self._global_step = max(0, int(global_step))  # graftlint: disable=LCK001 (trainer main thread only)
+        self._steps_dispatched = self._global_step  # graftlint: disable=LCK001 (trainer main thread only)
         self._dispatch = self._global_step // max(1, self._steps_per_item)  # graftlint: disable=LCK001 (trainer main thread only)
 
     # -- per-step path (zero-sync) -------------------------------------------
 
     def begin_epoch(self, epoch: int) -> None:
         self._epoch = int(epoch)
+        self._in_epochs = True
         self._epoch_t0 = time.perf_counter()
+
+    def position(self) -> Tuple[Optional[int], Optional[int]]:
+        """(epoch, optimizer steps dispatched so far) for a record made
+        beside the step path (telemetry/programs.py), from any thread;
+        (None, None) until the trainer begins its first epoch."""
+        if not self._in_epochs:
+            return None, None
+        return self._epoch, self._steps_dispatched
 
     def on_step(self, metrics, batch) -> None:
         """Record one dispatched train step: device metric scalars + host
-        timestamp + static batch metadata.  No device sync unless
-        ``sync_steps`` is set."""
+        timestamp + static batch metadata.  No device sync."""
         if not self.enabled:
             return
-        if self.cfg.sync_steps:
-            import jax
-
-            jax.block_until_ready(metrics["loss"])
         sig = (tuple(batch.x.shape), tuple(batch.senders.shape),
                tuple(batch.graph_mask.shape))
         if (self._step_fn is not None and sig not in self._flops_cache
@@ -499,6 +512,7 @@ class MetricsLogger:
             # so flush can compile the cost analysis off the hot path
             self._flops_cache[sig] = None
             self._pending_avals[sig] = shape_struct_tree(batch)
+        self._steps_dispatched += self._steps_per_item  # graftlint: disable=LCK001 (trainer main thread only)
         self._pending.append(
             (metrics, batch_pad_meta(batch), time.perf_counter(), sig))
 
@@ -718,6 +732,7 @@ class MetricsLogger:
                 rec["pipeline"] = pipe
             self._emit(rec)
             pipeline.set_enabled(False)
+        programs.RECORDER.detach(self)
         for s in self.sinks:
             try:
                 s.close()
@@ -731,10 +746,11 @@ class MetricsLogger:
         for s in self.sinks:
             s.emit(record)
 
-    def _emit_span(self, record: Dict[str, Any]) -> None:
-        """SpanRecorder's emit hook: stamp run identity and ride the
-        health lock — span records come from concurrent serve handler
-        threads and share the JSONL sink's text stream."""
+    def emit_threadsafe(self, record: Dict[str, Any]) -> None:
+        """The emit hook of what records from any thread (SpanRecorder:
+        concurrent serve handler threads; ProgramRecorder: the prefetch
+        thread builds too): stamp run identity and ride the health lock,
+        because they share the JSONL sink's text stream."""
         record.setdefault("run_id", self.run_id)
         record.setdefault("rank", self.rank)
         record.setdefault("t", time.time())

@@ -1602,6 +1602,7 @@ def train_validate_test(
         eval_step = step_notes.watch(eval_step)
     # epoch.tail: from the end of metrics_fetch to the next epoch's train
     in_tail = False
+    regions_before = len(tr.open_regions())
     try:
         for epoch in range(start_epoch, num_epoch):
             t0 = time.time()
@@ -1692,7 +1693,8 @@ def train_validate_test(
             in_tail = True
             if step_notes is not None:
                 step_notes.write(
-                    os.path.join(telemetry.out_dir, "hlo_scopes.json"))
+                    os.path.join(telemetry.out_dir, "hlo_scopes.json"),
+                    telemetry.log_program_memory)
                 step_notes = None
             train_loss, train_tasks = _epoch_metrics(train_acc)
             if valtest:
@@ -1839,8 +1841,9 @@ def train_validate_test(
         close_manager(os.path.join(
             _resume.resume_dir(logs_dir, log_name), _resume.STATE_DIRNAME))
         profiler.disable()
-        if in_tail:
-            tr.stop("epoch.tail")
+        # epoch.tail, and whatever an exception left open (train,
+        # train.dispatch ...): a later build must not name them
+        tr.close_to(regions_before)
         tr.unregister("spans")
         timer = tr.get("timer")
         telemetry.finalize(

@@ -89,10 +89,3 @@ class Profiler:
             jax.profiler.stop_trace()
             self._tracing = False
         self.enabled = False
-
-
-def annotate(name: str):
-    """Context manager adding a named region to device traces."""
-    import jax.profiler
-
-    return jax.profiler.TraceAnnotation(name)

