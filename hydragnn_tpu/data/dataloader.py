@@ -36,6 +36,27 @@ class GraphDataLoader:
     bounded compile count.  ``bucket_group`` > 1 forces that many consecutive
     batches to share one bucket (required when batches are later stacked
     across local devices by DeviceStackLoader).
+
+    Who picks a group's shape, and when.  By default the loader does, anew
+    for every epoch's plan, by looking the group's largest batch up in the
+    ladder it was built with (:meth:`_pick_spec`): a reshuffling run cannot
+    know its shapes in advance, so it guesses them from quantiles.  A train
+    loader that is about to be staged on the device is told so by the
+    trainer (:meth:`fit_to_groups`, from ``_align_bucket_group``): what
+    ``ResidentDeviceLoader`` stages is the plan of ONE epoch, replayed for
+    the rest of the run, so every shape the run will see is known from
+    sizes alone when that plan is made, and each dispatch group is padded
+    to what the groups themselves hold (:func:`fit_group_specs`): at most
+    as many shapes as the ladder has rungs.  The fitted shapes join
+    ``pad_specs`` (every spec a batch is padded to is a member; the
+    ladder's rungs stay, worst case last), ``group_shapes`` says which
+    groups took which.  Eval loaders are never told: an eval batch is a
+    group of one, which is what the ladder's quantiles were fitted to.  They
+    are handed the fitted shapes as further rungs (:meth:`add_specs`), so
+    the three loaders go on sharing one PadSpec set: an eval batch that
+    fits a train shape then runs at it, and what is built once for a padded
+    length (attention's block tables, seconds of host time each) is not
+    built again for a rung that only an eval batch would use.
     """
 
     def __init__(
@@ -76,6 +97,11 @@ class GraphDataLoader:
             self.pad_specs = [pad_spec]
         self.pad_spec = pad_spec
         self.bucket_group = max(1, int(bucket_group))
+        # the rungs this loader was built with; pad_specs gains the fitted
+        # shapes beside them once fit_to_groups() has been called
+        self._ladder = list(self.pad_specs)
+        self.fit_groups = False
+        self.group_shapes: List[List[int]] = []  # [nodes, edges, groups]
         # padding-waste accounting (real vs padded node slots), reset per epoch
         self.real_nodes = 0
         self.padded_nodes = 0
@@ -87,6 +113,22 @@ class GraphDataLoader:
     def padding_efficiency(self) -> float:
         """real node slots / padded node slots over batches yielded so far."""
         return self.real_nodes / max(self.padded_nodes, 1)
+
+    def fit_to_groups(self) -> bool:
+        """The trainer's word that this loader's next plan is the one that
+        gets staged on the device and replayed: from now on a dispatch
+        group's shape is fitted to the groups of the plan, not looked up in
+        the ladder.  A loader with a single spec (``HYDRAGNN_NUM_BUCKETS=1``,
+        a corpus of one batch, multi-process) keeps it.  Returns whether
+        fitting is on."""
+        self.fit_groups = len(self._ladder) > 1
+        return self.fit_groups
+
+    def add_specs(self, specs: Sequence[PadSpec]) -> None:
+        """Further rungs for :meth:`_pick_spec` to look a group up in (a
+        sibling loader's fitted shapes)."""
+        self.pad_specs = sorted(set(self.pad_specs) | set(specs),
+                                key=_spec_order)
 
     def _pick_spec(self, batches: Sequence[Sequence[GraphSample]]) -> PadSpec:
         """Smallest bucket that fits every batch in the group."""
@@ -126,10 +168,14 @@ class GraphDataLoader:
         self.real_nodes = 0
         self.padded_nodes = 0
         plan: List[Tuple[np.ndarray, PadSpec]] = []
-        for g0 in range(0, nb, self.bucket_group):
-            idxs = [order[b * self.batch_size:(b + 1) * self.batch_size]
-                    for b in range(g0, min(g0 + self.bucket_group, nb))]
-            if len(self.pad_specs) == 1:
+        groups = [[order[b * self.batch_size:(b + 1) * self.batch_size]
+                   for b in range(g0, min(g0 + self.bucket_group, nb))]
+                  for g0 in range(0, nb, self.bucket_group)]
+        fitted = self._fitted_specs(groups) if self.fit_groups else []
+        for g, idxs in enumerate(groups):
+            if g < len(fitted):
+                spec = fitted[g]
+            elif len(self.pad_specs) == 1:
                 spec = self.pad_spec
             else:
                 spec = self._pick_spec(
@@ -140,6 +186,25 @@ class GraphDataLoader:
                 self.padded_nodes += spec.num_nodes
                 plan.append((np.asarray(ix), spec))
         return plan
+
+    def _fitted_specs(self, groups: List[List[np.ndarray]]) -> List[PadSpec]:
+        """The fitted spec of every WHOLE group of the plan, in order.  A
+        trailing partial group is left to :meth:`_pick_spec`:
+        ``DeviceStackLoader(drop_last=True)`` never dispatches it, so it
+        takes a shape that is there and costs none."""
+        whole = [g for g in groups if len(g) == self.bucket_group]
+        needs = [
+            (max(sum(self.samples[i].num_nodes for i in ix) for ix in g),
+             max(sum(self.samples[i].num_edges for i in ix) for ix in g))
+            for g in whole]
+        fitted = fit_group_specs(
+            needs, self.pad_spec.num_graphs, len(self._ladder))
+        shapes = sorted(set(fitted), key=_spec_order)
+        self.group_shapes = [[p.num_nodes, p.num_edges, fitted.count(p)]
+                             for p in shapes]
+        self.pad_specs = self._ladder
+        self.add_specs(shapes)
+        return fitted
 
     def _batch_plan(self) -> List[Tuple[List[GraphSample], PadSpec]]:
         """The epoch's (samples, pad_spec) per batch — the thread-pool
@@ -182,6 +247,71 @@ class GraphDataLoader:
     def __iter__(self) -> Iterator[GraphBatch]:
         for item in self._batch_plan():
             yield self._collate_plan_item(item)
+
+
+def _spec_order(spec: PadSpec) -> Tuple[int, int]:
+    return spec.num_nodes, spec.num_edges
+
+
+def _round_up(x: int, to: int) -> int:
+    return int(-(-x // to) * to)
+
+
+# a group takes the shape of a larger one when that costs it at most this
+# share of extra slots: a shape of its own is one more compiled program
+# (seconds of set-up, and HBM for its temporaries) against ~3 % of one
+# group's padding
+_FIT_SHARE = 0.03
+
+
+def fit_group_specs(
+    needs: Sequence[Tuple[int, int]],
+    num_graphs: int,
+    max_shapes: int,
+    round_to: int = 8,
+) -> List[PadSpec]:
+    """One PadSpec per dispatch group from the groups' own needs.
+
+    ``needs`` holds (nodes, edges) of each group's largest batch.  A group's
+    own shape is its need rounded up as PadSpecs are (one padding node
+    slot; multiples of ``round_to``).  Shapes are then merged, nearest pair
+    first, into their elementwise maximum while the pair is within
+    ``_FIT_SHARE`` of each other or more than ``max_shapes`` are left, so
+    the count of compiled train programs is bounded by the knob that bounds
+    it for the ladder (``n_buckets``) and is usually one.  The distance of a
+    pair is the largest relative growth, in nodes or in edges, that the
+    merged shape asks of the smallest need it would hold.
+    """
+    own = [(_round_up(int(n) + 1, round_to),
+            _round_up(max(int(e), 1), round_to)) for n, e in needs]
+    # clusters as [lo, hi]: elementwise min and max of the shapes they hold;
+    # few (a resident corpus fits the device), so every pair is looked at
+    clusters = [[s, s] for s in sorted(set(own))]
+    max_shapes = max(1, int(max_shapes))
+    while len(clusters) > 1:
+        best = None
+        for i, (lo_i, hi_i) in enumerate(clusters):
+            for j in range(i + 1, len(clusters)):
+                lo_j, hi_j = clusters[j]
+                lo = (min(lo_i[0], lo_j[0]), min(lo_i[1], lo_j[1]))
+                hi = (max(hi_i[0], hi_j[0]), max(hi_i[1], hi_j[1]))
+                growth = max(hi[0] / lo[0], hi[1] / lo[1]) - 1.0
+                if best is None or growth < best[0]:
+                    best = (growth, i, j, lo, hi)
+        growth, i, j, lo, hi = best
+        if growth > _FIT_SHARE and len(clusters) <= max_shapes:
+            break
+        clusters[i] = [lo, hi]
+        del clusters[j]
+    specs = []
+    for shape in own:
+        # the smallest cluster that holds it (clusters may overlap)
+        hi = min((hi for lo, hi in clusters
+                  if hi[0] >= shape[0] and hi[1] >= shape[1]),
+                 key=lambda h: (h[0] + h[1], h))
+        specs.append(PadSpec(num_nodes=hi[0], num_edges=hi[1],
+                             num_graphs=num_graphs))
+    return specs
 
 
 def pad_spec_from_sizes(
@@ -233,16 +363,12 @@ def bucket_pad_specs_from_sizes(
         sums_n[i] = nodes[idx].sum()
         sums_e[i] = edges[idx].sum()
     specs: List[PadSpec] = []
-
-    def _round(x: int) -> int:
-        return int(-(-x // round_to) * round_to)
-
     # lower buckets at quantiles of the simulated batch sums; e.g. 3 buckets
     # -> q50, q99, worst-case
     qs = list(np.linspace(50.0, 99.0, n_buckets - 1)) if n_buckets > 2 else [90.0]
     for q in qs:
-        qn = _round(int(np.percentile(sums_n, q)) + 1)
-        qe = _round(int(np.percentile(sums_e, q)) + 1)
+        qn = _round_up(int(np.percentile(sums_n, q)) + 1, round_to)
+        qe = _round_up(int(np.percentile(sums_e, q)) + 1, round_to)
         if qn < worst.num_nodes:
             specs.append(PadSpec(
                 num_nodes=qn,
@@ -278,6 +404,16 @@ def bucket_pad_specs(
     below batch_size*max), then place bucket capacities at evenly spaced
     quantiles with the top bucket = exact worst case, so every batch fits
     somewhere.  Compile count is bounded by ``n_buckets``.
+
+    The rungs are placed for SINGLE batches, which is what an eval loader
+    and a host-fed train loader with ``bucket_group`` 1 look up.  A dispatch
+    group of K batches takes the rung of its LARGEST batch, which lies above
+    the single-batch q99 with probability 1 - 0.99^K: large groups fall
+    through to the worst case.  Where the run can know its groups (a train
+    loader staged resident) the ladder is only the fallback and the groups
+    shape themselves (:func:`fit_group_specs`); where it cannot (a host-fed
+    run reshuffles every epoch) the ladder still misfits K-groups, which
+    waits for a cell that runs the loader in a counted epoch (ROADMAP S1c).
     """
     nodes = np.fromiter((s.num_nodes for s in samples), np.int64,
                         count=len(samples))

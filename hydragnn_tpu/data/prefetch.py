@@ -188,6 +188,16 @@ class ResidentDeviceLoader:
     (HYDRAGNN_RESIDENT_DATASET=1) only when that distinction doesn't matter
     (it rarely does for large datasets; disable for tiny CI-scale runs
     where batch diversity per epoch is load-bearing).
+
+    Because what is staged is ONE plan of the underlying loader (``set_epoch``
+    is forwarded only until staging is complete, so the base loader stays at
+    the staged epoch and plans it again when asked), every shape the run
+    will see is known from sizes alone when that plan is made.  The trainer
+    therefore tells the train loader to fit each dispatch group's PadSpec to
+    the groups of that plan (``_align_bucket_group(..., fit=True)`` ->
+    ``GraphDataLoader.fit_to_groups``) instead of looking it up in the
+    quantile ladder; this wrapper itself picks no shape and stages whatever
+    it is handed.
     """
 
     def __init__(self, loader, seed: int = 0, sharding=None):

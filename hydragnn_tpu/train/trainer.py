@@ -347,19 +347,39 @@ def merge_scanned_metrics(ms):
     return merged
 
 
-def _align_bucket_group(loader, factor: int) -> None:
+def _base_loader(loader, attr: str):
+    """The innermost loader of a stack of wrappers that has ``attr``."""
+    while loader is not None and not hasattr(loader, attr):
+        loader = getattr(loader, "loader", None)
+    return loader
+
+
+def _align_bucket_group(loader, factor: int, fit: bool = False):
     """Raise the underlying GraphDataLoader's ``bucket_group`` to a multiple
     of ``factor`` so batches later stacked together (DeviceStackLoader over
     local devices and/or scan steps) share one bucket PadSpec — np.stack
-    over mismatched bucket shapes would raise mid-epoch."""
+    over mismatched bucket shapes would raise mid-epoch.
+
+    ``fit`` says the stacked loader is about to be staged on the device
+    (``ResidentDeviceLoader``: one epoch's plan, replayed for the whole
+    run).  The loader is then told to fit each group's PadSpec to the
+    groups of that plan (``GraphDataLoader.fit_to_groups``) instead of
+    looking it up in the ladder, whose rungs were placed for single
+    batches: the largest of K batches lies above the single-batch q99 with
+    probability 1 - 0.99^K and falls through to the worst-case rung.  A
+    loader that is not staged keeps the ladder: its shapes change every
+    epoch and a fitted one would compile inside the run.  Returns the
+    loader whose groups are fitted, or None."""
     if factor <= 1:
-        return
-    obj = loader
-    while obj is not None and not hasattr(obj, "bucket_group"):
-        obj = getattr(obj, "loader", None)
-    if obj is not None:
-        bg = max(1, int(obj.bucket_group))
-        obj.bucket_group = factor * (-(-bg // factor))
+        return None
+    obj = _base_loader(loader, "bucket_group")
+    if obj is None:
+        return None
+    bg = max(1, int(obj.bucket_group))
+    obj.bucket_group = factor * (-(-bg // factor))
+    if fit and hasattr(obj, "fit_to_groups") and obj.fit_to_groups():
+        return obj
+    return None
 
 
 def _auto_pipeline(train_loader, val_loader, test_loader, stack_factor=1):
@@ -410,9 +430,7 @@ def _auto_pipeline(train_loader, val_loader, test_loader, stack_factor=1):
     # bucketed loaders: the peeked batch may come from the SMALLEST
     # bucket; scale to the worst-case spec so residency never turns on
     # from an underestimate and OOMs HBM during staging
-    base = train_loader
-    while base is not None and not hasattr(base, "pad_specs"):
-        base = getattr(base, "loader", None)
+    base = _base_loader(train_loader, "pad_specs")
     if base is not None and len(base.pad_specs) > 1:
         lo, hi = base.pad_specs[0], base.pad_specs[-1]
         batch_bytes *= max(
@@ -937,6 +955,9 @@ def train_validate_test(
     if stream_fb:
         telemetry.health("stream_fallback", reason=stream_fb)
     stream_base = find_stream_loader(train_loader)
+    # the train loader whose dispatch groups are fitted to what they hold
+    # (_align_bucket_group), where the run stages it resident
+    fit_base = None
     if stream_base is not None:
         resident_on = False
         telemetry.health(
@@ -1185,8 +1206,10 @@ def train_validate_test(
                 dtype_policy=train_dtype)
             eval_step = make_dp_eval_step(model, cfg, mesh, axis=dp_axes,
                                           zero=zero_sh)
-            _align_bucket_group(
-                train_loader, n_local_devices * steps_per_dispatch)
+            # staged resident only in one process (the wrappers below)
+            fit_base = _align_bucket_group(
+                train_loader, n_local_devices * steps_per_dispatch,
+                fit=resident_on and single_proc)
             train_loader = DeviceStackLoader(
                 train_loader, n_local_devices, drop_last=True)
             val_loader = DeviceStackLoader(
@@ -1318,7 +1341,8 @@ def train_validate_test(
                                      nonfinite_guard=res_cfg.nonfinite_guard,
                                      dtype_policy=train_dtype),
                 donate_argnums=0)
-            _align_bucket_group(train_loader, steps_per_dispatch)
+            fit_base = _align_bucket_group(
+                train_loader, steps_per_dispatch, fit=resident_on)
             train_loader = DeviceStackLoader(
                 train_loader, steps_per_dispatch, drop_last=True)
         else:
@@ -1438,7 +1462,13 @@ def train_validate_test(
                      "train_dtype": train_dtype,
                      "train_dtype_requested": train_dtype_requested,
                      "auto_selected":
-                         "HYDRAGNN_STEPS_PER_DISPATCH" not in os.environ}}
+                         "HYDRAGNN_STEPS_PER_DISPATCH" not in os.environ,
+                     # who shaped the train dispatch groups: the groups
+                     # themselves (a resident run) or the ladder; the
+                     # shapes, [nodes, edges, groups], once the first
+                     # epoch's plan is made
+                     "group_fit": fit_base is not None,
+                     "group_shapes": []}}
     lr = get_learning_rate(state.opt_state)
 
     # -- mid-run resume (resilience/resume.py + resilience/elastic.py) ------
@@ -1565,7 +1595,11 @@ def train_validate_test(
                          # resumed run reuses it verbatim (no re-probe) so
                          # the continuation traces the SAME program
                          "train_dtype": train_dtype,
-                         "n_local_devices": n_local_devices},
+                         "n_local_devices": n_local_devices,
+                         # provenance only: a resumed run plans anew
+                         "group_fit": history["pipeline"]["group_fit"],
+                         "group_shapes":
+                             history["pipeline"]["group_shapes"]},
             "world_size": world_size,
             # the launched world shape + stream-plan identity: what a
             # resume at a DIFFERENT shape validates against and converts
@@ -1637,6 +1671,17 @@ def train_validate_test(
                 skip_first=sf, consumed_base=ff_base)
             tr.stop("train")
             if epoch == start_epoch:
+                if fit_base is not None:
+                    # the plan that was staged is made: its shapes, which
+                    # the eval loaders get as further rungs before their
+                    # first batch is planned (one PadSpec set for the
+                    # three loaders, as create_dataloaders builds them)
+                    history["pipeline"]["group_shapes"] = \
+                        fit_base.group_shapes
+                    for eval_loader in (val_loader, test_loader):
+                        eval_base = _base_loader(eval_loader, "add_specs")
+                        if eval_base is not None:
+                            eval_base.add_specs(fit_base.pad_specs)
                 # model dispatch sites recorded any fell-off-the-fast-path
                 # reasons at trace time (telemetry/pipeline.py); the first
                 # epoch's dispatch is done, so surface them as health

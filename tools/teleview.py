@@ -89,6 +89,20 @@ def step_rows(steps: List[Dict[str, Any]]) -> str:
                          "graphs/s", "pad_n%", "pad_e%", "mfu%"])
 
 
+def pipeline_line(pipe: Dict[str, Any]) -> str:
+    """The manifest's ``history.pipeline`` block on one line: the fast
+    path the run took, and who shaped its train dispatch groups (the
+    groups themselves, with the shapes, or the bucket ladder)."""
+    shapes = ", ".join(f"{n}x{e} ({g} group{'s' if g != 1 else ''})"
+                       for n, e, g in pipe.get("group_shapes") or [])
+    return (f"pipeline: K={pipe.get('steps_per_dispatch')} "
+            f"resident={pipe.get('resident')} "
+            f"mesh_dp={pipe.get('use_mesh_dp')} "
+            f"dp={pipe.get('dp_extent')}  group shapes (nodes x edges): "
+            + (f"fitted {shapes or '(no plan made)'}"
+               if pipe.get("group_fit") else "from the ladder"))
+
+
 def health_section(health: List[Dict[str, Any]],
                    manifests: List[Dict[str, Any]]) -> str:
     """Resilience health events (docs/RESILIENCE.md): skipped steps,
@@ -682,6 +696,9 @@ def main(argv=None) -> int:
               f"device {m.get('device_kind', '?')} x{m.get('device_count', '?')}  "
               + (f"peak basis {peak / 1e12:.0f} TF/s" if peak
                  else "peak basis: none (device not in DEVICE_PEAKS)"))
+        pipe = (m.get("history") or {}).get("pipeline")
+        if pipe:
+            print(f"  {pipeline_line(pipe)}")
         agg = (m.get("ring_summary") or {}).get("mfu_est_pct")
         if agg:
             print(f"  mfu_est_pct (ring window): avg {agg['avg']:.3g}  "
