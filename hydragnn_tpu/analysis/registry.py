@@ -738,6 +738,24 @@ _SCOPE_LIST = [
         "lie inside it)"),
     _sc("mtp.head", "hydragnn_tpu/models/glm_moe_lite.py",
         "multi-token prediction's final norm and the main head's product"),
+    # state-space layers and the latent expert space (models/nemotron_h.py)
+    _sc("ssm.in", "hydragnn_tpu/models/nemotron_h.py",
+        "a Mamba-2 layer's norm and its one input product to [z | xBC | "
+        "dt]"),
+    _sc("ssm.conv", "hydragnn_tpu/models/nemotron_h.py",
+        "the depthwise causal convolution that stops at graph boundaries, "
+        "its silu, and dt's softplus"),
+    _sc("ssm.scan", "hydragnn_tpu/ops/ssm.py",
+        "the selective scan over each graph's nodes: the chunked form's "
+        "four products, its decays and the scan over chunk states (or the "
+        "sequential twin), and the D skip"),
+    _sc("ssm.norm", "hydragnn_tpu/models/nemotron_h.py",
+        "the gate silu(z) and the grouped RMS norm"),
+    _sc("ssm.out", "hydragnn_tpu/models/nemotron_h.py",
+        "a Mamba-2 layer's output product and residual"),
+    _sc("moe.latent", "hydragnn_tpu/models/nemotron_h.py",
+        "both projections of the latent expert space: hidden -> latent "
+        "before dispatch, latent -> hidden after the combine"),
 ]
 
 SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}

@@ -65,7 +65,7 @@ EDGE_MODELS = ["PNA", "CGCNN", "SchNet", "EGNN"]
 # language models over each graph's nodes: the edge set is implicit, so no
 # edge list is built (data/transform.py) and the longest graph bands the
 # attention kernel (finalize)
-SEQUENCE_MODELS = ("Laguna", "GlmMoeLite")
+SEQUENCE_MODELS = ("Laguna", "GlmMoeLite", "NemotronH")
 EQUIVARIANT_MODELS = ["EGNN", "SchNet"]
 ALL_MODEL_TYPES = [
     "SAGE",
@@ -79,6 +79,7 @@ ALL_MODEL_TYPES = [
     "EGNN",
     "Laguna",
     "GlmMoeLite",
+    "NemotronH",
 ]
 
 

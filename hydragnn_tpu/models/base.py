@@ -188,6 +188,14 @@ class ModelConfig:
             share = LayerShare.from_arch(
                 arch["glm_moe_lite"], arch.get("share") or {},
                 experts_key="n_routed_experts")
+        if arch["model_type"] == "NemotronH":
+            from hydragnn_tpu.models.nemotron_h import NemotronHConfig
+            from hydragnn_tpu.parallel.share import LayerShare
+
+            lm = NemotronHConfig.from_arch(arch)
+            share = LayerShare.from_arch(
+                arch["nemotron_h"], arch.get("share") or {},
+                experts_key="n_routed_experts")
         if arch["model_type"] == "CGCNN":
             # CGConv preserves feature dims (reference CGCNNStack.py:30-40)
             hidden_dim = arch["input_dim"]

@@ -305,27 +305,29 @@ class GlmMoeLiteStack(nn.Module):
             blocks.append(b)
             outputs = (logits, logits2)
         if biases:
-            self._balance(biases, stats, train)
+            balance(self, biases, stats, train)
         count_blocks(self, blocks, train)
         return outputs
 
-    def _balance(self, biases, stats, train):
-        """The bias's step after a train step, and the step's counters:
-        models/laguna.py's and, over ALL the experts, the fullest one's
-        slots over the mean (what the bias acts on) and the largest
-        ``|b|``."""
-        with phase("moe.bias"):
-            counts = [s["counts_all"] for s in stats.values()]
-            if train and not self.is_initializing():
-                for name, s in stats.items():
-                    c = s["counts_all"]
-                    biases[name].value = (
-                        biases[name].value
-                        + BIAS_UPDATE_SPEED * jnp.sign(jnp.mean(c) - c))
-            count_routing(
-                self, list(stats.values()), train,
-                load_all_max_over_mean=sum(
-                    jnp.max(c) / jnp.maximum(jnp.mean(c), 1.0)
-                    for c in counts) / len(counts),
-                bias_abs_max=jnp.max(jnp.stack(
-                    [jnp.max(jnp.abs(b.value)) for b in biases.values()])))
+
+def balance(stack: nn.Module, biases, stats, train):
+    """The bias's step after a train step, and the step's counters kept in
+    ``stack``: models/laguna.py's and, over ALL the experts, the fullest
+    one's slots over the mean (what the bias acts on) and the largest
+    ``|b|``.  ``biases`` / ``stats``: the expert layers' bias variables and
+    routing stats by layer name (also models/nemotron_h.py's)."""
+    with phase("moe.bias"):
+        counts = [s["counts_all"] for s in stats.values()]
+        if train and not stack.is_initializing():
+            for name, s in stats.items():
+                c = s["counts_all"]
+                biases[name].value = (
+                    biases[name].value
+                    + BIAS_UPDATE_SPEED * jnp.sign(jnp.mean(c) - c))
+        count_routing(
+            stack, list(stats.values()), train,
+            load_all_max_over_mean=sum(
+                jnp.max(c) / jnp.maximum(jnp.mean(c), 1.0)
+                for c in counts) / len(counts),
+            bias_abs_max=jnp.max(jnp.stack(
+                [jnp.max(jnp.abs(b.value)) for b in biases.values()])))

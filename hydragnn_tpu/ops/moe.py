@@ -7,7 +7,11 @@ selected under a correction bias that carries no gradient), keeps the
 slots that fall on its own experts, sorts them by expert and runs the
 gated feed-forward as grouped matrix products over the ragged groups; the
 weighted outputs are added up per node.  What the other ranks' experts
-would add is left out: on one chip there is no exchange.
+would add is left out: on one chip there is no exchange.  The rows that
+are dispatched need not be the rows the router reads (``rows=``: a latent
+expert space), and the expert's form is an argument (``expert=``: the
+gated three-matrix SiLU unit, or two matrices with ``relu(.)^2`` between):
+models/nemotron_h.py.
 
 No slot of a held expert is dropped.  The grouped path works on a static
 number of rows, ``capacity`` (a multiple of the kernel's row tile, by
@@ -439,8 +443,13 @@ def _silu_mul(h1, h3, dtype):
     return (jax.nn.silu(h1) * h3).astype(dtype)
 
 
+def _relu2(h1, dtype):
+    return jnp.square(jax.nn.relu(h1)).astype(dtype)
+
+
 def _grouped_path(u, weights, local, held, loads, w1, w3, w2, capacity,
                   backend, interpret):
+    """``w3`` None: the ungated two-matrix expert, ``relu(x w1)^2 w2``."""
     cfg = _Cfg(backend, interpret, u.shape[0])
     with phase("moe.rows"):
         rows = _held_rows(local, held, loads, capacity)
@@ -449,8 +458,11 @@ def _grouped_path(u, weights, local, held, loads, w1, w3, w2, capacity,
         w_r = jnp.take(weights.reshape(-1), rows.slot_r, mode="fill",
                        fill_value=0.0)
         x = _dispatch(cfg, u, rows)
-    h = _silu_mul(_grouped(x, w1, loads, backend, interpret),
-                  _grouped(x, w3, loads, backend, interpret), u.dtype)
+    if w3 is None:
+        h = _relu2(_grouped(x, w1, loads, backend, interpret), u.dtype)
+    else:
+        h = _silu_mul(_grouped(x, w1, loads, backend, interpret),
+                      _grouped(x, w3, loads, backend, interpret), u.dtype)
     out = _grouped(h, w2, loads, backend, interpret)
     with phase("moe.rows"):
         return _combine(cfg, out, w_r, rows)
@@ -463,14 +475,15 @@ def _dense_path(u, weights, local, held, loads, w1, w3, w2):
     @jax.checkpoint
     def expert(e):
         we = jnp.sum(jnp.where(held & (local == e), weights, 0.0), axis=-1)
-        h = _silu_mul(
-            jnp.dot(u, w1[e], preferred_element_type=jnp.float32),
-            jnp.dot(u, w3[e], preferred_element_type=jnp.float32), u.dtype)
+        h1 = jnp.dot(u, w1[e], preferred_element_type=jnp.float32)
+        h = (_relu2(h1, u.dtype) if w3 is None else _silu_mul(
+            h1, jnp.dot(u, w3[e], preferred_element_type=jnp.float32),
+            u.dtype))
         return we[:, None] * jnp.dot(
             h, w2[e], preferred_element_type=jnp.float32)
 
     y, _ = lax.scan(lambda y, e: (y + expert(e), None),
-                    jnp.zeros(u.shape, jnp.float32),
+                    jnp.zeros((u.shape[0], w2.shape[2]), jnp.float32),
                     jnp.arange(w1.shape[0]))
     return y
 
@@ -478,11 +491,16 @@ def _dense_path(u, weights, local, held, loads, w1, w3, w2):
 def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
                    norm_topk=True, scale=1.0, scoring="softmax", bias=None,
                    compute_dtype=jnp.float32, capacity=None, backend=None,
-                   interpret=False):
+                   interpret=False, rows=None, expert="gated_silu"):
     """The held experts' part of the routed sum for nodes ``u`` [N, D].
 
     ``w1``/``w3`` [held, D, F], ``w2`` [held, F, D], ``router_w`` [D, E];
-    ``scoring`` and ``bias`` [E] as ``route`` takes them.  Padding nodes
+    ``scoring`` and ``bias`` [E] as ``route`` takes them.  ``rows`` [N, L]:
+    what is dispatched to the experts where that is not what the router
+    reads (a latent expert space, models/nemotron_h.py: ``w1`` [held, L,
+    F], ``w2`` [held, F, L], the result [N, L]); None: ``u`` itself.
+    ``expert``: ``"gated_silu"``, ``(silu(x w1) * (x w3)) w2``, or
+    ``"relu2"``, ``relu(x w1)^2 w2`` with ``w3`` None.  Padding nodes
     (``node_mask`` 0) are routed nowhere: they all carry the same input,
     and would land on one expert together.  Returns (float32 [N, D],
     stats): ``slots_held`` routed to held experts, ``slots_all`` of the
@@ -491,6 +509,10 @@ def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
     ``counts_all`` [E]: the real nodes' slots on each of ALL the experts,
     what the bias's update reads."""
     backend = backend or default_backend()
+    if (expert == "relu2") != (w3 is None) or expert not in (
+            "gated_silu", "relu2"):
+        raise ValueError(f"expert form {expert!r} with w3 "
+                         f"{'absent' if w3 is None else 'given'}")
     n = u.shape[0]
     with phase("moe.route"):
         ids, weights = route(u, router_w, top_k, norm_topk, scale, scoring,
@@ -506,8 +528,9 @@ def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
     if capacity is None:
         capacity = default_capacity(n, top_k, share.experts_held,
                                     share.num_experts_total)
-    uc = u.astype(compute_dtype)
-    w1, w3, w2 = (w.astype(compute_dtype) for w in (w1, w3, w2))
+    uc = (u if rows is None else rows).astype(compute_dtype)
+    w1, w3, w2 = (None if w is None else w.astype(compute_dtype)
+                  for w in (w1, w3, w2))
     with phase("moe.experts"):
         grouped = functools.partial(
             _grouped_path, capacity=capacity, backend=backend,
@@ -524,7 +547,28 @@ def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
     }
     if bias is not None:
         with phase("moe.bias"):
-            stats["counts_all"] = jnp.sum(
-                (ids[..., None] == jnp.arange(share.num_experts_total)) &
-                real[:, None, None], axis=(0, 1), dtype=jnp.float32)
+            stats["counts_all"] = _counts_all(
+                ids, real, share.num_experts_total)
     return y, stats
+
+
+COUNT_AT_ONCE = 1 << 24     # compares one [N, k, E] reduction may make
+
+
+def _counts_all(ids, real, experts):
+    """The real nodes' slots on each of ALL the experts, float32 [E].  One
+    compare over [N, k, E] where that is small (4 of 64: 4.5 M at 17,512
+    nodes); a wide router's (22 of 512: 141 M compares, which the TPU
+    compiler materialises, 0.56 GB three times over) is counted slot by
+    slot, [N, E] at a time."""
+    n, k = ids.shape
+    if n * k * experts <= COUNT_AT_ONCE:
+        return jnp.sum((ids[..., None] == jnp.arange(experts)) &
+                       real[:, None, None], axis=(0, 1), dtype=jnp.float32)
+
+    def slot(j, counts):
+        col = lax.dynamic_index_in_dim(ids, j, axis=1, keepdims=True)
+        return counts + jnp.sum((col == jnp.arange(experts)) & real[:, None],
+                                axis=0, dtype=jnp.float32)
+
+    return lax.fori_loop(0, k, slot, jnp.zeros((experts,), jnp.float32))

@@ -4,8 +4,12 @@ A layer of a sparse language model is shared by ``R`` chips: its routed
 experts over expert-parallel ranks, its attention heads over
 tensor-parallel ranks (one group of query heads per key/value head; or
 none cut, where attention is data-parallel), its vocabulary over
-vocabulary-parallel ranks.  ``LayerShare`` says what THIS chip holds; the
-layer reads it (models/laguna.py, models/glm_moe_lite.py, ops/moe.py): the
+vocabulary-parallel ranks, and, where it has state-space mixers, their heads
+over tensor-parallel ranks by GROUP (a group's ``B`` and ``C`` and the
+grouped norm's statistics belong to that group's heads alone, so a rank that
+holds whole groups computes its heads exactly).  ``LayerShare`` says what
+THIS chip holds; the layer reads it (models/laguna.py,
+models/glm_moe_lite.py, models/nemotron_h.py, ops/moe.py): the
 router keeps its published width and routes over all the experts, and the
 chip computes the part of the result its own experts give.  On one chip the
 layer runs without its exchange: what the absent experts and heads would
@@ -33,6 +37,14 @@ class LayerShare:
     vocab_total: int
     vocab_rows: int
     vocab_offset: int           # first held row's global token id
+    # state-space mixers (models/nemotron_h.py); a model without them
+    # holds "one head of one group"
+    ssm_heads_total: int = 1
+    ssm_heads_held: int = 1
+    ssm_head_offset: int = 0
+    ssm_groups_total: int = 1
+    ssm_groups_held: int = 1
+    ssm_group_offset: int = 0
 
     def __post_init__(self):
         for held, off, total, what in (
@@ -41,10 +53,25 @@ class LayerShare:
                 (self.kv_heads_held, self.kv_head_offset,
                  self.kv_heads_total, "key/value heads"),
                 (self.vocab_rows, self.vocab_offset, self.vocab_total,
-                 "vocabulary rows")):
+                 "vocabulary rows"),
+                (self.ssm_heads_held, self.ssm_head_offset,
+                 self.ssm_heads_total, "state-space heads"),
+                (self.ssm_groups_held, self.ssm_group_offset,
+                 self.ssm_groups_total, "state-space groups")):
             if not (held >= 1 and off >= 0 and off + held <= total):
                 raise ValueError(
                     f"share holds {what} [{off}, {off + held}) of {total}")
+        # whole groups: the held heads are the held groups' heads
+        per_group = self.ssm_heads_total // self.ssm_groups_total
+        if (self.ssm_heads_total % self.ssm_groups_total
+                or self.ssm_heads_held != per_group * self.ssm_groups_held
+                or self.ssm_head_offset != per_group * self.ssm_group_offset):
+            raise ValueError(
+                f"share holds state-space heads [{self.ssm_head_offset}, "
+                f"{self.ssm_head_offset + self.ssm_heads_held}) and groups "
+                f"[{self.ssm_group_offset}, "
+                f"{self.ssm_group_offset + self.ssm_groups_held}): not the "
+                f"whole groups of {per_group} heads")
 
     @staticmethod
     def from_arch(lm: Dict[str, Any], share: Dict[str, Any],
@@ -54,7 +81,19 @@ class LayerShare:
         and ``Architecture.share`` (the published totals and this chip's
         offsets; absent = the uncut model).  A deployment that cuts nothing
         from the heads (attention data-parallel: models/glm_moe_lite.py)
-        gives no ``kv_heads_total``, and every head is held."""
+        gives no ``kv_heads_total``, and every head is held.  A model with
+        state-space mixers names their heads ``mamba_num_heads`` and their
+        groups ``n_groups``."""
+        ssm = {}
+        if "mamba_num_heads" in lm:
+            heads, groups = int(lm["mamba_num_heads"]), int(lm["n_groups"])
+            ssm = dict(
+                ssm_heads_total=int(share.get("ssm_heads_total", heads)),
+                ssm_heads_held=heads,
+                ssm_head_offset=int(share.get("ssm_head_offset", 0)),
+                ssm_groups_total=int(share.get("ssm_groups_total", groups)),
+                ssm_groups_held=groups,
+                ssm_group_offset=int(share.get("ssm_group_offset", 0)))
         return LayerShare(
             num_experts_total=int(share.get("num_experts_total",
                                             lm[experts_key])),
@@ -66,7 +105,7 @@ class LayerShare:
             kv_head_offset=int(share.get("kv_head_offset", 0)),
             vocab_total=int(share.get("vocab_total", lm["vocab_size"])),
             vocab_rows=int(lm["vocab_size"]),
-            vocab_offset=int(share.get("vocab_offset", 0)))
+            vocab_offset=int(share.get("vocab_offset", 0)), **ssm)
 
     def local_expert(self, expert_ids):
         """(local id, held?) of global router ids (any array)."""

@@ -616,6 +616,14 @@ class MetricsLogger:
                     "blocks_run": float(m["attn_blocks_run"]),
                     "blocks_band": float(m["attn_blocks_band"]),
                 }
+            if "ssm_chunks" in m:
+                # what ONE state-space layer's scan walked, summed over
+                # the steps of the dispatch (ops/ssm.py scan_counts)
+                rec["ssm"] = {
+                    "chunks": float(m["ssm_chunks"]),
+                    "chunks_padding": float(m["ssm_chunks_padding"]),
+                    "resets": float(m["ssm_resets"]),
+                }
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

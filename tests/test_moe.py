@@ -1,0 +1,189 @@
+"""ops/moe.py ``routed_experts``: the rows to dispatch apart from the rows
+routed on, and the expert's form as an argument (models/nemotron_h.py's
+latent expert space: ungated ``relu^2`` experts over rows that are not what
+the router reads), on the grouped path (composed and with the kernels
+interpreted), on the dense path and against plain einsums, values and
+gradients; 22 slots a node over a wide router; and the defaults trace to
+the program the two older callers always had."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hydragnn_tpu.ops import moe
+from hydragnn_tpu.parallel.share import LayerShare
+
+N, D, LAT, F, E, HELD, OFF, K = 96, 32, 16, 24, 32, 4, 8, 6
+SHARE = LayerShare(E, HELD, OFF, 1, 1, 0, 64, 64, 0)
+
+
+def _inputs(seed=0):
+    k = jax.random.split(jax.random.PRNGKey(seed), 8)
+    return {
+        "u": jax.random.normal(k[0], (N, D)),
+        "rows": jax.random.normal(k[1], (N, LAT)),
+        "router": jax.random.normal(k[2], (D, E)) * D ** -0.5,
+        "w1": jax.random.normal(k[3], (HELD, LAT, F)) * LAT ** -0.5,
+        "w2": jax.random.normal(k[4], (HELD, F, LAT)) * F ** -0.5,
+        "bias": 0.2 * jax.random.normal(k[5], (E,)),
+        "mask": (jnp.arange(N) < N - 7).astype(jnp.float32),
+    }
+
+
+def _plain(a):
+    """The same sum from plain einsums: every held expert on every node."""
+    ids, weights = moe.route(a["u"], a["router"], K, True, 5.0, "sigmoid",
+                             a["bias"])
+    held = OFF + jnp.arange(HELD)
+    w = jnp.sum(jnp.where(ids[:, :, None] == held, weights[:, :, None], 0.0),
+                axis=1) * a["mask"][:, None]
+    hidden = jnp.square(jax.nn.relu(
+        jnp.einsum("nl,elf->enf", a["rows"], a["w1"])))
+    return jnp.einsum("ne,enl->nl", w,
+                      jnp.einsum("enf,efl->enl", hidden, a["w2"]))
+
+
+def _run(a, **kw):
+    y, stats = moe.routed_experts(
+        a["u"], a["router"], a["w1"], None, a["w2"], SHARE, top_k=K,
+        node_mask=a["mask"], scale=5.0, scoring="sigmoid", bias=a["bias"],
+        rows=a["rows"], expert="relu2", **kw)
+    return y, stats
+
+
+PATHS = {
+    "grouped_composed": dict(backend="ragged_dot"),
+    "grouped_kernels_interpreted": dict(backend="gmm", interpret=True),
+    # a capacity no step fits: every step takes the dense path
+    "dense": dict(backend="ragged_dot", capacity=0),
+}
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_latent_rows_and_relu2_experts_match_plain_einsums(path):
+    a = _inputs()
+    with jax.default_matmul_precision("highest"):
+        want = _plain(a)
+        y, stats = _run(a, **PATHS[path])
+    assert y.shape == (N, LAT) and y.dtype == jnp.float32
+    np.testing.assert_allclose(y, want, rtol=0, atol=2e-5)
+    assert float(stats["dense_steps"]) == float(path == "dense")
+    assert float(stats["slots_all"]) == (N - 7) * K
+    assert stats["counts_all"].shape == (E,)
+    assert float(jnp.sum(stats["counts_all"])) == (N - 7) * K
+    # padding nodes are routed nowhere
+    assert not np.any(np.asarray(y)[N - 7:])
+
+
+@pytest.mark.parametrize("path", list(PATHS))
+def test_gradients_match_plain_einsums(path):
+    a = _inputs(1)
+    probe = jax.random.normal(jax.random.PRNGKey(5), (N, LAT))
+    keys = ("u", "rows", "router", "w1", "w2")
+
+    def loss(fn, *vals):
+        return jnp.sum(fn(dict(a, **dict(zip(keys, vals)))) * probe)
+
+    vals = [a[k] for k in keys]
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda *v: loss(_plain, *v),
+                        argnums=range(5))(*vals)
+        got = jax.grad(lambda *v: loss(
+            lambda b: _run(b, **PATHS[path])[0], *v),
+            argnums=range(5))(*vals)
+    for k, g, w in zip(keys, got, want):
+        np.testing.assert_allclose(g, w, rtol=0, atol=5e-5, err_msg=k)
+    # the router reads ``u``, the experts read ``rows``: each gets its own
+    assert np.any(np.asarray(got[0])) and np.any(np.asarray(got[1]))
+
+
+def test_twenty_two_slots_a_node_over_a_wide_router():
+    """The benchmark cell's routing shape at a small node count: 22 of 512
+    experts a node, 8 held; the grouped path holds every held slot."""
+    n, e, k = 200, 512, 22
+    share = LayerShare(e, 8, 0, 1, 1, 0, 64, 64, 0)
+    key = jax.random.split(jax.random.PRNGKey(2), 5)
+    u = jax.random.normal(key[0], (n, D))
+    rows = jax.random.normal(key[1], (n, LAT))
+    router = jax.random.normal(key[2], (D, e)) * D ** -0.5
+    w1 = jax.random.normal(key[3], (8, LAT, F)) * LAT ** -0.5
+    w2 = jax.random.normal(key[4], (8, F, LAT)) * F ** -0.5
+    args = (u, router, w1, None, w2, share)
+    kw = dict(top_k=k, scale=5.0, scoring="sigmoid", bias=jnp.zeros((e,)),
+              rows=rows, expert="relu2")
+    with jax.default_matmul_precision("highest"):
+        y, stats = moe.routed_experts(*args, **kw)
+        dense, _ = moe.routed_experts(*args, capacity=0, **kw)
+    np.testing.assert_allclose(y, dense, rtol=0, atol=2e-5)
+    assert float(stats["dense_steps"]) == 0.0
+    assert float(stats["slots_all"]) == n * k
+    c = np.asarray(stats["counts_all"])
+    assert c.shape == (e,) and c.sum() == n * k
+    assert float(stats["slots_held"]) == c[:8].sum()
+    # a node sends one expert at most one slot: no more than n a expert
+    assert c.max() <= n
+    assert moe.default_capacity(n, k, 8, e) >= float(stats["slots_held"])
+
+
+def test_a_wide_router_is_counted_slot_by_slot_to_the_same_counts(
+        monkeypatch):
+    ids = jax.random.randint(jax.random.PRNGKey(3), (300, 22), 0, 512)
+    real = jnp.arange(300) < 280
+    at_once = moe._counts_all(ids, real, 512)
+    monkeypatch.setattr(moe, "COUNT_AT_ONCE", 0)
+    by_slot = jax.jit(moe._counts_all, static_argnums=2)(ids, real, 512)
+    assert np.array_equal(np.asarray(at_once), np.asarray(by_slot))
+    assert float(jnp.sum(by_slot)) == 280 * 22
+    # the benchmark cell's shape takes the loop, GLM's the one compare
+    assert 12496 * 22 * 512 > 1 << 24 >= 17512 * 4 * 64
+
+
+def test_the_expert_form_must_fit_the_matrices_given():
+    a = _inputs()
+    w3 = jnp.zeros_like(a["w1"])
+    for w3_given, form in ((w3, "relu2"), (None, "gated_silu"),
+                           (None, "swish")):
+        with pytest.raises(ValueError, match="expert form"):
+            moe.routed_experts(a["rows"], a["router"][:LAT], a["w1"],
+                               w3_given, a["w2"], SHARE, top_k=K,
+                               expert=form)
+
+
+# sha256[:16] of the gradient jaxpr below as the commit BEFORE the keyword
+# arguments (7bd1849, PR 36) printed it, on this container's jax 0.9.0: the
+# defaults must leave the two older callers' traced program as it was.  A
+# jax upgrade changes how jaxprs print: regenerate from that commit then.
+OLD_PROGRAM = {"ragged_dot": "40d9d2c9e908e55e", "gmm": "f7222e22480f0372"}
+
+
+def _old_callers_digest(backend):
+    k = jax.random.split(jax.random.PRNGKey(0), 5)
+    u = jax.random.normal(k[0], (N, D))
+    router = jax.random.normal(k[1], (D, E)) * D ** -0.5
+    w1 = jax.random.normal(k[2], (HELD, D, F))
+    w3 = jax.random.normal(k[3], (HELD, D, F))
+    w2 = jax.random.normal(k[4], (HELD, F, D))
+    mask = (jnp.arange(N) < N - 7).astype(jnp.float32)
+
+    def loss(u, router, w1, w3, w2, bias):
+        y, stats = moe.routed_experts(
+            u, router, w1, w3, w2, SHARE, top_k=K, node_mask=mask,
+            scale=1.8, scoring="sigmoid", bias=bias,
+            compute_dtype=jnp.bfloat16, backend=backend,
+            interpret=backend == "gmm")
+        y2, _ = moe.routed_experts(
+            u, router, w1, w3, w2, SHARE, top_k=K, node_mask=mask,
+            scale=2.5, backend=backend, interpret=backend == "gmm")
+        return jnp.sum(y) + jnp.sum(y2) + jnp.sum(stats["counts_all"])
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(
+        u, router, w1, w3, w2, jnp.zeros((E,))))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("backend", list(OLD_PROGRAM))
+def test_the_defaults_trace_to_the_old_program(backend):
+    assert _old_callers_digest(backend) == OLD_PROGRAM[backend]

@@ -337,27 +337,33 @@ def test_training_smoke_emits_full_jsonl(tmp_path, capsys):
     assert "aggr dispatch:" in rendered
 
 
-@pytest.mark.parametrize("stack", ["laguna", "glm_moe_lite", "sage"])
+@pytest.mark.parametrize("stack", ["laguna", "glm_moe_lite", "nemotron_h",
+                                   "sage"])
 def test_step_records_carry_the_attention_schedule_of_a_language_model(
         stack, tmp_path):
     """A language-model stack's step record has an ``attention`` block
     (ops/attention.py scheduled_blocks, summed over the attending layers'
-    forward calls); a message-passing stack's has none."""
+    forward calls); a message-passing stack's has none.  A stack with
+    state-space layers also has an ``ssm`` block (ops/ssm.py scan_counts:
+    what one layer's scan walked), no other stack has."""
     if stack == "sage":
         cfg, (batch, _pad, _s), layers = _cfg(), _batch(), 0
     else:
         import test_glm_moe_lite
         import test_laguna
+        import test_nemotron_h
 
-        T = test_laguna if stack == "laguna" else test_glm_moe_lite
+        T = {"laguna": test_laguna, "glm_moe_lite": test_glm_moe_lite,
+             "nemotron_h": test_nemotron_h}[stack]
         cfg = ModelConfig.from_config(T.nn_section())
         rng = np.random.default_rng(0)
         docs = [T.sample(rng.integers(0, 64, size=n)) for n in (5, 20, 3, 12)]
         heads = [HeadSpec(f"next{i}", "node", 1)
                  for i in range(len(cfg.output_dim))]
         batch = collate(docs, PadSpec(48, 8, 5), heads)
-        # three layers, and the multi-token-prediction module's own
-        layers = 3 + (stack == "glm_moe_lite")
+        # three layers, and the multi-token-prediction module's own; the
+        # state-space stack's pattern has ONE attention layer
+        layers = {"laguna": 3, "glm_moe_lite": 4, "nemotron_h": 1}[stack]
     model = create_model(cfg)
     opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
     state = create_train_state(model, batch, opt)
@@ -383,6 +389,10 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
         assert r["attention"] == {"blocks_run": float(layers),
                                   "blocks_band": float(layers)}
         assert "moe" in r
+        # 48 node slots in chunks of 16, 40 real nodes, four graphs
+        assert r.get("ssm") == ({"chunks": 3.0, "chunks_padding": 0.0,
+                                 "resets": 4.0}
+                                if stack == "nemotron_h" else None)
 
 
 def test_disabled_logger_writes_nothing(tmp_path):
