@@ -58,6 +58,7 @@ from hydragnn_tpu.models.laguna import (
     ids_and_positions,
 )
 from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
+from hydragnn_tpu.ops.moe import KEEP_ROUTE
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
 
@@ -201,7 +202,7 @@ class GlmLayer(nn.Module):
         h = x + a
         if self.dense:
             return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
-        y, stats = nn.remat(MoE)(
+        y, stats = nn.remat(MoE, policy=KEEP_ROUTE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask, bias)
         return h + y, stats, blocks

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.laguna_reference import apply_rotary
 from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
-from hydragnn_tpu.ops.moe import routed_experts
+from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
 
@@ -173,15 +173,16 @@ class LagunaLayer(nn.Module):
         heads = lm.num_attention_heads_per_layer[self.layer]
         kv = lm.num_key_value_heads
         # the attention half and the feed-forward half are each recomputed
-        # in the backward pass from their input alone (the dense
-        # feed-forward slice by slice, DenseFFN)
+        # in the backward pass from their input (the dense feed-forward
+        # slice by slice, DenseFFN; the expert half also from its router's
+        # kept decision, ops/moe.py KEEP_ROUTE)
         a, blocks = nn.remat(Attention)(
             lm, kind, heads, kv, self.dtype, self.attention_backend,
             self.interpret, name="attn")(x, node_gid, node_mask, positions)
         h = x + a
         if lm.mlp_layer_types[self.layer] == "dense":
             return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
-        y, stats = nn.remat(MoE)(
+        y, stats = nn.remat(MoE, policy=KEEP_ROUTE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask)
         return h + y, stats, blocks

@@ -59,7 +59,7 @@ from hydragnn_tpu.models.laguna import (
     ids_and_positions,
 )
 from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
-from hydragnn_tpu.ops.moe import routed_experts
+from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.ops.ssm import graph_causal_conv, graph_ssm, scan_counts
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
@@ -325,8 +325,9 @@ LAYERS = {"M": Mamba2, "E": LatentMoE, "*": Attention}
 
 def _layer(kind, lm, share, dtype, backends, name, remat=True):
     """One layer; ``remat``: recomputed in the backward pass from its input
-    alone."""
-    cls = nn.remat(LAYERS[kind]) if remat else LAYERS[kind]
+    and, an expert layer, from its router's decision (``KEEP_ROUTE``)."""
+    cls = (nn.remat(LAYERS[kind], policy=KEEP_ROUTE) if remat
+           else LAYERS[kind])
     return cls(lm, share, dtype, backends, name=name)
 
 
@@ -337,7 +338,10 @@ class Unit(nn.Module):
     one a layer; inside that recomputation every layer but the last is
     checkpointed again, so what is alive at once is one layer's
     internals.  (A checkpoint a layer kept 16 KB a row a layer more: 1.5
-    GB at the benchmark's cell, which did not fit; PERF.md section 6.)"""
+    GB at the benchmark's cell, which did not fit; PERF.md section 6.)
+    Both checkpoints keep what ``ops/moe.py KEEP_ROUTE`` names, the
+    router's logits and ids, 2.2 KB a row an ``E`` layer: the router runs
+    once a step and not three times."""
 
     lm: NemotronHConfig
     share: LayerShare
@@ -415,7 +419,7 @@ class NemotronHStack(nn.Module):
                 [biases[f"layer_{i}"].value for i in held]).reshape(
                     reps, per_unit, -1) if held else jnp.zeros((reps, 0, 1))
             x, scanned = nn.scan(
-                nn.remat(Unit), variable_axes={"params": 0},
+                nn.remat(Unit, policy=KEEP_ROUTE), variable_axes={"params": 0},
                 split_rngs={"params": True},
                 in_axes=(0, nn.broadcast, nn.broadcast), length=reps)(
                     lm, share, unit, dtype, backends, name=name)(

@@ -3,10 +3,12 @@
 The layer is TOLD which experts it holds (parallel/share.py LayerShare).
 It routes every node over ALL the experts (the router keeps its published
 width; ``route``: softmax scores and their k largest, or sigmoid scores
-selected under a correction bias that carries no gradient), keeps the
-slots that fall on its own experts, sorts them by expert and runs the
-gated feed-forward as grouped matrix products over the ragged groups; the
-weighted outputs are added up per node.  What the other ranks' experts
+selected under a correction bias that carries no gradient; the selected
+scores read by mask, the logits and the ids named so that a checkpoint
+round the layer keeps them: ``KEEP_ROUTE``), keeps the slots that fall on
+its own experts, sorts them by expert and runs the gated feed-forward as
+grouped matrix products over the ragged groups; the weighted outputs are
+added up per node.  What the other ranks' experts
 would add is left out: on one chip there is no exchange.  The rows that
 are dispatched need not be the rows the router reads (``rows=``: a latent
 expert space), and the expert's form is an argument (``expert=``: the
@@ -59,6 +61,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.utils.scope import phase
 
@@ -84,6 +87,40 @@ def default_capacity(num_nodes, top_k, experts_held, num_experts_total,
     return -(-rows // ROW_TILE) * ROW_TILE
 
 
+# What a checkpoint that wraps an expert layer keeps of ``route`` (16.5 MB
+# a layer at 7,440 nodes over 512 experts): with the logits and the ids kept,
+# a recomputed forward runs neither the HIGHEST product nor ``top_k``.  The
+# logits and not the scores: an activation's derivative reads its own
+# output, which no name can reach, so naming the scores would leave the
+# product in the recomputation.  ``KEEP_ROUTE`` is the policy of every such
+# checkpoint (of the outermost one, where they nest).
+ROUTE_LOGITS = "moe.route.logits"
+ROUTE_IDS = "moe.route.ids"
+KEEP_ROUTE = jax.checkpoint_policies.save_only_these_names(
+    ROUTE_LOGITS, ROUTE_IDS)
+
+
+def _selected(scores, ids):
+    """``scores[n, ids[n, j]]``, [N, k], read by mask: a row's ids are
+    distinct, so each sum over the experts has one non-zero term and IS the
+    selected score, and its transpose is a masked sum over the slots with
+    at most one term an element: both bit for bit what XLA's gather and
+    scatter-add give (which cost 1.4 ms a call at 164 k keys, PERF.md
+    section 6).  Slot by slot, [N, E] at a time, as ``_counts_all``: no
+    [N, k, E] array exists.  The two barriers make ``scores`` (so its
+    cotangent too) and the result arrays of their own, as they were round
+    the gather.  Without them the TPU compiler sums a node's slots for the
+    renormalisation in an order of its own and makes the cotangent inside
+    the operand of the backward pass's two products: the last bit of the
+    weights and of the router's gradients moves, and the step is 5 ms
+    slower (PERF.md section 6)."""
+    experts = jnp.arange(scores.shape[1], dtype=ids.dtype)
+    scores = lax.optimization_barrier(scores)
+    return lax.optimization_barrier(jnp.stack(
+        [jnp.sum(jnp.where(ids[:, j:j + 1] == experts, scores, 0.0), axis=-1)
+         for j in range(ids.shape[1])], axis=-1))
+
+
 def route(u, router_w, top_k, norm_topk=True, scale=1.0, scoring="softmax",
           bias=None):
     """(expert ids [N, k], weights [N, k]) over all the experts, scores in
@@ -97,19 +134,20 @@ def route(u, router_w, top_k, norm_topk=True, scale=1.0, scoring="softmax",
     default is: a bf16 pass moves scores by 2^-9, enough to swap the last
     selected expert of a node with the first one left out, and a swapped
     expert is a different function."""
-    logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
-                     precision=lax.Precision.HIGHEST)
+    logits = checkpoint_name(
+        jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                precision=lax.Precision.HIGHEST), ROUTE_LOGITS)
     if scoring == "softmax":
         scores, eps = jax.nn.softmax(logits, axis=-1), None
     elif scoring == "sigmoid":
         scores, eps = jax.nn.sigmoid(logits), 1e-20
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
-    if bias is None:
-        top, ids = lax.top_k(scores, top_k)
-    else:
-        _, ids = lax.top_k(scores + lax.stop_gradient(bias), top_k)
-        top = jnp.take_along_axis(scores, ids, axis=-1)
+    # the selection carries no gradient; the values are read from ``scores``
+    _, ids = lax.top_k(
+        lax.stop_gradient(scores if bias is None else scores + bias), top_k)
+    ids = checkpoint_name(ids, ROUTE_IDS)
+    top = _selected(scores, ids)
     if norm_topk:
         total = jnp.sum(top, axis=-1, keepdims=True)
         top = top / (total if eps is None else total + eps)

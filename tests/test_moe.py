@@ -3,8 +3,10 @@ routed on, and the expert's form as an argument (models/nemotron_h.py's
 latent expert space: ungated ``relu^2`` experts over rows that are not what
 the router reads), on the grouped path (composed and with the kernels
 interpreted), on the dense path and against plain einsums, values and
-gradients; 22 slots a node over a wide router; and the defaults trace to
-the program the two older callers always had."""
+gradients; 22 slots a node over a wide router; ``route`` reads its selected
+scores by mask to the bits the gather it replaced gave (PR 38: the parent's
+form is kept here as ``parent_route``); and with that form put back the
+defaults trace to the program the two older callers always had."""
 
 import hashlib
 
@@ -12,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from hydragnn_tpu.ops import moe
 from hydragnn_tpu.parallel.share import LayerShare
@@ -152,10 +155,64 @@ def test_the_expert_form_must_fit_the_matrices_given():
                                expert=form)
 
 
+def parent_route(u, router_w, top_k, norm_topk=True, scale=1.0,
+                 scoring="softmax", bias=None):
+    """``route`` as 4cb4377 (PR 37) had it: the selected scores are
+    ``top_k``'s values, or XLA's gather where a bias selects."""
+    logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores, eps = ((jax.nn.softmax(logits, axis=-1), None)
+                   if scoring == "softmax" else
+                   (jax.nn.sigmoid(logits), 1e-20))
+    if bias is None:
+        top, ids = lax.top_k(scores, top_k)
+    else:
+        _, ids = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+        top = jnp.take_along_axis(scores, ids, axis=-1)
+    if norm_topk:
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        top = top / (total if eps is None else total + eps)
+    return ids, top * scale
+
+
+@pytest.mark.parametrize("k,e", [(10, 256), (4, 64), (22, 512)])
+@pytest.mark.parametrize("norm_topk", [True, False])
+@pytest.mark.parametrize("biased", [True, False], ids=["bias", "no_bias"])
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_route_reads_by_mask_what_the_gather_read(scoring, biased,
+                                                  norm_topk, k, e):
+    """Ids equal; weights and the gradients with respect to the nodes and
+    the router equal to the last bit, eagerly and jitted; the last rows are
+    padding nodes, which all carry one input."""
+    n, d = 37, 24
+    key = jax.random.split(jax.random.PRNGKey(k + e), 4)
+    u = jax.random.normal(key[0], (n, d)).at[-6:].set(0.25)
+    router = jax.random.normal(key[1], (d, e)) * d ** -0.5
+    bias = 0.3 * jax.random.normal(key[2], (e,)) if biased else None
+    mix = jax.random.normal(key[3], (n, k))
+
+    def run(fn):
+        def loss(u, router):
+            ids, weights = fn(u, router, k, norm_topk, 2.5, scoring, bias)
+            return jnp.sum(weights * mix), (ids, weights)
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+
+    for wrap in (lambda f: f, jax.jit):
+        (_, (ids, weights)), (du, dw) = wrap(run(moe.route))(u, router)
+        (_, (ids0, weights0)), (du0, dw0) = wrap(run(parent_route))(
+            u, router)
+        assert np.array_equal(ids, ids0)
+        for got, want in ((weights, weights0), (du, du0), (dw, dw0)):
+            assert np.array_equal(np.asarray(got), np.asarray(want))
+        assert np.any(np.asarray(du)) and np.any(np.asarray(dw))
+    assert len(set(np.asarray(ids[-1]).tolist())) == k
+
+
 # sha256[:16] of the gradient jaxpr below as the commit BEFORE the keyword
 # arguments (7bd1849, PR 36) printed it, on this container's jax 0.9.0: the
-# defaults must leave the two older callers' traced program as it was.  A
-# jax upgrade changes how jaxprs print: regenerate from that commit then.
+# defaults must leave the two older callers' traced program as it was, once
+# ``route`` (the one function PR 38 rewrote) is the parent's again.  A jax
+# upgrade changes how jaxprs print: regenerate from that commit then.
 OLD_PROGRAM = {"ragged_dot": "40d9d2c9e908e55e", "gmm": "f7222e22480f0372"}
 
 
@@ -185,5 +242,6 @@ def _old_callers_digest(backend):
 
 
 @pytest.mark.parametrize("backend", list(OLD_PROGRAM))
-def test_the_defaults_trace_to_the_old_program(backend):
+def test_the_defaults_trace_to_the_old_program(backend, monkeypatch):
+    monkeypatch.setattr(moe, "route", parent_route)
     assert _old_callers_digest(backend) == OLD_PROGRAM[backend]
