@@ -756,6 +756,15 @@ _SCOPE_LIST = [
     _sc("moe.latent", "hydragnn_tpu/models/nemotron_h.py",
         "both projections of the latent expert space: hidden -> latent "
         "before dispatch, latent -> hidden after the combine"),
+    # the double-gated short convolution (models/lfm2_moe.py)
+    _sc("sconv.in", "hydragnn_tpu/models/lfm2_moe.py",
+        "a short-convolution operator's norm and its one input product to "
+        "[B | C | X]"),
+    _sc("sconv.core", "hydragnn_tpu/models/lfm2_moe.py",
+        "the gate B * X, the depthwise causal taps that stop at graph "
+        "boundaries and the gate C * v (ops/sconv.py graph_short_conv)"),
+    _sc("sconv.out", "hydragnn_tpu/models/lfm2_moe.py",
+        "a short-convolution operator's output product"),
 ]
 
 SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}

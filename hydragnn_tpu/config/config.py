@@ -65,7 +65,7 @@ EDGE_MODELS = ["PNA", "CGCNN", "SchNet", "EGNN"]
 # language models over each graph's nodes: the edge set is implicit, so no
 # edge list is built (data/transform.py) and the longest graph bands the
 # attention kernel (finalize)
-SEQUENCE_MODELS = ("Laguna", "GlmMoeLite", "NemotronH")
+SEQUENCE_MODELS = ("Laguna", "GlmMoeLite", "NemotronH", "Lfm2Moe")
 EQUIVARIANT_MODELS = ["EGNN", "SchNet"]
 ALL_MODEL_TYPES = [
     "SAGE",
@@ -80,6 +80,7 @@ ALL_MODEL_TYPES = [
     "Laguna",
     "GlmMoeLite",
     "NemotronH",
+    "Lfm2Moe",
 ]
 
 

@@ -196,6 +196,13 @@ class ModelConfig:
             share = LayerShare.from_arch(
                 arch["nemotron_h"], arch.get("share") or {},
                 experts_key="n_routed_experts")
+        if arch["model_type"] == "Lfm2Moe":
+            from hydragnn_tpu.models.lfm2_moe import Lfm2MoeConfig
+            from hydragnn_tpu.parallel.share import LayerShare
+
+            lm = Lfm2MoeConfig.from_arch(arch)
+            share = LayerShare.from_arch(arch["lfm2_moe"],
+                                         arch.get("share") or {})
         if arch["model_type"] == "CGCNN":
             # CGConv preserves feature dims (reference CGCNNStack.py:30-40)
             hidden_dim = arch["input_dim"]

@@ -24,6 +24,7 @@ from hydragnn_tpu.models.egnn import EGCLStack
 from hydragnn_tpu.models.dimenet import DIMEStack
 from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteStack
 from hydragnn_tpu.models.laguna import LagunaStack
+from hydragnn_tpu.models.lfm2_moe import Lfm2MoeStack
 from hydragnn_tpu.models.nemotron_h import NemotronHStack
 
 _STACKS = {
@@ -47,11 +48,13 @@ ALL_ARCHS = tuple(_STACKS)
 # in the per-arch sweeps above: language models over each graph's nodes,
 # each with its own reference and tests (models/laguna.py,
 # tests/test_laguna.py; models/glm_moe_lite.py, tests/test_glm_moe_lite.py;
-# models/nemotron_h.py, tests/test_nemotron_h.py).
+# models/nemotron_h.py, tests/test_nemotron_h.py; models/lfm2_moe.py,
+# tests/test_lfm2_moe.py).
 # The value's second entry is the model's own section of ``Architecture``.
 _SEQUENCE_STACKS = {"Laguna": (LagunaStack, "laguna"),
                     "GlmMoeLite": (GlmMoeLiteStack, "glm_moe_lite"),
-                    "NemotronH": (NemotronHStack, "nemotron_h")}
+                    "NemotronH": (NemotronHStack, "nemotron_h"),
+                    "Lfm2Moe": (Lfm2MoeStack, "lfm2_moe")}
 
 
 def create_model_config(config: Dict[str, Any]) -> Base:

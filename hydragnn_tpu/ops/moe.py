@@ -122,13 +122,14 @@ def _selected(scores, ids):
 
 
 def route(u, router_w, top_k, norm_topk=True, scale=1.0, scoring="softmax",
-          bias=None):
+          bias=None, norm_eps=None):
     """(expert ids [N, k], weights [N, k]) over all the experts, scores in
     float32.  ``scoring`` "softmax": softmax scores, the k largest,
     renormalised, times ``scale``.  ``scoring`` "sigmoid" (DeepSeek-V3's
     ``noaux_tc``, one group): sigmoid scores; with ``bias`` [E] the k
     largest of ``score + bias`` are SELECTED and the weights are the
-    selected experts' UNbiased scores, renormalised (+ 1e-20), times
+    selected experts' UNbiased scores, renormalised (+ ``norm_eps``, None:
+    1e-20, DeepSeek-V3's; models/lfm2_moe.py's family adds 1e-6), times
     ``scale``: the bias moves load and never a weight, and no gradient
     reaches it.  The product runs at HIGHEST precision whatever the step's
     default is: a bf16 pass moves scores by 2^-9, enough to swap the last
@@ -140,7 +141,8 @@ def route(u, router_w, top_k, norm_topk=True, scale=1.0, scoring="softmax",
     if scoring == "softmax":
         scores, eps = jax.nn.softmax(logits, axis=-1), None
     elif scoring == "sigmoid":
-        scores, eps = jax.nn.sigmoid(logits), 1e-20
+        scores = jax.nn.sigmoid(logits)
+        eps = 1e-20 if norm_eps is None else norm_eps
     else:
         raise ValueError(f"unknown router scoring {scoring!r}")
     # the selection carries no gradient; the values are read from ``scores``
@@ -529,11 +531,13 @@ def _dense_path(u, weights, local, held, loads, w1, w3, w2):
 def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
                    norm_topk=True, scale=1.0, scoring="softmax", bias=None,
                    compute_dtype=jnp.float32, capacity=None, backend=None,
-                   interpret=False, rows=None, expert="gated_silu"):
+                   interpret=False, rows=None, expert="gated_silu",
+                   norm_eps=None):
     """The held experts' part of the routed sum for nodes ``u`` [N, D].
 
     ``w1``/``w3`` [held, D, F], ``w2`` [held, F, D], ``router_w`` [D, E];
-    ``scoring`` and ``bias`` [E] as ``route`` takes them.  ``rows`` [N, L]:
+    ``scoring``, ``bias`` [E] and ``norm_eps`` as ``route`` takes them.
+    ``rows`` [N, L]:
     what is dispatched to the experts where that is not what the router
     reads (a latent expert space, models/nemotron_h.py: ``w1`` [held, L,
     F], ``w2`` [held, F, L], the result [N, L]); None: ``u`` itself.
@@ -554,7 +558,7 @@ def routed_experts(u, router_w, w1, w3, w2, share, *, top_k, node_mask=None,
     n = u.shape[0]
     with phase("moe.route"):
         ids, weights = route(u, router_w, top_k, norm_topk, scale, scoring,
-                             bias)
+                             bias, norm_eps)
         local, held = share.local_expert(ids)
         real = (jnp.ones((n,), bool) if node_mask is None
                 else node_mask > 0)

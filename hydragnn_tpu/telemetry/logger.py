@@ -624,6 +624,15 @@ class MetricsLogger:
                     "chunks_padding": float(m["ssm_chunks_padding"]),
                     "resets": float(m["ssm_resets"]),
                 }
+            if "sconv_rows" in m:
+                # what the short convolutions met, summed over the conv
+                # layers and the steps of the dispatch (ops/sconv.py
+                # conv_counts)
+                rec["sconv"] = {
+                    "rows": float(m["sconv_rows"]),
+                    "starts": float(m["sconv_starts"]),
+                    "taps_cut": float(m["sconv_taps_cut"]),
+                }
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

@@ -236,11 +236,12 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
 
 def model_counters(batch_stats) -> Dict[str, jax.Array]:
     """What the model counted in this step for the step records: the
-    top-level ``moe_*``, ``attn_*`` and ``ssm_*`` scalars a stack keeps in
-    ``batch_stats`` (models/laguna.py count_routing, count_blocks;
-    models/nemotron_h.py); {} for every other stack."""
+    top-level ``moe_*``, ``attn_*``, ``ssm_*`` and ``sconv_*`` scalars a
+    stack keeps in ``batch_stats`` (models/laguna.py count_routing,
+    count_blocks; models/nemotron_h.py; models/lfm2_moe.py); {} for every
+    other stack."""
     return {k: v for k, v in batch_stats.items()
-            if k.startswith(("moe_", "attn_", "ssm_"))
+            if k.startswith(("moe_", "attn_", "ssm_", "sconv_"))
             and getattr(v, "ndim", None) == 0}
 
 
@@ -328,7 +329,8 @@ def make_train_step(
 _COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
                       "moe_slots_held", "moe_slots_all", "moe_dense_steps",
                       "attn_blocks_run", "attn_blocks_band",
-                      "ssm_chunks", "ssm_chunks_padding", "ssm_resets")
+                      "ssm_chunks", "ssm_chunks_padding", "ssm_resets",
+                      "sconv_rows", "sconv_starts", "sconv_taps_cut")
 
 
 def merge_scanned_metrics(ms):

@@ -156,9 +156,12 @@ def test_the_expert_form_must_fit_the_matrices_given():
 
 
 def parent_route(u, router_w, top_k, norm_topk=True, scale=1.0,
-                 scoring="softmax", bias=None):
+                 scoring="softmax", bias=None, norm_eps=None):
     """``route`` as 4cb4377 (PR 37) had it: the selected scores are
-    ``top_k``'s values, or XLA's gather where a bias selects."""
+    ``top_k``'s values, or XLA's gather where a bias selects
+    (``norm_eps``: what ``routed_experts`` passes on since PR 40; its
+    callers of that time give none)."""
+    assert norm_eps is None
     logits = jnp.dot(u.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
     scores, eps = ((jax.nn.softmax(logits, axis=-1), None)
@@ -245,3 +248,43 @@ def _old_callers_digest(backend):
 def test_the_defaults_trace_to_the_old_program(backend, monkeypatch):
     monkeypatch.setattr(moe, "route", parent_route)
     assert _old_callers_digest(backend) == OLD_PROGRAM[backend]
+
+
+# the same jaxpr with ``route`` as it is, as 12b8c6c (PR 38) printed it:
+# ``norm_eps`` (PR 40, models/lfm2_moe.py's 1e-6) left at its default
+# changes nothing in the three older callers' traced program
+PR38_PROGRAM = {"ragged_dot": "54e032fbef03a757", "gmm": "dcbc4c9afd9c4586"}
+
+
+@pytest.mark.parametrize("backend", list(PR38_PROGRAM))
+def test_the_default_epsilon_traces_to_the_program_before_it(backend):
+    assert _old_callers_digest(backend) == PR38_PROGRAM[backend]
+
+
+def test_the_epsilon_of_the_renormalisation_is_an_argument():
+    """None is 1e-20, to the jaxpr; 1e-6 selects the same experts and moves
+    a weight by 1e-6 over the sum of the selected scores and no more;
+    softmax scores take no epsilon whatever is given."""
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    u = jax.random.normal(k[0], (37, D))
+    router = jax.random.normal(k[1], (D, E)) * D ** -0.5
+    bias = 0.3 * jax.random.normal(k[2], (E,))
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda u, r, b: moe.route(
+            u, r, K, True, 1.0, "sigmoid", b, **kw))(u, router, bias))
+
+    assert text() == text(norm_eps=1e-20) != text(norm_eps=1e-6)
+    ids0, w0 = moe.route(u, router, K, True, 1.0, "sigmoid", bias)
+    ids1, w1 = moe.route(u, router, K, True, 1.0, "sigmoid", bias, 1e-6)
+    assert np.array_equal(ids0, ids1)
+    total = jnp.sum(jnp.take_along_axis(
+        jax.nn.sigmoid(jnp.dot(u, router, precision=lax.Precision.HIGHEST)),
+        ids0, axis=-1), axis=-1, keepdims=True)
+    # 1e-6 over a sum of K scores in (0, 1), and float32's own rounding
+    rel = np.asarray((w0 - w1) / w0)
+    assert np.all(np.abs(rel - 1e-6 / np.asarray(total)) <= 2.5e-7)
+    assert 1e-7 < float(rel.mean()) < 1e-6
+    soft = [moe.route(u, router, K, True, 1.0, "softmax", None, eps)[1]
+            for eps in (None, 1e-6)]
+    assert np.array_equal(*soft)

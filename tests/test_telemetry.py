@@ -338,23 +338,26 @@ def test_training_smoke_emits_full_jsonl(tmp_path, capsys):
 
 
 @pytest.mark.parametrize("stack", ["laguna", "glm_moe_lite", "nemotron_h",
-                                   "sage"])
+                                   "lfm2_moe", "sage"])
 def test_step_records_carry_the_attention_schedule_of_a_language_model(
         stack, tmp_path):
     """A language-model stack's step record has an ``attention`` block
     (ops/attention.py scheduled_blocks, summed over the attending layers'
     forward calls); a message-passing stack's has none.  A stack with
     state-space layers also has an ``ssm`` block (ops/ssm.py scan_counts:
-    what one layer's scan walked), no other stack has."""
+    what one layer's scan walked), one with short convolutions an ``sconv``
+    block (ops/sconv.py conv_counts, summed over the conv layers); no other
+    stack has either."""
     if stack == "sage":
         cfg, (batch, _pad, _s), layers = _cfg(), _batch(), 0
     else:
         import test_glm_moe_lite
         import test_laguna
+        import test_lfm2_moe
         import test_nemotron_h
 
         T = {"laguna": test_laguna, "glm_moe_lite": test_glm_moe_lite,
-             "nemotron_h": test_nemotron_h}[stack]
+             "nemotron_h": test_nemotron_h, "lfm2_moe": test_lfm2_moe}[stack]
         cfg = ModelConfig.from_config(T.nn_section())
         rng = np.random.default_rng(0)
         docs = [T.sample(rng.integers(0, 64, size=n)) for n in (5, 20, 3, 12)]
@@ -362,8 +365,10 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
                  for i in range(len(cfg.output_dim))]
         batch = collate(docs, PadSpec(48, 8, 5), heads)
         # three layers, and the multi-token-prediction module's own; the
-        # state-space stack's pattern has ONE attention layer
-        layers = {"laguna": 3, "glm_moe_lite": 4, "nemotron_h": 1}[stack]
+        # state-space stack's pattern has ONE attention layer, as has the
+        # short-convolution stack's
+        layers = {"laguna": 3, "glm_moe_lite": 4, "nemotron_h": 1,
+                  "lfm2_moe": 1}[stack]
     model = create_model(cfg)
     opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
     state = create_train_state(model, batch, opt)
@@ -393,6 +398,11 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
         assert r.get("ssm") == ({"chunks": 3.0, "chunks_padding": 0.0,
                                  "resets": 4.0}
                                 if stack == "nemotron_h" else None)
+        # two conv layers over 40 real rows in four graphs of three nodes
+        # and more: 3 taps cut a graph and a layer
+        assert r.get("sconv") == ({"rows": 80.0, "starts": 8.0,
+                                   "taps_cut": 24.0}
+                                  if stack == "lfm2_moe" else None)
 
 
 def test_disabled_logger_writes_nothing(tmp_path):

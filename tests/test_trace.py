@@ -965,6 +965,11 @@ def test_backlog_goes_to_the_first_logger_with_sinks_in_order(tmp_path):
 
     from hydragnn_tpu.telemetry import programs
 
+    # a worker that has built thousands of programs comes here with a full
+    # backlog, whose oldest records go as this test adds its own: a logger
+    # with sinks takes what waits, and hands the recorder back
+    MetricsLogger(TelemetryConfig(enable=True, sinks=("jsonl",)),
+                  run_name="drain", out_dir=str(tmp_path / "drain")).finalize()
     x = jnp.ones(4)
     _fresh_jit("before_logger_a")(x)
     _fresh_jit("before_logger_b")(x)

@@ -28,7 +28,8 @@ The default list is every arch at h128 f32 plus the widths SchNet's
 in-kernel filter network chooses its edge blocks for (128 at ``highest``;
 256 / 512 / 1024 in f32 and bf16; 1024 at ``highest``), the sharded op,
 the routed experts at the language-model cell's shapes in both dtypes, and
-attention at the two language-model cells' shapes.
+attention at three language-model cells' shapes (the last: 32 query heads
+over 8 key/value heads of 64, eight multi-query calls).
 
 Exit code 0 only if every target compiled.
 """
@@ -220,7 +221,8 @@ def main(argv) -> int:
            "cfconv:128:float32:shard_map",
            "moe_rows:20000:3072:bfloat16", "moe_rows:20000:3072:float32",
            "attention:20136:20x20x256:4096", "attention:20000:6x1x128:5580",
-           "attention:20000:9x1x128:5580:512"])
+           "attention:20000:9x1x128:5580:512",
+           "attention:24000:32x8x64:4096"])
     failed = 0
     for t in targets:
         arch, hidden, dtype, *rest = t.split(":")
