@@ -57,7 +57,12 @@ from hydragnn_tpu.models.laguna import (
     count_routing,
     ids_and_positions,
 )
-from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
+from hydragnn_tpu.ops.attention import (
+    KEEP_ATTN_OUT,
+    graph_attention,
+    kept_mb,
+    scheduled_blocks,
+)
 from hydragnn_tpu.ops.moe import KEEP_ROUTE
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
@@ -178,8 +183,9 @@ class LatentAttention(nn.Module):
                                 max_span=lm.max_graph_nodes,
                                 backend=self.backend,
                                 interpret=self.interpret)
-            blocks = scheduled_blocks(node_gid, node_mask,
-                                      max_span=lm.max_graph_nodes)
+            blocks = (*scheduled_blocks(node_gid, node_mask,
+                                        max_span=lm.max_graph_nodes),
+                      kept_mb(q, k, v, KEEP_ATTN_OUT, backend=self.backend))
         with phase("mla.out"):
             return _dot(o.reshape(n, heads * dv), wo, self.dtype), blocks
 
@@ -196,7 +202,13 @@ class GlmLayer(nn.Module):
     @nn.compact
     def __call__(self, x, node_gid, node_mask, positions, bias):
         lm = self.lm
-        a, blocks = nn.remat(LatentAttention)(
+        # each half recomputed in the backward pass from its input; the
+        # attention half also from the kernel's kept result and log-sum-exp
+        # (ops/attention.py KEEP_ATTN_OUT: no forward kernel runs twice).
+        # q, k, v are NOT kept: 20 heads of 256 each, 0.54 GB a layer at
+        # 17,512 nodes, rebuilt by two products from a 768- and a 512-wide
+        # latent and one shared rotary key
+        a, blocks = nn.remat(LatentAttention, policy=KEEP_ATTN_OUT)(
             lm, self.dtype, self.attention_backend, self.interpret,
             name="attn")(x, node_gid, node_mask, positions)
         h = x + a

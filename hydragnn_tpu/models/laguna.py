@@ -40,7 +40,12 @@ import jax.numpy as jnp
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.laguna_reference import apply_rotary
-from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
+from hydragnn_tpu.ops.attention import (
+    KEEP_ATTN,
+    graph_attention,
+    kept_mb,
+    scheduled_blocks,
+)
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
@@ -175,8 +180,12 @@ class LagunaLayer(nn.Module):
         # the attention half and the feed-forward half are each recomputed
         # in the backward pass from their input (the dense feed-forward
         # slice by slice, DenseFFN; the expert half also from its router's
-        # kept decision, ops/moe.py KEEP_ROUTE)
-        a, blocks = nn.remat(Attention)(
+        # kept decision, ops/moe.py KEEP_ROUTE; the attention half also
+        # from the kernel's kept result and log-sum-exp and from q, k, v,
+        # one key/value head beside 6 or 9 query heads: ops/attention.py
+        # KEEP_ATTN, so its backward pass runs no forward kernel, rotary
+        # or cast again)
+        a, blocks = nn.remat(Attention, policy=KEEP_ATTN)(
             lm, kind, heads, kv, self.dtype, self.attention_backend,
             self.interpret, name="attn")(x, node_gid, node_mask, positions)
         h = x + a
@@ -226,8 +235,9 @@ class Attention(nn.Module):
         o = graph_attention(q, k, v, node_gid, node_mask, window=window,
                             max_span=lm.max_graph_nodes,
                             backend=self.backend, interpret=self.interpret)
-        blocks = scheduled_blocks(node_gid, node_mask, window=window,
-                                  max_span=lm.max_graph_nodes)
+        blocks = (*scheduled_blocks(node_gid, node_mask, window=window,
+                                    max_span=lm.max_graph_nodes),
+                  kept_mb(q, k, v, KEEP_ATTN, backend=self.backend))
         with phase("attn.proj"):
             o = o.astype(jnp.float32) * gate[:, :, None]
             return _dot(o.reshape(n, self.heads * hd), wo,
@@ -371,13 +381,14 @@ def count_routing(stack: nn.Module, stats, train, **more):
 
 
 def count_blocks(stack: nn.Module, blocks, train):
-    """The attention kernels' block schedule of this step, summed over the
-    attending layers' forward calls (``blocks``: one
-    ops/attention.py ``scheduled_blocks`` each), kept as
-    ``count_routing`` keeps its counters."""
+    """The attention kernels' block schedule of this step and the MB the
+    attention halves' checkpoints keep, summed over the attending layers'
+    forward calls (``blocks``: one ops/attention.py ``scheduled_blocks``
+    and ``kept_mb`` each), kept as ``count_routing`` keeps its counters.
+    ``attn_kept_mb`` is a number of the step's shape, the same every step."""
     cells = [stack.variable("batch_stats", f"attn_{k}",
                             lambda: jnp.zeros((), jnp.float32))
-             for k in ("blocks_run", "blocks_band")]
+             for k in ("blocks_run", "blocks_band", "kept_mb")]
     if not train or stack.is_initializing():
         return
     for cell, values in zip(cells, zip(*blocks)):

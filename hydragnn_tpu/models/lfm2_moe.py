@@ -59,7 +59,12 @@ from hydragnn_tpu.models.laguna import (
     ids_and_positions,
 )
 from hydragnn_tpu.models.lfm2_moe_reference import apply_rotary
-from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
+from hydragnn_tpu.ops.attention import (
+    KEEP_ATTN,
+    graph_attention,
+    kept_mb,
+    scheduled_blocks,
+)
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.ops.sconv import conv_counts, graph_short_conv
 from hydragnn_tpu.parallel.share import LayerShare
@@ -198,8 +203,9 @@ class Attention(nn.Module):
         o = graph_attention(q, k, v, node_gid, node_mask,
                             max_span=lm.max_graph_nodes,
                             backend=self.backend, interpret=self.interpret)
-        blocks = scheduled_blocks(node_gid, node_mask,
-                                  max_span=lm.max_graph_nodes)
+        blocks = (*scheduled_blocks(node_gid, node_mask,
+                                    max_span=lm.max_graph_nodes),
+                  kept_mb(q, k, v, KEEP_ATTN, backend=self.backend))
         with phase("attn.proj"):
             return _dot(o.reshape(n, heads * hd), wo, self.dtype), blocks
 
@@ -243,13 +249,16 @@ class Lfm2Layer(nn.Module):
         """(x after both halves, routing stats or None, attention's
         scheduled blocks or None); each half recomputed in the backward
         pass from its input (the expert half also from its router's kept
-        decision, ops/moe.py KEEP_ROUTE)."""
+        decision, ops/moe.py KEEP_ROUTE; the attention half also from the
+        kernel's kept result and log-sum-exp and from q, k, v, 8 key/value
+        heads beside 32 query heads, normed and rotated in float32:
+        ops/attention.py KEEP_ATTN)."""
         lm, blocks, stats = self.lm, None, None
         if lm.layer_types[self.layer] == "conv":
             a = nn.remat(ShortConv)(lm, self.dtype, name="op")(
                 x, node_gid, node_mask)
         else:
-            a, blocks = nn.remat(Attention)(
+            a, blocks = nn.remat(Attention, policy=KEEP_ATTN)(
                 lm, self.dtype, self.attention_backend, self.interpret,
                 name="op")(x, node_gid, node_mask, positions)
         h = x + a

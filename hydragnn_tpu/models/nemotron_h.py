@@ -58,7 +58,12 @@ from hydragnn_tpu.models.laguna import (
     count_blocks,
     ids_and_positions,
 )
-from hydragnn_tpu.ops.attention import graph_attention, scheduled_blocks
+from hydragnn_tpu.ops.attention import (
+    KEEP_ATTN,
+    graph_attention,
+    kept_mb,
+    scheduled_blocks,
+)
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.ops.ssm import graph_causal_conv, graph_ssm, scan_counts
 from hydragnn_tpu.parallel.share import LayerShare
@@ -274,8 +279,9 @@ class Attention(nn.Module):
                             max_span=lm.max_graph_nodes,
                             backend=self.backends.attention,
                             interpret=self.backends.interpret)
-        blocks = scheduled_blocks(node_gid, node_mask,
-                                  max_span=lm.max_graph_nodes)
+        blocks = (*scheduled_blocks(node_gid, node_mask,
+                                    max_span=lm.max_graph_nodes),
+                  kept_mb(q, k, v, KEEP, backend=self.backends.attention))
         with phase("attn.proj"):
             return (x + _dot(o.reshape(n, heads * hd), wo, self.dtype), None,
                     blocks)
@@ -322,12 +328,17 @@ class LatentMoE(nn.Module):
 
 LAYERS = {"M": Mamba2, "E": LatentMoE, "*": Attention}
 
+# What every checkpoint of this stack keeps, the two name sets joined (a
+# layer holds the names of its own kind only): an ``E`` layer's router
+# decision, and a ``*`` layer's kernel result, log-sum-exp and q, k, v (one
+# or two key/value heads beside 32 query heads: ops/attention.py KEEP_ATTN).
+KEEP = jax.checkpoint_policies.save_from_both_policies(KEEP_ROUTE, KEEP_ATTN)
+
 
 def _layer(kind, lm, share, dtype, backends, name, remat=True):
     """One layer; ``remat``: recomputed in the backward pass from its input
-    and, an expert layer, from its router's decision (``KEEP_ROUTE``)."""
-    cls = (nn.remat(LAYERS[kind], policy=KEEP_ROUTE) if remat
-           else LAYERS[kind])
+    and from what ``KEEP`` names in it."""
+    cls = nn.remat(LAYERS[kind], policy=KEEP) if remat else LAYERS[kind]
     return cls(lm, share, dtype, backends, name=name)
 
 
@@ -339,9 +350,9 @@ class Unit(nn.Module):
     checkpointed again, so what is alive at once is one layer's
     internals.  (A checkpoint a layer kept 16 KB a row a layer more: 1.5
     GB at the benchmark's cell, which did not fit; PERF.md section 6.)
-    Both checkpoints keep what ``ops/moe.py KEEP_ROUTE`` names, the
-    router's logits and ids, 2.2 KB a row an ``E`` layer: the router runs
-    once a step and not three times."""
+    Both checkpoints keep what ``KEEP`` names: of ``ops/moe.py
+    KEEP_ROUTE`` the router's logits and ids, 2.2 KB a row an ``E`` layer,
+    so the router runs once a step and not three times."""
 
     lm: NemotronHConfig
     share: LayerShare
@@ -419,7 +430,7 @@ class NemotronHStack(nn.Module):
                 [biases[f"layer_{i}"].value for i in held]).reshape(
                     reps, per_unit, -1) if held else jnp.zeros((reps, 0, 1))
             x, scanned = nn.scan(
-                nn.remat(Unit, policy=KEEP_ROUTE), variable_axes={"params": 0},
+                nn.remat(Unit, policy=KEEP), variable_axes={"params": 0},
                 split_rngs={"params": True},
                 in_axes=(0, nn.broadcast, nn.broadcast), length=reps)(
                     lm, share, unit, dtype, backends, name=name)(

@@ -33,6 +33,11 @@ its row stays finite, and nothing else.  Nothing reads a padding row.
     no copy.
 ``dense``   the masked [N, N] composition in ``jax.numpy``: the CPU path
     and the twin the tests hold the kernels to; quadratic in memory.
+
+What a checkpoint round an attention half may keep of the ``splash``
+backend, by name (``KEEP_ATTN``, ``KEEP_ATTN_OUT`` below): the forward
+kernel's result and log-sum-exp, which are all the two backward kernels
+read of it, and the operands.  The ``dense`` backend names nothing.
 """
 
 from __future__ import annotations
@@ -43,10 +48,30 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.utils.scope import phase
 
 _BLOCK = 512      # splash tile edge, and the multiple the node axis pads to
+
+# What a checkpoint that wraps an attention half keeps of the splash
+# backend.  ``ATTN_OUT`` is the forward kernel's result and its log-sum-exp
+# ([heads, padded nodes] each, named inside the library's ``custom_vjp``):
+# with them kept the backward pass runs the dq and dkv kernels and no
+# forward kernel.  ``ATTN_Q`` / ``ATTN_K`` / ``ATTN_V`` are the operands as
+# they arrive (the scale, pad and transpose behind them stay cheap
+# recomputation): with them kept too, nothing upstream of the kernels is
+# recomputed for the kernels' sake.  A half whose operands are few heads
+# made by dear work (a grouped-query layer's normed, rotated q and k) keeps
+# ``KEEP_ATTN``; one whose operands are many heads rebuilt from something
+# narrow (latent attention's 20 heads of 256 from a 576-wide latent: 3.2 GB
+# over six layers at 17,512 nodes) keeps ``KEEP_ATTN_OUT`` and rebuilds
+# them.  ``kept_mb`` is the count under either.
+ATTN_Q, ATTN_K, ATTN_V = "attn.core.q", "attn.core.k", "attn.core.v"
+ATTN_OUT = "attn.core.out"
+KEEP_ATTN = jax.checkpoint_policies.save_only_these_names(
+    ATTN_Q, ATTN_K, ATTN_V, ATTN_OUT)
+KEEP_ATTN_OUT = jax.checkpoint_policies.save_only_these_names(ATTN_OUT)
 
 
 def default_backend() -> str:
@@ -116,6 +141,31 @@ def scheduled_blocks(node_gid, node_mask=None, *, window=None,
         return jnp.sum(needed), int(in_band.sum())
 
 
+@functools.lru_cache(maxsize=None)
+def _name_primitive():
+    """The primitive ``checkpoint_name`` binds: what a policy is asked."""
+    return jax.make_jaxpr(lambda a: checkpoint_name(a, ""))(0.0).eqns[
+        0].primitive
+
+
+def kept_mb(q, k, v, policy, *, backend=None):
+    """MB (1e6 bytes) that a checkpoint under ``policy`` keeps of ONE
+    ``graph_attention`` call on these operands, by asking ``policy`` for
+    each name above: the forward kernel's result in ``q``'s dtype and its
+    float32 log-sum-exp over the padded node axis (``ATTN_OUT``), and q, k
+    and v as they arrive.  0 under no policy, and on the ``dense`` backend,
+    which names nothing.  A number of the shapes alone."""
+    if policy is None or (backend or default_backend()) != "splash":
+        return 0.0
+    n, h, _ = q.shape
+    rows = h * _padded(n, None, None)[0]
+    named = {ATTN_OUT: rows * (v.shape[-1] * q.dtype.itemsize + 4),
+             **{name: a.size * a.dtype.itemsize
+                for name, a in ((ATTN_Q, q), (ATTN_K, k), (ATTN_V, v))}}
+    return sum(size for name, size in named.items()
+               if policy(_name_primitive(), name=name)) / 1e6
+
+
 def _follow(info, needed, dkv):
     """``info`` (a kernel's static tables, [1, query blocks, positions], or
     for dkv [1, positions, key blocks]: the grid is shrunk to the band, and
@@ -161,7 +211,8 @@ def _splash_kernel(n, heads, band, interpret, multi_head=False):
         make = sk.make_splash_mha if multi_head else sk.make_splash_mqa
         return make(
             sm.MultiHeadMask([mask] * heads), block_sizes=blocks,
-            head_shards=1, q_seq_shards=1, interpret=interpret)
+            head_shards=1, q_seq_shards=1, interpret=interpret,
+            residual_checkpoint_name=ATTN_OUT)
 
 
 def _splash(q, k, v, gid, band, interpret):
@@ -173,6 +224,10 @@ def _splash(q, k, v, gid, band, interpret):
     n, h, d = q.shape
     kv = k.shape[1]
     n_pad = gid.shape[0]
+    # named flat, [N, heads x d]: kept as [N, heads, d] the last two axes
+    # (1 to 32 heads, a head of 64) would be padded to the chip's tiles
+    q, k, v = (checkpoint_name(a.reshape(n, -1), name).reshape(a.shape)
+               for a, name in ((q, ATTN_Q), (k, ATTN_K), (v, ATTN_V)))
     pad = ((0, n_pad - n), (0, 0), (0, 0))
     # the kernel takes the scale with q
     q = jnp.pad(q * (1.0 / math.sqrt(d)), pad)

@@ -611,10 +611,13 @@ class MetricsLogger:
             if "attn_blocks_band" in m:
                 # the attention kernels' block schedule, summed over the
                 # layers' forward calls and the steps of the dispatch
-                # (ops/attention.py scheduled_blocks)
+                # (ops/attention.py scheduled_blocks), and the MB the
+                # attention halves' checkpoints keep in ONE step, summed
+                # over the layers (ops/attention.py kept_mb)
                 rec["attention"] = {
                     "blocks_run": float(m["attn_blocks_run"]),
                     "blocks_band": float(m["attn_blocks_band"]),
+                    "kept_mb": float(m["attn_kept_mb"]),
                 }
             if "ssm_chunks" in m:
                 # what ONE state-space layer's scan walked, summed over

@@ -331,6 +331,9 @@ _COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
                       "attn_blocks_run", "attn_blocks_band",
                       "ssm_chunks", "ssm_chunks_padding", "ssm_resets",
                       "sconv_rows", "sconv_starts", "sconv_taps_cut")
+# a number of the dispatch's shape, the same on each of the K steps: the
+# merge hands it on as it is
+_STATIC_METRIC_KEYS = ("attn_kept_mb",)
 
 
 def merge_scanned_metrics(ms):
@@ -338,13 +341,15 @@ def merge_scanned_metrics(ms):
     multi-step train step — same epoch-accumulation semantics as K separate
     dispatches (one definition shared by the local and mesh scan paths).
     Counts (graphs/nodes/edges consumed) sum over the K steps; losses and
-    the telemetry norms merge graph-weighted."""
+    the telemetry norms merge graph-weighted; a static number stays."""
     ng = ms["num_graphs"]
     total = jnp.maximum(jnp.sum(ng), 1.0)
     merged = {}
     for k, v in ms.items():
         if k in _COUNT_METRIC_KEYS:
             merged[k] = jnp.sum(v)
+        elif k in _STATIC_METRIC_KEYS:
+            merged[k] = v[0]
         else:
             merged[k] = jnp.sum(v * ng) / total
     return merged

@@ -23,7 +23,7 @@ from hydragnn_tpu.models import glm_moe_lite, nemotron_h
 from hydragnn_tpu.models import laguna as laguna_model
 from hydragnn_tpu.models.base import ModelConfig
 from hydragnn_tpu.models.create import create_model
-from hydragnn_tpu.ops import moe
+from hydragnn_tpu.ops import attention, moe
 from hydragnn_tpu.train.trainer import _loss_and_metrics
 
 WRAPPERS = (laguna_model, glm_moe_lite, nemotron_h)
@@ -91,6 +91,7 @@ def test_one_top_k_a_layer_no_gather_and_the_parents_bits(name, monkeypatch):
     # the parent's form: no policy at any wrap, top_k's values or a gather
     for module in WRAPPERS:
         monkeypatch.setattr(module, "KEEP_ROUTE", None)
+    monkeypatch.setattr(nemotron_h, "KEEP", None)
     monkeypatch.setattr(moe, "route", parent_route)
     fn0, params0 = _grad_fn(name)
     jaxpr0 = jax.make_jaxpr(fn0)(params0).jaxpr
@@ -109,3 +110,8 @@ def test_one_top_k_a_layer_no_gather_and_the_parents_bits(name, monkeypatch):
 
 def test_every_wrap_of_an_expert_layer_has_the_one_policy():
     assert all(module.KEEP_ROUTE is moe.KEEP_ROUTE for module in WRAPPERS)
+    # the state-space stack's checkpoints keep an attention layer's names
+    # too (tests/test_attention_residuals.py): the router's among them
+    name = attention._name_primitive()
+    assert all(nemotron_h.KEEP(name, name=n)
+               for n in (moe.ROUTE_LOGITS, moe.ROUTE_IDS))

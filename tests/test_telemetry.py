@@ -391,8 +391,10 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
             continue
         # 48 nodes are one block of the kernels' 512: every layer's band
         # is its diagonal block, and that always runs
+        # the dense backend (the CPU's) names nothing for a checkpoint to keep
         assert r["attention"] == {"blocks_run": float(layers),
-                                  "blocks_band": float(layers)}
+                                  "blocks_band": float(layers),
+                                  "kept_mb": 0.0}
         assert "moe" in r
         # 48 node slots in chunks of 16, 40 real nodes, four graphs
         assert r.get("ssm") == ({"chunks": 3.0, "chunks_padding": 0.0,
