@@ -23,10 +23,14 @@ residual stream, the norms, the rotary angles, the router (HIGHEST), the
 softmaxes and the loss stay float32.  This stack casts for itself
 (``casts_at_boundary = False``): the trainer's boundary cast would round
 the router and the ids.  Each half of a layer (attention; feed-forward) is
-recomputed in the backward pass from its input alone, the dense
-feed-forward in ``DENSE_CHUNKS`` node slices: beside 16 bytes a parameter
-of weights, gradients and AdamW moments there is room for one half-layer's
-activations, not for five layers'.
+recomputed in the backward pass from its input and from the few arrays its
+checkpoint keeps by name because running them again is dear (the attention
+kernel's result, log-sum-exp and operands: ops/attention.py ``KEEP_ATTN``;
+the router's decision: ops/moe.py ``KEEP_ROUTE``; the dense feed-forward's
+two hidden products: ``KEEP_FFN`` below), the dense feed-forward in
+``DENSE_CHUNKS`` node slices: beside 16 bytes a parameter of weights,
+gradients and AdamW moments there is room for one half-layer's activations
+and those arrays, not for five layers'.
 """
 
 from __future__ import annotations
@@ -37,6 +41,7 @@ from typing import Any, ClassVar, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.laguna_reference import apply_rotary
@@ -44,6 +49,7 @@ from hydragnn_tpu.ops.attention import (
     KEEP_ATTN,
     graph_attention,
     kept_mb,
+    named_mb,
     scheduled_blocks,
 )
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
@@ -136,26 +142,60 @@ def _rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale
 
 
-def _gated_mlp(u, w1, w3, w2, dtype):
+def _gated_mlp(u, w1, w3, w2, dtype, names=None):
     """The hidden products leave the MXU rounded to ``dtype`` (float32
-    accumulation inside): at width 12288 a float32 hidden is 1 GB."""
+    accumulation inside): at width 12288 a float32 hidden is 1 GB.
+    ``names``: what to bind the two under for a checkpoint's policy (the
+    dense feed-forward's; the shared experts bind nothing)."""
     h1, h3 = _dot(u, w1, dtype, dtype), _dot(u, w3, dtype, dtype)
+    if names:
+        h1, h3 = checkpoint_name(h1, names[0]), checkpoint_name(h3, names[1])
     h = (jax.nn.silu(h1.astype(jnp.float32)) * h3).astype(dtype)
     return _dot(h, w2, dtype)
 
 
-def _in_chunks(fn, u, chunks):
+def _in_chunks(fn, u, chunks, policy=None):
     """``fn`` over ``chunks`` slices of the node axis, one at a time and
     each recomputed in the backward pass: a wide hidden layer then lives
-    for one slice only.  ``chunks`` must divide the node count."""
+    for one slice only.  What ``policy`` keeps of a slice is not
+    recomputed and lives for the whole step.  Under a policy the loop over
+    the slices is unrolled: as a loop it would hand the kept arrays on,
+    stacked, as loop state, and of a loop inside the scanned train step the
+    TPU compiler reserves that state twice (1.1 GB kept cost 2.2 GB at
+    23,512 nodes: PERF.md section 6, PR 42).  ``chunks`` must divide the
+    node count."""
     if chunks <= 1 or u.shape[0] % chunks:
         return fn(u)
-    out = jax.lax.map(jax.checkpoint(fn),
-                      u.reshape(chunks, u.shape[0] // chunks, u.shape[1]))
+    piece = jax.checkpoint(fn, policy=policy)
+    _, out = jax.lax.scan(
+        lambda _, x: ((), piece(x)), (),
+        u.reshape(chunks, u.shape[0] // chunks, u.shape[1]),
+        unroll=policy is not None)
     return out.reshape(u.shape[0], out.shape[-1])
 
 
 DENSE_CHUNKS = 4     # node slices of the dense feed-forward
+
+# What the checkpoint of a dense feed-forward's slice keeps where the layer
+# hands ``DenseFFN`` this policy: the two up-products, [N, intermediate] in
+# the compute dtype each once the slices are stacked.  With them kept a
+# recomputed slice runs the norm and the elementwise gate (whose float32
+# temporaries still live one slice at a time) and neither product: two of
+# the half's eight wide products a step (1.15e12 FLOP each at 15,168 nodes,
+# 3072 -> 12288).  Whether a stack keeps them is its layer's to say, by the
+# memory its step has left.
+FFN_H1, FFN_H3 = "ffn.dense.h1", "ffn.dense.h3"
+KEEP_FFN = jax.checkpoint_policies.save_only_these_names(FFN_H1, FFN_H3)
+
+
+def where_narrow(policy, dtype):
+    """``policy`` where the products leave the MXU in 2 bytes a value
+    (``dtype`` bfloat16), else None: a wide product's result is worth its
+    room at that size only.  In float32 the same arrays are twice the
+    bytes: one float32 forward and backward pass at 23,512 nodes, five
+    layers, would need 17.3 GB with them and needs 12.7 without (the
+    device has 16.9; PERF.md section 6, PR 42)."""
+    return policy if jnp.dtype(dtype).itemsize <= 2 else None
 
 
 def _init(fan_in):
@@ -173,28 +213,34 @@ class LagunaLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, node_gid, node_mask, positions):
+        """(x after both halves, routing stats or None, attention's
+        scheduled blocks, the MB the feed-forward half's checkpoints keep:
+        ``{"ffn": MB}`` on a dense layer, else {}).  The attention half and
+        the feed-forward half are each recomputed in the backward pass from
+        their input: the dense feed-forward slice by slice (DenseFFN) and,
+        in bfloat16, from its two kept up-products (KEEP_FFN, where_narrow:
+        0.75 GB at 15,168 nodes, where the step needs 12.5 of the device's
+        16.9 GB); the expert half also from its router's kept decision
+        (ops/moe.py KEEP_ROUTE); the attention half also from the kernel's
+        kept result and log-sum-exp and from q, k, v, one key/value head
+        beside 6 or 9 query heads (ops/attention.py KEEP_ATTN), so its
+        backward pass runs no forward kernel, rotary or cast again."""
         lm = self.lm
         kind = lm.layer_types[self.layer]
         heads = lm.num_attention_heads_per_layer[self.layer]
         kv = lm.num_key_value_heads
-        # the attention half and the feed-forward half are each recomputed
-        # in the backward pass from their input (the dense feed-forward
-        # slice by slice, DenseFFN; the expert half also from its router's
-        # kept decision, ops/moe.py KEEP_ROUTE; the attention half also
-        # from the kernel's kept result and log-sum-exp and from q, k, v,
-        # one key/value head beside 6 or 9 query heads: ops/attention.py
-        # KEEP_ATTN, so its backward pass runs no forward kernel, rotary
-        # or cast again)
         a, blocks = nn.remat(Attention, policy=KEEP_ATTN)(
             lm, kind, heads, kv, self.dtype, self.attention_backend,
             self.interpret, name="attn")(x, node_gid, node_mask, positions)
         h = x + a
         if lm.mlp_layer_types[self.layer] == "dense":
-            return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
+            ffn = DenseFFN(lm, self.dtype,
+                           where_narrow(KEEP_FFN, self.dtype), name="ffn")
+            return h + ffn(h), None, blocks, {"ffn": ffn.kept_mb(h)}
         y, stats = nn.remat(MoE, policy=KEEP_ROUTE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask)
-        return h + y, stats, blocks
+        return h + y, stats, blocks, {}
 
 
 class Attention(nn.Module):
@@ -245,8 +291,12 @@ class Attention(nn.Module):
 
 
 class DenseFFN(nn.Module):
+    """``policy``: what each slice's checkpoint keeps (``KEEP_FFN``, or
+    None: a slice is recomputed from its input alone)."""
+
     lm: LagunaConfig
     dtype: Any
+    policy: Any = None
 
     @nn.compact
     def __call__(self, h):
@@ -257,10 +307,22 @@ class DenseFFN(nn.Module):
         w2 = self.param("w2", _init(f), (f, d))
         def ffn(hs):
             u = _rms_norm(hs, norm, self.lm.rms_norm_eps)
-            return _gated_mlp(u, w1, w3, w2, self.dtype)
+            # named only where a checkpoint asks: a bare name leaves the
+            # program as it was but for the numbering of its functions,
+            # which is enough to miss the compile cache
+            names = (FFN_H1, FFN_H3) if self.policy else None
+            return _gated_mlp(u, w1, w3, w2, self.dtype, names)
 
         with phase("ffn.dense"):
-            return _in_chunks(ffn, h, DENSE_CHUNKS)
+            return _in_chunks(ffn, h, DENSE_CHUNKS, self.policy)
+
+    def kept_mb(self, h):
+        """MB (1e6 bytes) the slices' checkpoints keep of ``h``'s rows in
+        one step, by asking the policy for each name: a number of the
+        shapes alone, 0 under no policy."""
+        hidden = jax.ShapeDtypeStruct(
+            (h.shape[0], self.lm.intermediate_size), self.dtype)
+        return named_mb(self.policy, {FFN_H1: hidden, FFN_H3: hidden})
 
 
 class MoE(nn.Module):
@@ -325,13 +387,14 @@ class LagunaStack(nn.Module):
         with phase("lm.embed"):
             ids, positions = ids_and_positions(g, share)
             x = jnp.take(embed, ids, axis=0)
-        stats, blocks = [], []
+        stats, blocks, kept = [], [], []
         for layer in range(lm.num_layers):
-            x, s, b = LagunaLayer(
+            x, s, b, m = LagunaLayer(
                 lm, share, layer, dtype, self.attention_backend,
                 self.moe_backend, self.interpret, name=f"layer_{layer}")(
                     x, g.node_gid, g.node_mask, positions)
             blocks.append(b)
+            kept.append(m)
             if s is not None:
                 stats.append(s)
         final_norm = self.param("final_norm", nn.initializers.ones,
@@ -343,6 +406,7 @@ class LagunaStack(nn.Module):
                           dtype)
         count_routing(self, stats, train)
         count_blocks(self, blocks, train)
+        count_kept(self, kept, train, "ffn")
         return (logits,)
 
 
@@ -393,3 +457,18 @@ def count_blocks(stack: nn.Module, blocks, train):
         return
     for cell, values in zip(cells, zip(*blocks)):
         cell.value = jnp.asarray(sum(values), jnp.float32)
+
+
+def count_kept(stack: nn.Module, kept, train, *blocks):
+    """The MB that the checkpoints of the halves named in ``blocks`` keep
+    in this step beyond attention's (``kept``: one ``{block: MB}`` a
+    layer), summed over the layers and kept as ``<block>_kept_mb``, as
+    ``count_blocks`` keeps ``attn_kept_mb``: numbers of the step's shape."""
+    cells = {b: stack.variable("batch_stats", f"{b}_kept_mb",
+                               lambda: jnp.zeros((), jnp.float32))
+             for b in blocks}
+    if not train or stack.is_initializing():
+        return
+    for b, cell in cells.items():
+        cell.value = jnp.asarray(sum(m.get(b, 0.0) for m in kept),
+                                 jnp.float32)

@@ -47,22 +47,27 @@ from typing import Any, ClassVar, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.glm_moe_lite import balance
 from hydragnn_tpu.models.laguna import (
+    KEEP_FFN,
     DenseFFN,
     _dot,
     _init,
     _rms_norm,
     count_blocks,
+    count_kept,
     ids_and_positions,
+    where_narrow,
 )
 from hydragnn_tpu.models.lfm2_moe_reference import apply_rotary
 from hydragnn_tpu.ops.attention import (
     KEEP_ATTN,
     graph_attention,
     kept_mb,
+    named_mb,
     scheduled_blocks,
 )
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
@@ -71,6 +76,18 @@ from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
 
 ROUTE_NORM_EPS = 1e-6       # the family's block; ops/moe.py route
+
+# What the checkpoint round a short-convolution half keeps: the input
+# product's result ``[B | C | X]``, [N, 3 x hidden] in the compute dtype
+# (289 MB a layer at 23,512 nodes in bfloat16; the last axis is 48 x 128
+# lanes, so what the chip holds is what the shape says).  With it kept the
+# recomputed forward runs the norm (the weight's gradient reads it), the
+# three slices and ``sconv.core`` (the output product's gradient reads
+# ``y``), and NOT the 2048 -> 6144 product: 5.9e11 FLOP a layer.  ``y`` is
+# not kept: rebuilding it is one fused memory-bound pass, ~3 ms a step over
+# four layers, against 0.39 GB.
+SCONV_PROJ = "sconv.in.proj"
+KEEP_SCONV = jax.checkpoint_policies.save_only_these_names(SCONV_PROJ)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -162,8 +179,9 @@ class ShortConv(nn.Module):
                 k, s, jnp.float32, -taps ** -0.5, taps ** -0.5), (taps, d))
         w_out = self.param("w_out", _init(d), (d, d))
         with phase("sconv.in"):
-            proj = _dot(_rms_norm(x, norm, lm.norm_eps), w_in, self.dtype,
-                        self.dtype)
+            proj = checkpoint_name(
+                _dot(_rms_norm(x, norm, lm.norm_eps), w_in, self.dtype,
+                     self.dtype), SCONV_PROJ)
         with phase("sconv.core"):
             y = graph_short_conv(proj[:, :d], proj[:, d:2 * d],
                                  proj[:, 2 * d:], conv_w, node_gid,
@@ -247,27 +265,40 @@ class Lfm2Layer(nn.Module):
     @nn.compact
     def __call__(self, x, node_gid, node_mask, positions, bias):
         """(x after both halves, routing stats or None, attention's
-        scheduled blocks or None); each half recomputed in the backward
-        pass from its input (the expert half also from its router's kept
-        decision, ops/moe.py KEEP_ROUTE; the attention half also from the
-        kernel's kept result and log-sum-exp and from q, k, v, 8 key/value
-        heads beside 32 query heads, normed and rotated in float32:
-        ops/attention.py KEEP_ATTN)."""
-        lm, blocks, stats = self.lm, None, None
+        scheduled blocks or None, the MB the other halves' checkpoints
+        keep: ``{"sconv": MB}`` on a conv layer, ``{"ffn": MB}`` on a dense
+        one).  Each half is recomputed in the backward pass from its input
+        and from what its checkpoint keeps by name: in bfloat16
+        (models/laguna.py where_narrow) the short convolution its input
+        product (KEEP_SCONV) and the dense feed-forward, slice by slice,
+        its two up-products (models/laguna.py KEEP_FFN), 1.16 + 1.11 GB at
+        23,512 nodes, where the step needs 13.2 of the device's 16.9 GB;
+        the expert half its router's decision (ops/moe.py KEEP_ROUTE); the
+        attention half the kernel's result and log-sum-exp and q, k, v, 8
+        key/value heads beside 32 query heads, normed and rotated in
+        float32 (ops/attention.py KEEP_ATTN)."""
+        lm, blocks, stats, kept = self.lm, None, None, {}
         if lm.layer_types[self.layer] == "conv":
-            a = nn.remat(ShortConv)(lm, self.dtype, name="op")(
-                x, node_gid, node_mask)
+            keep = where_narrow(KEEP_SCONV, self.dtype)
+            a = nn.remat(ShortConv, policy=keep)(
+                lm, self.dtype, name="op")(x, node_gid, node_mask)
+            kept["sconv"] = named_mb(keep, {
+                SCONV_PROJ: jax.ShapeDtypeStruct(
+                    (x.shape[0], 3 * lm.hidden_size), self.dtype)})
         else:
             a, blocks = nn.remat(Attention, policy=KEEP_ATTN)(
                 lm, self.dtype, self.attention_backend, self.interpret,
                 name="op")(x, node_gid, node_mask, positions)
         h = x + a
         if self.layer < lm.num_dense_layers:
-            return h + DenseFFN(lm, self.dtype, name="ffn")(h), stats, blocks
+            ffn = DenseFFN(lm, self.dtype,
+                           where_narrow(KEEP_FFN, self.dtype), name="ffn")
+            kept["ffn"] = ffn.kept_mb(h)
+            return h + ffn(h), stats, blocks, kept
         y, stats = nn.remat(Experts, policy=KEEP_ROUTE)(
             lm, self.share, self.dtype, self.moe_backend, self.interpret,
             name="moe")(h, node_mask, bias)
-        return h + y, stats, blocks
+        return h + y, stats, blocks, kept
 
 
 class Lfm2MoeStack(nn.Module):
@@ -302,10 +333,10 @@ class Lfm2MoeStack(nn.Module):
         with phase("lm.embed"):
             ids, positions = ids_and_positions(g, share)
             x = jnp.take(embed, ids, axis=0)
-        stats, blocks = {}, []
+        stats, blocks, kept = {}, [], []
         for layer in range(len(lm.layer_types)):
             name = f"layer_{layer}"
-            x, s, b = Lfm2Layer(
+            x, s, b, m = Lfm2Layer(
                 lm, share, layer, dtype, self.attention_backend,
                 self.moe_backend, self.interpret, name=name)(
                     x, g.node_gid, g.node_mask, positions,
@@ -314,6 +345,7 @@ class Lfm2MoeStack(nn.Module):
                 stats[name] = s
             if b is not None:
                 blocks.append(b)
+            kept.append(m)
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (lm.hidden_size,))
         with phase("lm.head"):
@@ -324,6 +356,7 @@ class Lfm2MoeStack(nn.Module):
         if blocks:
             count_blocks(self, blocks, train)
         self._count_convs(g, train)
+        count_kept(self, kept, train, "sconv", "ffn")
         return (logits,)
 
     def _count_convs(self, g, train):

@@ -148,22 +148,31 @@ def _name_primitive():
         0].primitive
 
 
+def named_mb(policy, named):
+    """MB (1e6 bytes) of the arrays in ``named`` ({name: an array, a
+    ``ShapeDtypeStruct`` or a byte count}) that a checkpoint under
+    ``policy`` keeps, by asking ``policy`` for each name; 0 under no
+    policy.  A number of the shapes alone."""
+    if policy is None:
+        return 0.0
+    return sum(a if isinstance(a, int) else a.size * a.dtype.itemsize
+               for name, a in named.items()
+               if policy(_name_primitive(), name=name)) / 1e6
+
+
 def kept_mb(q, k, v, policy, *, backend=None):
     """MB (1e6 bytes) that a checkpoint under ``policy`` keeps of ONE
-    ``graph_attention`` call on these operands, by asking ``policy`` for
-    each name above: the forward kernel's result in ``q``'s dtype and its
-    float32 log-sum-exp over the padded node axis (``ATTN_OUT``), and q, k
-    and v as they arrive.  0 under no policy, and on the ``dense`` backend,
-    which names nothing.  A number of the shapes alone."""
-    if policy is None or (backend or default_backend()) != "splash":
+    ``graph_attention`` call on these operands (``named_mb``): the forward
+    kernel's result in ``q``'s dtype and its float32 log-sum-exp over the
+    padded node axis (``ATTN_OUT``), and q, k and v as they arrive.  0
+    under no policy, and on the ``dense`` backend, which names nothing."""
+    if (backend or default_backend()) != "splash":
         return 0.0
     n, h, _ = q.shape
     rows = h * _padded(n, None, None)[0]
-    named = {ATTN_OUT: rows * (v.shape[-1] * q.dtype.itemsize + 4),
-             **{name: a.size * a.dtype.itemsize
-                for name, a in ((ATTN_Q, q), (ATTN_K, k), (ATTN_V, v))}}
-    return sum(size for name, size in named.items()
-               if policy(_name_primitive(), name=name)) / 1e6
+    return named_mb(policy, {
+        ATTN_OUT: rows * (v.shape[-1] * q.dtype.itemsize + 4),
+        ATTN_Q: q, ATTN_K: k, ATTN_V: v})
 
 
 def _follow(info, needed, dkv):

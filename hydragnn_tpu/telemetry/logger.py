@@ -630,12 +630,20 @@ class MetricsLogger:
             if "sconv_rows" in m:
                 # what the short convolutions met, summed over the conv
                 # layers and the steps of the dispatch (ops/sconv.py
-                # conv_counts)
+                # conv_counts), and the MB their checkpoints keep in ONE
+                # step, summed over the conv layers (models/lfm2_moe.py
+                # KEEP_SCONV)
                 rec["sconv"] = {
                     "rows": float(m["sconv_rows"]),
                     "starts": float(m["sconv_starts"]),
                     "taps_cut": float(m["sconv_taps_cut"]),
+                    "kept_mb": float(m["sconv_kept_mb"]),
                 }
+            if "ffn_kept_mb" in m:
+                # the MB the dense feed-forwards' checkpoints keep in ONE
+                # step, summed over the dense layers (models/laguna.py
+                # KEEP_FFN)
+                rec["ffn"] = {"kept_mb": float(m["ffn_kept_mb"])}
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

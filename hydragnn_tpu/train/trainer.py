@@ -236,12 +236,12 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
 
 def model_counters(batch_stats) -> Dict[str, jax.Array]:
     """What the model counted in this step for the step records: the
-    top-level ``moe_*``, ``attn_*``, ``ssm_*`` and ``sconv_*`` scalars a
-    stack keeps in ``batch_stats`` (models/laguna.py count_routing,
-    count_blocks; models/nemotron_h.py; models/lfm2_moe.py); {} for every
-    other stack."""
+    top-level ``moe_*``, ``attn_*``, ``ssm_*``, ``sconv_*`` and ``ffn_*``
+    scalars a stack keeps in ``batch_stats`` (models/laguna.py
+    count_routing, count_blocks, count_kept; models/nemotron_h.py;
+    models/lfm2_moe.py); {} for every other stack."""
     return {k: v for k, v in batch_stats.items()
-            if k.startswith(("moe_", "attn_", "ssm_", "sconv_"))
+            if k.startswith(("moe_", "attn_", "ssm_", "sconv_", "ffn_"))
             and getattr(v, "ndim", None) == 0}
 
 
@@ -333,7 +333,7 @@ _COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
                       "sconv_rows", "sconv_starts", "sconv_taps_cut")
 # a number of the dispatch's shape, the same on each of the K steps: the
 # merge hands it on as it is
-_STATIC_METRIC_KEYS = ("attn_kept_mb",)
+_STATIC_METRIC_KEYS = ("attn_kept_mb", "sconv_kept_mb", "ffn_kept_mb")
 
 
 def merge_scanned_metrics(ms):

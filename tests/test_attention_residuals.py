@@ -137,7 +137,7 @@ def _half(name, backend):
     params = layer.init({"params": keys[2]}, x, *args)["params"]
 
     def loss(params, x):
-        out, _, blocks = layer.apply({"params": params}, x, *args)
+        out, _, blocks, *_ = layer.apply({"params": params}, x, *args)
         return jnp.sum(out * weigh), blocks
 
     return loss, (params, x), (_named(names, SLOTS, N_PAD, heads, kv, size),

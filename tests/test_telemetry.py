@@ -346,8 +346,10 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
     forward calls); a message-passing stack's has none.  A stack with
     state-space layers also has an ``ssm`` block (ops/ssm.py scan_counts:
     what one layer's scan walked), one with short convolutions an ``sconv``
-    block (ops/sconv.py conv_counts, summed over the conv layers); no other
-    stack has either."""
+    block (ops/sconv.py conv_counts, summed over the conv layers, and the
+    MB those layers' checkpoints keep); no other stack has either.  A stack
+    whose dense feed-forward keeps its up-products says how many MB in an
+    ``ffn`` block (models/laguna.py KEEP_FFN)."""
     if stack == "sage":
         cfg, (batch, _pad, _s), layers = _cfg(), _batch(), 0
     else:
@@ -401,10 +403,17 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
                                  "resets": 4.0}
                                 if stack == "nemotron_h" else None)
         # two conv layers over 40 real rows in four graphs of three nodes
-        # and more: 3 taps cut a graph and a layer
+        # and more: 3 taps cut a graph and a layer; in float32 a layer's
+        # checkpoint keeps nothing of its input product
+        # (tests/test_product_residuals.py has the bfloat16 numbers)
         assert r.get("sconv") == ({"rows": 80.0, "starts": 8.0,
-                                   "taps_cut": 24.0}
+                                   "taps_cut": 24.0, "kept_mb": 0.0}
                                   if stack == "lfm2_moe" else None)
+        # a stack whose dense layer may keep its up-products says so;
+        # latent attention's never does, the state-space stack has no
+        # such layer
+        assert r.get("ffn") == ({"kept_mb": 0.0}
+                                if stack in ("laguna", "lfm2_moe") else None)
 
 
 def test_disabled_logger_writes_nothing(tmp_path):
