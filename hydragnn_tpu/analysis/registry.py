@@ -694,7 +694,7 @@ _SCOPE_LIST = [
         "scores, softmax and values over each graph's nodes (the splash "
         "kernels and the block schedule made from node_gid, or the dense "
         "twin)"),
-    _sc("ffn.dense", "hydragnn_tpu/models/laguna.py",
+    _sc("ffn.dense", "hydragnn_tpu/models/sequence.py",
         "the dense gated feed-forward, node slice by node slice"),
     _sc("moe.route", "hydragnn_tpu/ops/moe.py",
         "router product, softmax, top-k, the held experts' loads"),
@@ -708,12 +708,12 @@ _SCOPE_LIST = [
         "the row movement alone, inside moe.experts: the held slots' "
         "index bookkeeping, nodes -> rows and rows -> nodes (the two "
         "row-walk kernels, or take / segment_sum)"),
-    _sc("moe.shared", "hydragnn_tpu/models/laguna.py",
+    _sc("moe.shared", "hydragnn_tpu/models/sequence.py",
         "the shared expert's gated feed-forward"),
     _sc("moe.bias", "hydragnn_tpu/ops/moe.py",
         "a router under a correction bias: the real nodes' slots on each "
         "of ALL the experts, and the bias's step after a train step "
-        "(models/glm_moe_lite.py)"),
+        "(models/sequence.py balance)"),
     _sc("lm.head", "hydragnn_tpu/models/laguna.py",
         "final norm and the untied head product"),
     _sc("lm.xent", "hydragnn_tpu/models/layers.py",

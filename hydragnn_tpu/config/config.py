@@ -64,8 +64,12 @@ def _telemetry_defaults() -> Dict[str, Any]:
 EDGE_MODELS = ["PNA", "CGCNN", "SchNet", "EGNN"]
 # language models over each graph's nodes: the edge set is implicit, so no
 # edge list is built (data/transform.py) and the longest graph bands the
-# attention kernel (finalize)
-SEQUENCE_MODELS = ("Laguna", "GlmMoeLite", "NemotronH", "Lfm2Moe")
+# attention kernel (finalize).  THE table of the sequence stacks:
+# ``model_type`` -> the stack's section of ``Architecture``, which is also
+# its module under hydragnn_tpu/models/ (``Config``, ``Stack``;
+# models/sequence.py says what a new one brings)
+SEQUENCE_MODELS = {"Laguna": "laguna", "GlmMoeLite": "glm_moe_lite",
+                   "NemotronH": "nemotron_h", "Lfm2Moe": "lfm2_moe"}
 EQUIVARIANT_MODELS = ["EGNN", "SchNet"]
 ALL_MODEL_TYPES = [
     "SAGE",
@@ -77,10 +81,7 @@ ALL_MODEL_TYPES = [
     "SchNet",
     "DimeNet",
     "EGNN",
-    "Laguna",
-    "GlmMoeLite",
-    "NemotronH",
-    "Lfm2Moe",
+    *SEQUENCE_MODELS,
 ]
 
 

@@ -19,6 +19,7 @@ Differences by design (TPU-first):
 from __future__ import annotations
 
 import dataclasses
+from importlib import import_module
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import flax.linen as nn
 
+from hydragnn_tpu.config.config import SEQUENCE_MODELS
 from hydragnn_tpu.graph import segment
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.layers import (
@@ -34,6 +36,14 @@ from hydragnn_tpu.models.layers import (
     activation_module,
     loss_function,
 )
+
+
+def sequence_module(model_type: str):
+    """The module of a sequence stack (a row of ``SEQUENCE_MODELS``): its
+    ``Config`` and ``Stack``.  Imported when asked for, so that building a
+    message-passing model imports no language model."""
+    return import_module(
+        f"hydragnn_tpu.models.{SEQUENCE_MODELS[model_type]}")
 
 
 def _validated_compute_dtype(arch) -> str:
@@ -173,36 +183,14 @@ class ModelConfig:
             avg_lin = float((bins * hist).sum() / total)
         hidden_dim = arch["hidden_dim"]
         lm = share = None
-        if arch["model_type"] == "Laguna":
-            from hydragnn_tpu.models.laguna import LagunaConfig
+        if arch["model_type"] in SEQUENCE_MODELS:
             from hydragnn_tpu.parallel.share import LayerShare
 
-            lm = LagunaConfig.from_arch(arch)
-            share = LayerShare.from_arch(arch["laguna"],
-                                         arch.get("share") or {})
-        if arch["model_type"] == "GlmMoeLite":
-            from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteConfig
-            from hydragnn_tpu.parallel.share import LayerShare
-
-            lm = GlmMoeLiteConfig.from_arch(arch)
+            config = sequence_module(arch["model_type"]).Config
+            lm = config.from_arch(arch)
             share = LayerShare.from_arch(
-                arch["glm_moe_lite"], arch.get("share") or {},
-                experts_key="n_routed_experts")
-        if arch["model_type"] == "NemotronH":
-            from hydragnn_tpu.models.nemotron_h import NemotronHConfig
-            from hydragnn_tpu.parallel.share import LayerShare
-
-            lm = NemotronHConfig.from_arch(arch)
-            share = LayerShare.from_arch(
-                arch["nemotron_h"], arch.get("share") or {},
-                experts_key="n_routed_experts")
-        if arch["model_type"] == "Lfm2Moe":
-            from hydragnn_tpu.models.lfm2_moe import Lfm2MoeConfig
-            from hydragnn_tpu.parallel.share import LayerShare
-
-            lm = Lfm2MoeConfig.from_arch(arch)
-            share = LayerShare.from_arch(arch["lfm2_moe"],
-                                         arch.get("share") or {})
+                arch[SEQUENCE_MODELS[arch["model_type"]]],
+                arch.get("share") or {}, experts_key=config.experts_key)
         if arch["model_type"] == "CGCNN":
             # CGConv preserves feature dims (reference CGCNNStack.py:30-40)
             hidden_dim = arch["input_dim"]

@@ -11,8 +11,9 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
+from hydragnn_tpu.config.config import SEQUENCE_MODELS
 from hydragnn_tpu.graph.batch import GraphBatch
-from hydragnn_tpu.models.base import Base, ModelConfig
+from hydragnn_tpu.models.base import Base, ModelConfig, sequence_module
 from hydragnn_tpu.models.sage import SAGEStack
 from hydragnn_tpu.models.gin import GINStack
 from hydragnn_tpu.models.gat import GATStack
@@ -22,10 +23,6 @@ from hydragnn_tpu.models.cgcnn import CGCNNStack
 from hydragnn_tpu.models.schnet import SCFStack
 from hydragnn_tpu.models.egnn import EGCLStack
 from hydragnn_tpu.models.dimenet import DIMEStack
-from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteStack
-from hydragnn_tpu.models.laguna import LagunaStack
-from hydragnn_tpu.models.lfm2_moe import Lfm2MoeStack
-from hydragnn_tpu.models.nemotron_h import NemotronHStack
 
 _STACKS = {
     "SAGE": SAGEStack,
@@ -41,20 +38,11 @@ _STACKS = {
 
 # THE canonical arch list: bench.py's per-arch sweep and the fused-vs-
 # scatter parity tests (tests/test_fused_block.py) both derive from it, so
-# a newly registered stack cannot miss bench or parity coverage.
+# a newly registered stack cannot miss bench or parity coverage.  The
+# sequence stacks (config.SEQUENCE_MODELS: language models over each
+# graph's nodes, not message passing over an edge list) take no part in
+# those sweeps; each has its own reference and tests.
 ALL_ARCHS = tuple(_STACKS)
-
-# Stacks that are not message passing over an edge list and so take no part
-# in the per-arch sweeps above: language models over each graph's nodes,
-# each with its own reference and tests (models/laguna.py,
-# tests/test_laguna.py; models/glm_moe_lite.py, tests/test_glm_moe_lite.py;
-# models/nemotron_h.py, tests/test_nemotron_h.py; models/lfm2_moe.py,
-# tests/test_lfm2_moe.py).
-# The value's second entry is the model's own section of ``Architecture``.
-_SEQUENCE_STACKS = {"Laguna": (LagunaStack, "laguna"),
-                    "GlmMoeLite": (GlmMoeLiteStack, "glm_moe_lite"),
-                    "NemotronH": (NemotronHStack, "nemotron_h"),
-                    "Lfm2Moe": (Lfm2MoeStack, "lfm2_moe")}
 
 
 def create_model_config(config: Dict[str, Any]) -> Base:
@@ -64,12 +52,11 @@ def create_model_config(config: Dict[str, Any]) -> Base:
 
 
 def create_model(cfg: ModelConfig) -> Base:
-    if cfg.model_type in _SEQUENCE_STACKS:
-        stack, section = _SEQUENCE_STACKS[cfg.model_type]
+    if cfg.model_type in SEQUENCE_MODELS:
         if cfg.lm is None or cfg.share is None:
-            raise ValueError(
-                f"{cfg.model_type} requires Architecture.{section}")
-        return stack(cfg=cfg)
+            raise ValueError(f"{cfg.model_type} requires Architecture."
+                             f"{SEQUENCE_MODELS[cfg.model_type]}")
+        return sequence_module(cfg.model_type).Stack(cfg=cfg)
     if cfg.model_type not in _STACKS:
         raise ValueError(f"Unknown model_type: {cfg.model_type}")
     if (cfg.model_type == "GAT" and cfg.dropout > 0

@@ -2,12 +2,10 @@
 a correction bias, and multi-token prediction as a second head: the
 eleventh stack.
 
-A document is a graph, a token a node, as in models/laguna.py, whose
-embedding, dense feed-forward, expert module and precision rules this
-stack shares (float32 parameters; with ``compute_dtype: bfloat16`` the
-matrix products take bfloat16 operands and accumulate in float32; residual
-stream, norms, rotary angles, router, softmaxes and losses float32; each
-half-layer recomputed in the backward pass).  What is its own:
+A document is a graph, a token a node (models/sequence.py, whose dense
+feed-forward, expert module, correction bias, precision rules and counters
+this stack reads; each half-layer is recomputed in the backward pass).
+What is its own:
 
 * **Latent attention (MLA)**, unabsorbed as training computes it: queries
   through a normed rank-768 bottleneck; keys and values rebuilt from ONE
@@ -19,11 +17,8 @@ half-layer recomputed in the backward pass).  What is its own:
 * **The correction bias** ``b`` (``e_score_correction_bias``, one [E] per
   expert layer, zeros at the start): state in ``batch_stats`` that no
   gradient moves.  Selection reads ``score + b``, the weights the unbiased
-  scores (ops/moe.py route).  After a TRAIN step ``b <- b + BIAS_UPDATE_SPEED
-  x sign(mean(c) - c)``, ``c`` the step's slots on each of ALL the experts
-  over real nodes: this rank's own count; in the deployment it is summed
-  over the ranks, and no code stands in for them.  Eval steps read ``b``
-  and leave it alone.
+  scores (ops/moe.py route); models/sequence.py ``balance`` steps it after
+  a train step.
 * **Multi-token prediction**: ``h'_i = [RMSNorm(Emb(t_{i+1})) |
   RMSNorm(h_i)] Weh``, one more expert layer with its own router and bias,
   its own final norm, the MAIN head's matrix: the stack's second output,
@@ -47,29 +42,22 @@ import jax.numpy as jnp
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.glm_moe_lite_reference import apply_rotary
-from hydragnn_tpu.models.laguna import (
+from hydragnn_tpu.models.sequence import (
     DenseFFN,
     MoE,
-    _dot,
-    _init,
-    _rms_norm,
+    SequenceStack,
+    attend,
+    balance,
     count_blocks,
-    count_routing,
+    dot,
+    fan_in,
     ids_and_positions,
+    rms_norm,
 )
-from hydragnn_tpu.ops.attention import (
-    KEEP_ATTN_OUT,
-    graph_attention,
-    kept_mb,
-    scheduled_blocks,
-)
+from hydragnn_tpu.ops.attention import KEEP_ATTN_OUT
 from hydragnn_tpu.ops.moe import KEEP_ROUTE
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.utils.scope import phase
-
-# the bias's step (DeepSeek-V3's bias update speed; not in the config:
-# ``ASSUMED`` in the reference)
-BIAS_UPDATE_SPEED = 1e-3
 
 
 @dataclasses.dataclass(frozen=True)
@@ -98,6 +86,7 @@ class GlmMoeLiteConfig:
     num_nextn_predict_layers: int
     max_graph_nodes: Optional[int] = None
     router_scoring: ClassVar[str] = "sigmoid"     # ops/moe.py route
+    experts_key: ClassVar[str] = "n_routed_experts"     # parallel/share.py
 
     @staticmethod
     def from_arch(arch: Dict[str, Any]) -> "GlmMoeLiteConfig":
@@ -122,7 +111,7 @@ class GlmMoeLiteConfig:
                for k, t in sizes.items()},
             max_graph_nodes=arch.get("max_graph_nodes"))
 
-    # what models/laguna.py's expert module reads
+    # what models/sequence.py's expert module reads
     @property
     def shared_expert_intermediate_size(self) -> int:
         return self.n_shared_experts * self.moe_intermediate_size
@@ -152,21 +141,21 @@ class LatentAttention(nn.Module):
         rq, rkv = lm.q_lora_rank, lm.kv_lora_rank
         n, eps = x.shape[0], lm.rms_norm_eps
         norm = self.param("norm", nn.initializers.ones, (d,))
-        wdq = self.param("wdq", _init(d), (d, rq))
+        wdq = self.param("wdq", fan_in(d), (d, rq))
         q_norm = self.param("q_norm", nn.initializers.ones, (rq,))
-        wuq = self.param("wuq", _init(rq), (rq, heads * (nope + rope)))
-        wdkv = self.param("wdkv", _init(d), (d, rkv + rope))
+        wuq = self.param("wuq", fan_in(rq), (rq, heads * (nope + rope)))
+        wdkv = self.param("wdkv", fan_in(d), (d, rkv + rope))
         kv_norm = self.param("kv_norm", nn.initializers.ones, (rkv,))
-        wukv = self.param("wukv", _init(rkv), (rkv, heads * (nope + dv)))
-        wo = self.param("wo", _init(heads * dv), (heads * dv, d))
+        wukv = self.param("wukv", fan_in(rkv), (rkv, heads * (nope + dv)))
+        wo = self.param("wo", fan_in(heads * dv), (heads * dv, d))
         with phase("mla.down"):
-            u = _rms_norm(x, norm, eps)
-            cq = _rms_norm(_dot(u, wdq, self.dtype), q_norm, eps)
-            down = _dot(u, wdkv, self.dtype)
-            ckv = _rms_norm(down[:, :rkv], kv_norm, eps)
+            u = rms_norm(x, norm, eps)
+            cq = rms_norm(dot(u, wdq, self.dtype), q_norm, eps)
+            down = dot(u, wdkv, self.dtype)
+            ckv = rms_norm(down[:, :rkv], kv_norm, eps)
         with phase("mla.up"):
-            q = _dot(cq, wuq, self.dtype).reshape(n, heads, nope + rope)
-            kv = _dot(ckv, wukv, self.dtype).reshape(n, heads, nope + dv)
+            q = dot(cq, wuq, self.dtype).reshape(n, heads, nope + rope)
+            kv = dot(ckv, wukv, self.dtype).reshape(n, heads, nope + dv)
             # the rotation is the reference's own function (float32 angles)
             q = jnp.concatenate(
                 [q[..., :nope],
@@ -179,15 +168,12 @@ class LatentAttention(nn.Module):
                 axis=-1).astype(self.dtype)
             v = kv[..., nope:].astype(self.dtype)
         with phase("mla.core"):
-            o = graph_attention(q, k, v, node_gid, node_mask,
-                                max_span=lm.max_graph_nodes,
-                                backend=self.backend,
-                                interpret=self.interpret)
-            blocks = (*scheduled_blocks(node_gid, node_mask,
-                                        max_span=lm.max_graph_nodes),
-                      kept_mb(q, k, v, KEEP_ATTN_OUT, backend=self.backend))
+            o, blocks = attend(
+                q, k, v, node_gid, node_mask, keep=KEEP_ATTN_OUT,
+                max_span=lm.max_graph_nodes, backend=self.backend,
+                interpret=self.interpret)
         with phase("mla.out"):
-            return _dot(o.reshape(n, heads * dv), wo, self.dtype), blocks
+            return dot(o.reshape(n, heads * dv), wo, self.dtype), blocks
 
 
 class GlmLayer(nn.Module):
@@ -214,7 +200,7 @@ class GlmLayer(nn.Module):
         h = x + a
         if self.dense:
             # no policy: each slice is recomputed from its input alone.
-            # The two up-products (models/laguna.py KEEP_FFN) would be 0.72
+            # The two up-products (models/sequence.py KEEP_FFN) would be 0.72
             # GB at 17,512 nodes for ~9 ms of a 753 ms step, and this
             # stack's step needs 15.4 of the device's 16.9 GB without them
             return h + DenseFFN(lm, self.dtype, name="ffn")(h), None, blocks
@@ -239,7 +225,7 @@ class NextNextToken(nn.Module):
         lm, d, eps = self.lm, self.lm.hidden_size, self.lm.rms_norm_eps
         enorm = self.param("enorm", nn.initializers.ones, (d,))
         hnorm = self.param("hnorm", nn.initializers.ones, (d,))
-        eh_proj = self.param("eh_proj", _init(2 * d), (2 * d, d))
+        eh_proj = self.param("eh_proj", fan_in(2 * d), (2 * d, d))
         final_norm = self.param("final_norm", nn.initializers.ones, (d,))
         with phase("mtp.proj"):
             # nodes of a graph are contiguous: node i's successor is node
@@ -251,42 +237,26 @@ class NextNextToken(nn.Module):
                 (g.node_gid[1:] == g.node_gid[:-1]) & (g.node_mask[1:] > 0),
                 jnp.zeros((1,), bool)])
             after = jnp.where(has_next, jnp.roll(ids, -1), ids)
-            x = _dot(jnp.concatenate(
-                [_rms_norm(jnp.take(embed, after, axis=0), enorm, eps),
-                 _rms_norm(h, hnorm, eps)], axis=-1), eh_proj, self.dtype)
+            x = dot(jnp.concatenate(
+                [rms_norm(jnp.take(embed, after, axis=0), enorm, eps),
+                 rms_norm(h, hnorm, eps)], axis=-1), eh_proj, self.dtype)
         with phase("mtp.layer"):
             x, stats, blocks = GlmLayer(
                 lm, self.share, False, self.dtype, self.attention_backend,
                 self.moe_backend, self.interpret, name="layer")(
                     x, g.node_gid, g.node_mask * has_next, positions, bias)
         with phase("mtp.head"):
-            return _dot(_rms_norm(x, final_norm, eps), head,
-                        self.dtype), stats, blocks
+            return dot(rms_norm(x, final_norm, eps), head,
+                       self.dtype), stats, blocks
 
 
-class GlmMoeLiteStack(nn.Module):
-    """``cfg.lm`` / ``cfg.share`` carry the model; the trainer's contract
-    is the other stacks': ``model.apply(variables, batch, train=...)`` ->
-    a tuple with one output per head: the logits [N, V held] for node
-    ``i+1``'s id and, with the multi-token-prediction module, for node
-    ``i+2``'s."""
-
-    cfg: Any
-    attention_backend: Optional[str] = None
-    moe_backend: Optional[str] = None
-    interpret: bool = False
-
-    # as models/laguna.py LagunaStack: the stack casts for itself, shapes
-    # its parameters under jit, and leaves the in-run MFU estimate out
-    casts_at_boundary = False
-    jit_init = True
-    cost_model_sees_flops = False
+class GlmMoeLiteStack(SequenceStack):
+    """One output per head: the logits [N, V held] for node ``i+1``'s id
+    and, with the multi-token-prediction module, for node ``i+2``'s."""
 
     @nn.compact
     def __call__(self, g: GraphBatch, train: bool = True):
-        lm, share = self.cfg.lm, self.cfg.share
-        dtype = (jnp.bfloat16 if self.cfg.compute_dtype == "bfloat16"
-                 else jnp.float32)
+        lm, share, dtype = self.cfg.lm, self.cfg.share, self.compute_dtype
         backends = (self.attention_backend, self.moe_backend, self.interpret)
         embed = self.param("embed", nn.initializers.normal(stddev=1.0),
                            (share.vocab_rows, lm.hidden_size))
@@ -309,11 +279,11 @@ class GlmMoeLiteStack(nn.Module):
                 stats[name] = s
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (lm.hidden_size,))
-        head = self.param("head", _init(lm.hidden_size),
+        head = self.param("head", fan_in(lm.hidden_size),
                           (lm.hidden_size, share.vocab_rows))
         with phase("lm.head"):
-            logits = _dot(_rms_norm(x, final_norm, lm.rms_norm_eps), head,
-                          dtype)
+            logits = dot(rms_norm(x, final_norm, lm.rms_norm_eps), head,
+                         dtype)
         outputs = (logits,)
         if "mtp" in biases:
             logits2, stats["mtp"], b = NextNextToken(
@@ -327,24 +297,4 @@ class GlmMoeLiteStack(nn.Module):
         return outputs
 
 
-def balance(stack: nn.Module, biases, stats, train):
-    """The bias's step after a train step, and the step's counters kept in
-    ``stack``: models/laguna.py's and, over ALL the experts, the fullest
-    one's slots over the mean (what the bias acts on) and the largest
-    ``|b|``.  ``biases`` / ``stats``: the expert layers' bias variables and
-    routing stats by layer name (also models/nemotron_h.py's)."""
-    with phase("moe.bias"):
-        counts = [s["counts_all"] for s in stats.values()]
-        if train and not stack.is_initializing():
-            for name, s in stats.items():
-                c = s["counts_all"]
-                biases[name].value = (
-                    biases[name].value
-                    + BIAS_UPDATE_SPEED * jnp.sign(jnp.mean(c) - c))
-        count_routing(
-            stack, list(stats.values()), train,
-            load_all_max_over_mean=sum(
-                jnp.max(c) / jnp.maximum(jnp.mean(c), 1.0)
-                for c in counts) / len(counts),
-            bias_abs_max=jnp.max(jnp.stack(
-                [jnp.max(jnp.abs(b.value)) for b in biases.values()])))
+Config, Stack = GlmMoeLiteConfig, GlmMoeLiteStack

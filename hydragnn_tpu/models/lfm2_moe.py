@@ -3,12 +3,10 @@ graph's nodes in three layers of four, qk-normed grouped-query attention in
 the fourth, sigmoid routing under an expert bias with no shared expert, one
 table for embedding and head: the thirteenth stack.
 
-A document is a graph, a token a node, as in models/laguna.py, whose dense
-feed-forward, precision rules and counters this stack shares (float32
-parameters; with ``compute_dtype: bfloat16`` the matrix products take
-bfloat16 operands and accumulate in float32; residual stream, norms, rotary
-angles, router, softmax and loss float32; each half-layer recomputed in the
-backward pass).  What is its own:
+A document is a graph, a token a node (models/sequence.py, whose dense
+feed-forward, correction bias, precision rules and counters this stack
+reads; each half-layer is recomputed in the backward pass).  What is its
+own:
 
 * **A layer's first half is read from ``layer_types``** (``conv`` or
   ``full_attention``), its second from the layer's index (``<
@@ -25,7 +23,7 @@ backward pass).  What is its own:
   over each query and key head before the rotation, full rotary, no gate
   (``graph_attention``, ops/attention.py).
 * **Experts**: ``routed_experts`` alone (ops/moe.py; sigmoid scores, the
-  bias of models/glm_moe_lite.py and its step, the weights renormalised
+  bias that models/sequence.py ``balance`` steps, the weights renormalised
   over ``sum + 1e-6``).  There is NO shared expert: a node none of whose
   selected experts is held here gets nothing from this half but the
   residual.
@@ -50,29 +48,26 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.graph.batch import GraphBatch
-from hydragnn_tpu.models.glm_moe_lite import balance
-from hydragnn_tpu.models.laguna import (
+from hydragnn_tpu.models.lfm2_moe_reference import apply_rotary
+from hydragnn_tpu.models.sequence import (
     KEEP_FFN,
     DenseFFN,
-    _dot,
-    _init,
-    _rms_norm,
+    SequenceStack,
+    attend,
+    balance,
     count_blocks,
     count_kept,
+    dot,
+    fan_in,
     ids_and_positions,
+    rms_norm,
     where_narrow,
 )
-from hydragnn_tpu.models.lfm2_moe_reference import apply_rotary
-from hydragnn_tpu.ops.attention import (
-    KEEP_ATTN,
-    graph_attention,
-    kept_mb,
-    named_mb,
-    scheduled_blocks,
-)
+from hydragnn_tpu.ops.attention import KEEP_ATTN, named_mb
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.ops.sconv import conv_counts, graph_short_conv
 from hydragnn_tpu.parallel.share import LayerShare
+from hydragnn_tpu.telemetry import counters
 from hydragnn_tpu.utils.scope import phase
 
 ROUTE_NORM_EPS = 1e-6       # the family's block; ops/moe.py route
@@ -112,6 +107,7 @@ class Lfm2MoeConfig:
     rope_theta: float
     max_graph_nodes: Optional[int] = None
     router_scoring: ClassVar[str] = "sigmoid"     # ops/moe.py route
+    experts_key: ClassVar[str] = "num_experts"    # parallel/share.py
 
     @staticmethod
     def from_arch(arch: Dict[str, Any]) -> "Lfm2MoeConfig":
@@ -153,7 +149,7 @@ class Lfm2MoeConfig:
             layer_types=kinds, rope_theta=float(rope["rope_theta"]),
             max_graph_nodes=arch.get("max_graph_nodes"))
 
-    # what models/laguna.py's dense feed-forward reads
+    # what models/sequence.py's dense feed-forward reads
     @property
     def rms_norm_eps(self) -> float:
         return self.norm_eps
@@ -173,21 +169,21 @@ class ShortConv(nn.Module):
     def __call__(self, x, node_gid, node_mask):
         lm, d, taps = self.lm, self.lm.hidden_size, self.lm.conv_L_cache
         norm = self.param("norm", nn.initializers.ones, (d,))
-        w_in = self.param("w_in", _init(d), (d, 3 * d))
+        w_in = self.param("w_in", fan_in(d), (d, 3 * d))
         conv_w = self.param(
             "conv_w", lambda k, s: jax.random.uniform(
                 k, s, jnp.float32, -taps ** -0.5, taps ** -0.5), (taps, d))
-        w_out = self.param("w_out", _init(d), (d, d))
+        w_out = self.param("w_out", fan_in(d), (d, d))
         with phase("sconv.in"):
             proj = checkpoint_name(
-                _dot(_rms_norm(x, norm, lm.norm_eps), w_in, self.dtype,
-                     self.dtype), SCONV_PROJ)
+                dot(rms_norm(x, norm, lm.norm_eps), w_in, self.dtype,
+                    self.dtype), SCONV_PROJ)
         with phase("sconv.core"):
             y = graph_short_conv(proj[:, :d], proj[:, d:2 * d],
                                  proj[:, 2 * d:], conv_w, node_gid,
                                  node_mask)
         with phase("sconv.out"):
-            return _dot(y, w_out, self.dtype)
+            return dot(y, w_out, self.dtype)
 
 
 class Attention(nn.Module):
@@ -201,31 +197,28 @@ class Attention(nn.Module):
         lm, d, hd = self.lm, self.lm.hidden_size, self.lm.head_dim
         heads, kv, n = lm.num_attention_heads, lm.num_key_value_heads, x.shape[0]
         norm = self.param("norm", nn.initializers.ones, (d,))
-        wq = self.param("wq", _init(d), (d, heads * hd))
-        wk = self.param("wk", _init(d), (d, kv * hd))
-        wv = self.param("wv", _init(d), (d, kv * hd))
+        wq = self.param("wq", fan_in(d), (d, heads * hd))
+        wk = self.param("wk", fan_in(d), (d, kv * hd))
+        wv = self.param("wv", fan_in(d), (d, kv * hd))
         q_norm = self.param("q_norm", nn.initializers.ones, (hd,))
         k_norm = self.param("k_norm", nn.initializers.ones, (hd,))
-        wo = self.param("wo", _init(heads * hd), (heads * hd, d))
+        wo = self.param("wo", fan_in(heads * hd), (heads * hd, d))
         with phase("attn.proj"):
-            u = _rms_norm(x, norm, lm.norm_eps)
+            u = rms_norm(x, norm, lm.norm_eps)
             # each head normed over its own channels, then rotated (the
             # reference's own function: float32 angles)
-            q = apply_rotary(_rms_norm(
-                _dot(u, wq, self.dtype).reshape(n, heads, hd), q_norm,
+            q = apply_rotary(rms_norm(
+                dot(u, wq, self.dtype).reshape(n, heads, hd), q_norm,
                 lm.norm_eps), positions, lm.rope_theta).astype(self.dtype)
-            k = apply_rotary(_rms_norm(
-                _dot(u, wk, self.dtype).reshape(n, kv, hd), k_norm,
+            k = apply_rotary(rms_norm(
+                dot(u, wk, self.dtype).reshape(n, kv, hd), k_norm,
                 lm.norm_eps), positions, lm.rope_theta).astype(self.dtype)
-            v = _dot(u, wv, self.dtype, self.dtype).reshape(n, kv, hd)
-        o = graph_attention(q, k, v, node_gid, node_mask,
-                            max_span=lm.max_graph_nodes,
-                            backend=self.backend, interpret=self.interpret)
-        blocks = (*scheduled_blocks(node_gid, node_mask,
-                                    max_span=lm.max_graph_nodes),
-                  kept_mb(q, k, v, KEEP_ATTN, backend=self.backend))
+            v = dot(u, wv, self.dtype, self.dtype).reshape(n, kv, hd)
+        o, blocks = attend(q, k, v, node_gid, node_mask, keep=KEEP_ATTN,
+                           max_span=lm.max_graph_nodes,
+                           backend=self.backend, interpret=self.interpret)
         with phase("attn.proj"):
-            return _dot(o.reshape(n, heads * hd), wo, self.dtype), blocks
+            return dot(o.reshape(n, heads * hd), wo, self.dtype), blocks
 
 
 class Experts(nn.Module):
@@ -240,12 +233,12 @@ class Experts(nn.Module):
         lm, share, d = self.lm, self.share, self.lm.hidden_size
         f, e = lm.moe_intermediate_size, share.experts_held
         norm = self.param("norm", nn.initializers.ones, (d,))
-        router = self.param("router", _init(d), (d, share.num_experts_total))
-        w1 = self.param("experts_w1", _init(d), (e, d, f))
-        w3 = self.param("experts_w3", _init(d), (e, d, f))
-        w2 = self.param("experts_w2", _init(f), (e, f, d))
+        router = self.param("router", fan_in(d), (d, share.num_experts_total))
+        w1 = self.param("experts_w1", fan_in(d), (e, d, f))
+        w3 = self.param("experts_w3", fan_in(d), (e, d, f))
+        w2 = self.param("experts_w2", fan_in(f), (e, f, d))
         return routed_experts(
-            _rms_norm(h, norm, lm.norm_eps), router, w1, w3, w2, share,
+            rms_norm(h, norm, lm.norm_eps), router, w1, w3, w2, share,
             node_mask=node_mask, top_k=lm.num_experts_per_tok,
             norm_topk=lm.norm_topk_prob, scale=lm.routed_scaling_factor,
             scoring=lm.router_scoring, bias=bias, compute_dtype=self.dtype,
@@ -269,9 +262,9 @@ class Lfm2Layer(nn.Module):
         keep: ``{"sconv": MB}`` on a conv layer, ``{"ffn": MB}`` on a dense
         one).  Each half is recomputed in the backward pass from its input
         and from what its checkpoint keeps by name: in bfloat16
-        (models/laguna.py where_narrow) the short convolution its input
+        (models/sequence.py where_narrow) the short convolution its input
         product (KEEP_SCONV) and the dense feed-forward, slice by slice,
-        its two up-products (models/laguna.py KEEP_FFN), 1.16 + 1.11 GB at
+        its two up-products (models/sequence.py KEEP_FFN), 1.16 + 1.11 GB at
         23,512 nodes, where the step needs 13.2 of the device's 16.9 GB;
         the expert half its router's decision (ops/moe.py KEEP_ROUTE); the
         attention half the kernel's result and log-sum-exp and q, k, v, 8
@@ -301,30 +294,15 @@ class Lfm2Layer(nn.Module):
         return h + y, stats, blocks, kept
 
 
-class Lfm2MoeStack(nn.Module):
-    """``cfg.lm`` / ``cfg.share`` carry the model; the trainer's contract
-    is the other stacks': ``model.apply(variables, batch, train=...)`` ->
-    a tuple with one output per head (here the logits [N, V held])."""
-
-    cfg: Any
-    attention_backend: Optional[str] = None
-    moe_backend: Optional[str] = None
-    interpret: bool = False
-
-    # as models/laguna.py LagunaStack: the stack casts for itself, shapes
-    # its parameters under jit, and leaves the in-run MFU estimate out
-    casts_at_boundary = False
-    jit_init = True
-    cost_model_sees_flops = False
+class Lfm2MoeStack(SequenceStack):
+    """One output: the logits [N, V held] for node ``i+1``'s id."""
 
     @nn.compact
     def __call__(self, g: GraphBatch, train: bool = True):
-        lm, share = self.cfg.lm, self.cfg.share
-        dtype = (jnp.bfloat16 if self.cfg.compute_dtype == "bfloat16"
-                 else jnp.float32)
+        lm, share, dtype = self.cfg.lm, self.cfg.share, self.compute_dtype
         # ONE table: the embedding's rows and, transposed, the head's
-        # columns (as a head's matrix it is ``_init(fan_in)``)
-        embed = self.param("embed", _init(lm.hidden_size),
+        # columns (as a head's matrix it is drawn by its fan-in)
+        embed = self.param("embed", fan_in(lm.hidden_size),
                            (share.vocab_rows, lm.hidden_size))
         biases = {name: self.variable(
             "batch_stats", f"bias_{name}", lambda: jnp.zeros(
@@ -349,8 +327,8 @@ class Lfm2MoeStack(nn.Module):
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (lm.hidden_size,))
         with phase("lm.head"):
-            logits = _dot(_rms_norm(x, final_norm, lm.norm_eps), embed.T,
-                          dtype)
+            logits = dot(rms_norm(x, final_norm, lm.norm_eps), embed.T,
+                         dtype)
         if biases:
             balance(self, biases, stats, train)
         if blocks:
@@ -361,20 +339,20 @@ class Lfm2MoeStack(nn.Module):
 
     def _count_convs(self, g, train):
         """What the short convolutions of this step met, summed over the
-        ``conv`` layers (all of them meet the same rows): ``sconv_rows``
-        (real rows), ``sconv_starts`` (graph starts: the step's real graphs
-        a layer), ``sconv_taps_cut`` (taps of real rows that read zero at a
-        boundary), kept as models/laguna.py ``count_routing`` keeps its
-        counters.  Counted from the batch by ``tap_reach``, the function
-        every layer's boundaries come from, not inside the layers: the
-        block says what the batch put to them, not what each one did."""
+        ``conv`` layers (all of them meet the same rows): real rows, graph
+        starts (the step's real graphs a layer), taps of real rows that
+        read zero at a boundary.  Counted from the batch by ``tap_reach``,
+        the function every layer's boundaries come from, not inside the
+        layers: the block says what the batch put to them, not what each
+        one did.  A method, because its name is the scope its operations
+        are found under."""
         lm = self.cfg.lm
         layers = lm.layer_types.count("conv")
-        cells = [self.variable("batch_stats", f"sconv_{k}",
-                               lambda: jnp.zeros((), jnp.float32))
-                 for k in ("rows", "starts", "taps_cut")]
-        if not layers or not train or self.is_initializing():
-            return
-        for cell, v in zip(cells, conv_counts(g.node_gid, g.node_mask,
-                                              lm.conv_L_cache)):
-            cell.value = layers * v
+        counters.keep(
+            self, "sconv", train and layers > 0,
+            ("rows", "starts", "taps_cut"),
+            lambda: (layers * v for v in conv_counts(
+                g.node_gid, g.node_mask, lm.conv_L_cache)))
+
+
+Config, Stack = Lfm2MoeConfig, Lfm2MoeStack

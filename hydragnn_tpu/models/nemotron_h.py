@@ -2,12 +2,9 @@
 nodes, experts in a latent space, one mixer or one feed-forward a layer:
 the twelfth stack.
 
-A document is a graph, a token a node, as in models/laguna.py, whose
-embedding, head, precision rules and counters this stack shares (float32
-parameters; with ``compute_dtype: bfloat16`` the matrix products take
-bfloat16 operands and accumulate in float32; residual stream, norms, router,
-decays, running sums, the carried state, softmax and loss float32).  What is
-its own:
+A document is a graph, a token a node (models/sequence.py, whose
+correction bias, precision rules and counters this stack reads; the decays,
+the running sums and the carried state are float32 too).  What is its own:
 
 * **A layer is ONE mixer**: ``x <- x + Mixer(RMSNorm(x))``, the mixer read
   from the pattern string (``hybrid_override_pattern`` as held: ``M``
@@ -22,7 +19,7 @@ its own:
 * **``*``**: grouped-query attention with NO positional term
   (``graph_attention``, ops/attention.py).
 * **``E``**: the router reads the hidden state (sigmoid scores under a
-  correction bias, models/glm_moe_lite.py's), the experts live in a
+  correction bias, models/sequence.py ``balance``), the experts live in a
   ``moe_latent_size``-wide space: ``routed_experts`` dispatches the latent
   rows to ungated ``relu^2`` experts (ops/moe.py ``rows=``, ``expert=``);
   the shared expert reads and writes the hidden space.
@@ -50,25 +47,22 @@ import jax
 import jax.numpy as jnp
 
 from hydragnn_tpu.graph.batch import GraphBatch
-from hydragnn_tpu.models.glm_moe_lite import balance
-from hydragnn_tpu.models.laguna import (
-    _dot,
-    _init,
-    _rms_norm,
+from hydragnn_tpu.models.sequence import (
+    SequenceStack,
+    attend,
+    balance,
     count_blocks,
+    dot,
+    fan_in,
     ids_and_positions,
+    rms_norm,
 )
-from hydragnn_tpu.ops.attention import (
-    KEEP_ATTN,
-    graph_attention,
-    kept_mb,
-    scheduled_blocks,
-)
+from hydragnn_tpu.ops.attention import KEEP_ATTN
 from hydragnn_tpu.ops.moe import KEEP_ROUTE, routed_experts
 from hydragnn_tpu.ops.ssm import graph_causal_conv, graph_ssm, scan_counts
 from hydragnn_tpu.parallel.share import LayerShare
+from hydragnn_tpu.telemetry import counters
 from hydragnn_tpu.utils.scope import phase
-
 
 
 class Backends(NamedTuple):
@@ -108,6 +102,7 @@ class NemotronHConfig:
     routed_scaling_factor: float
     max_graph_nodes: Optional[int] = None
     router_scoring: ClassVar[str] = "sigmoid"     # ops/moe.py route
+    experts_key: ClassVar[str] = "n_routed_experts"     # parallel/share.py
 
     @staticmethod
     def from_arch(arch: Dict[str, Any]) -> "NemotronHConfig":
@@ -218,7 +213,7 @@ class Mamba2(nn.Module):
         inner, conv = heads * hd, heads * hd + 2 * groups * state
         n = x.shape[0]
         norm = self.param("norm", nn.initializers.ones, (d,))
-        in_proj = self.param("in_proj", _init(d), (d, inner + conv + heads))
+        in_proj = self.param("in_proj", fan_in(d), (d, inner + conv + heads))
         conv_w = self.param(
             "conv_w", lambda k, s: jax.random.uniform(
                 k, s, jnp.float32, -taps ** -0.5, taps ** -0.5),
@@ -228,10 +223,10 @@ class Mamba2(nn.Module):
         skip = self.param("D", nn.initializers.ones, (heads,))
         dt_bias = self.param("dt_bias", _dt_bias_init(lm), (heads,))
         gate_norm = self.param("gate_norm", nn.initializers.ones, (inner,))
-        out_proj = self.param("out_proj", _init(inner), (inner, d))
+        out_proj = self.param("out_proj", fan_in(inner), (inner, d))
         with phase("ssm.in"):
-            proj = _dot(_rms_norm(x, norm, lm.layer_norm_epsilon), in_proj,
-                        self.dtype)
+            proj = dot(rms_norm(x, norm, lm.layer_norm_epsilon), in_proj,
+                       self.dtype)
             z, xbc, dt = (proj[:, :inner], proj[:, inner:inner + conv],
                           proj[:, inner + conv:])
         with phase("ssm.conv"):
@@ -253,7 +248,7 @@ class Mamba2(nn.Module):
                 + lm.layer_norm_epsilon)
             y = g.reshape(n, inner) * gate_norm
         with phase("ssm.out"):
-            return x + _dot(y, out_proj, self.dtype), None, None
+            return x + dot(y, out_proj, self.dtype), None, None
 
 
 class Attention(nn.Module):
@@ -267,23 +262,20 @@ class Attention(nn.Module):
         lm, d, hd = self.lm, self.lm.hidden_size, self.lm.head_dim
         heads, kv, n = lm.num_attention_heads, lm.num_key_value_heads, x.shape[0]
         norm = self.param("norm", nn.initializers.ones, (d,))
-        wq = self.param("wq", _init(d), (d, heads * hd))
-        wk = self.param("wk", _init(d), (d, kv * hd))
-        wv = self.param("wv", _init(d), (d, kv * hd))
-        wo = self.param("wo", _init(heads * hd), (heads * hd, d))
+        wq = self.param("wq", fan_in(d), (d, heads * hd))
+        wk = self.param("wk", fan_in(d), (d, kv * hd))
+        wv = self.param("wv", fan_in(d), (d, kv * hd))
+        wo = self.param("wo", fan_in(heads * hd), (heads * hd, d))
         with phase("attn.proj"):
-            u = _rms_norm(x, norm, lm.layer_norm_epsilon)
-            q, k, v = (_dot(u, w, self.dtype, self.dtype).reshape(n, h, hd)
+            u = rms_norm(x, norm, lm.layer_norm_epsilon)
+            q, k, v = (dot(u, w, self.dtype, self.dtype).reshape(n, h, hd)
                        for w, h in ((wq, heads), (wk, kv), (wv, kv)))
-        o = graph_attention(q, k, v, node_gid, node_mask,
-                            max_span=lm.max_graph_nodes,
-                            backend=self.backends.attention,
-                            interpret=self.backends.interpret)
-        blocks = (*scheduled_blocks(node_gid, node_mask,
-                                    max_span=lm.max_graph_nodes),
-                  kept_mb(q, k, v, KEEP, backend=self.backends.attention))
+        o, blocks = attend(q, k, v, node_gid, node_mask, keep=KEEP,
+                           max_span=lm.max_graph_nodes,
+                           backend=self.backends.attention,
+                           interpret=self.backends.interpret)
         with phase("attn.proj"):
-            return (x + _dot(o.reshape(n, heads * hd), wo, self.dtype), None,
+            return (x + dot(o.reshape(n, heads * hd), wo, self.dtype), None,
                     blocks)
 
 
@@ -299,16 +291,16 @@ class LatentMoE(nn.Module):
         f, fs = lm.moe_intermediate_size, lm.moe_shared_expert_intermediate_size
         e, lat = share.experts_held, lm.moe_latent_size
         norm = self.param("norm", nn.initializers.ones, (d,))
-        router = self.param("router", _init(d), (d, share.num_experts_total))
-        down = self.param("down", _init(d), (d, lat))
-        w1 = self.param("experts_w1", _init(lat), (e, lat, f))
-        w2 = self.param("experts_w2", _init(f), (e, f, lat))
-        up = self.param("up", _init(lat), (lat, d))
-        s1 = self.param("shared_w1", _init(d), (d, fs))
-        s2 = self.param("shared_w2", _init(fs), (fs, d))
-        u = _rms_norm(x, norm, lm.layer_norm_epsilon)
+        router = self.param("router", fan_in(d), (d, share.num_experts_total))
+        down = self.param("down", fan_in(d), (d, lat))
+        w1 = self.param("experts_w1", fan_in(lat), (e, lat, f))
+        w2 = self.param("experts_w2", fan_in(f), (e, f, lat))
+        up = self.param("up", fan_in(lat), (lat, d))
+        s1 = self.param("shared_w1", fan_in(d), (d, fs))
+        s2 = self.param("shared_w2", fan_in(fs), (fs, d))
+        u = rms_norm(x, norm, lm.layer_norm_epsilon)
         with phase("moe.latent"):
-            latent = _dot(u, down, self.dtype, self.dtype)
+            latent = dot(u, down, self.dtype, self.dtype)
         routed, stats = routed_experts(
             u, router, w1, None, w2, share, node_mask=node_mask,
             top_k=lm.num_experts_per_tok, norm_topk=lm.norm_topk_prob,
@@ -316,14 +308,14 @@ class LatentMoE(nn.Module):
             bias=bias, compute_dtype=self.dtype, backend=self.backends.moe,
             interpret=self.backends.interpret, rows=latent, expert="relu2")
         with phase("moe.latent"):
-            y = _dot(routed, up, self.dtype)
+            y = dot(routed, up, self.dtype)
         with phase("moe.shared"):
             # the hidden product leaves the MXU rounded to ``dtype`` (float32
-            # accumulation inside), as models/laguna.py's feed-forward does
-            h = jnp.square(jax.nn.relu(_dot(u, s1, self.dtype, self.dtype)
+            # accumulation inside), as models/sequence.py's feed-forward does
+            h = jnp.square(jax.nn.relu(dot(u, s1, self.dtype, self.dtype)
                                        .astype(jnp.float32))
                            ).astype(self.dtype)
-            return x + y + _dot(h, s2, self.dtype), stats, None
+            return x + y + dot(h, s2, self.dtype), stats, None
 
 
 LAYERS = {"M": Mamba2, "E": LatentMoE, "*": Attention}
@@ -376,29 +368,15 @@ class Unit(nn.Module):
         return x, stats
 
 
-class NemotronHStack(nn.Module):
-    """``cfg.lm`` / ``cfg.share`` carry the model; the trainer's contract
-    is the other stacks': ``model.apply(variables, batch, train=...)`` ->
-    a tuple with one output per head (here the logits [N, V held])."""
+class NemotronHStack(SequenceStack):
+    """One output: the logits [N, V held] for node ``i+1``'s id."""
 
-    cfg: Any
-    attention_backend: Optional[str] = None
-    moe_backend: Optional[str] = None
     ssm_backend: Optional[str] = None
-    interpret: bool = False
-
-    # as models/laguna.py LagunaStack: the stack casts for itself, shapes
-    # its parameters under jit, and leaves the in-run MFU estimate out
-    casts_at_boundary = False
-    jit_init = True
-    cost_model_sees_flops = False
 
     @nn.compact
     def __call__(self, g: GraphBatch, train: bool = True):
-        lm, share = self.cfg.lm, self.cfg.share
+        lm, share, dtype = self.cfg.lm, self.cfg.share, self.compute_dtype
         pattern = lm.hybrid_override_pattern
-        dtype = (jnp.bfloat16 if self.cfg.compute_dtype == "bfloat16"
-                 else jnp.float32)
         backends = Backends(self.attention_backend, self.moe_backend,
                             self.ssm_backend, self.interpret)
         embed = self.param("embed", nn.initializers.normal(stddev=1.0),
@@ -440,11 +418,11 @@ class NemotronHStack(nn.Module):
                     lambda a, k=k: a[k // per_unit], scanned[k % per_unit])
         final_norm = self.param("final_norm", nn.initializers.ones,
                                 (lm.hidden_size,))
-        head = self.param("head", _init(lm.hidden_size),
+        head = self.param("head", fan_in(lm.hidden_size),
                           (lm.hidden_size, share.vocab_rows))
         with phase("lm.head"):
-            logits = _dot(_rms_norm(x, final_norm, lm.layer_norm_epsilon),
-                          head, dtype)
+            logits = dot(rms_norm(x, final_norm, lm.layer_norm_epsilon),
+                         head, dtype)
         if biases:
             balance(self, biases, stats, train)
         if blocks:
@@ -455,14 +433,13 @@ class NemotronHStack(nn.Module):
 
     def _count_scan(self, g, train):
         """What ONE state-space layer's scan walks this step (all of them
-        walk the same chunks): ``ssm_chunks``, ``ssm_chunks_padding`` (no
-        real node), ``ssm_resets`` (graph starts: the step's real graphs),
-        kept as models/laguna.py ``count_routing`` keeps its counters."""
-        cells = [self.variable("batch_stats", f"ssm_{k}",
-                               lambda: jnp.zeros((), jnp.float32))
-                 for k in ("chunks", "chunks_padding", "resets")]
-        if not train or self.is_initializing():
-            return
-        for cell, v in zip(cells, scan_counts(
-                g.node_gid, g.node_mask, self.cfg.lm.chunk_size)):
-            cell.value = v
+        walk the same chunks): chunks, those with no real node, and graph
+        starts (the step's real graphs).  A method, because its name is the
+        scope its operations are found under."""
+        counters.keep(
+            self, "ssm", train, ("chunks", "chunks_padding", "resets"),
+            lambda: scan_counts(g.node_gid, g.node_mask,
+                                self.cfg.lm.chunk_size))
+
+
+Config, Stack = NemotronHConfig, NemotronHStack

@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from hydragnn_tpu.telemetry import pipeline, programs
+from hydragnn_tpu.telemetry import counters, pipeline, programs
 from hydragnn_tpu.telemetry.flops import (
     mfu_pct,
     peak_flops,
@@ -594,56 +594,8 @@ class MetricsLogger:
                 "edges_waste_pct": waste_pct(edges_real, pad["padded_edges"]),
                 "graphs_waste_pct": waste_pct(ng, pad["padded_graphs"]),
             }
-            if "moe_slots_all" in m:
-                # the expert layers' routing, summed over layers and over
-                # the steps of the dispatch (ops/moe.py stats)
-                rec["moe"] = {
-                    "slots_held": float(m["moe_slots_held"]),
-                    "slots_all": float(m["moe_slots_all"]),
-                    "load_max_over_mean": float(
-                        m["moe_load_max_over_mean"]),
-                    "dense_steps": float(m["moe_dense_steps"]),
-                }
-                # a router under a correction bias (models/glm_moe_lite.py)
-                for k in ("load_all_max_over_mean", "bias_abs_max"):
-                    if f"moe_{k}" in m:
-                        rec["moe"][k] = float(m[f"moe_{k}"])
-            if "attn_blocks_band" in m:
-                # the attention kernels' block schedule, summed over the
-                # layers' forward calls and the steps of the dispatch
-                # (ops/attention.py scheduled_blocks), and the MB the
-                # attention halves' checkpoints keep in ONE step, summed
-                # over the layers (ops/attention.py kept_mb)
-                rec["attention"] = {
-                    "blocks_run": float(m["attn_blocks_run"]),
-                    "blocks_band": float(m["attn_blocks_band"]),
-                    "kept_mb": float(m["attn_kept_mb"]),
-                }
-            if "ssm_chunks" in m:
-                # what ONE state-space layer's scan walked, summed over
-                # the steps of the dispatch (ops/ssm.py scan_counts)
-                rec["ssm"] = {
-                    "chunks": float(m["ssm_chunks"]),
-                    "chunks_padding": float(m["ssm_chunks_padding"]),
-                    "resets": float(m["ssm_resets"]),
-                }
-            if "sconv_rows" in m:
-                # what the short convolutions met, summed over the conv
-                # layers and the steps of the dispatch (ops/sconv.py
-                # conv_counts), and the MB their checkpoints keep in ONE
-                # step, summed over the conv layers (models/lfm2_moe.py
-                # KEEP_SCONV)
-                rec["sconv"] = {
-                    "rows": float(m["sconv_rows"]),
-                    "starts": float(m["sconv_starts"]),
-                    "taps_cut": float(m["sconv_taps_cut"]),
-                    "kept_mb": float(m["sconv_kept_mb"]),
-                }
-            if "ffn_kept_mb" in m:
-                # the MB the dense feed-forwards' checkpoints keep in ONE
-                # step, summed over the dense layers (models/laguna.py
-                # KEEP_FFN)
-                rec["ffn"] = {"kept_mb": float(m["ffn_kept_mb"])}
+            # what the model counted (telemetry/counters.py)
+            rec.update(counters.record_blocks(m))
             fl = self._flops_for(sig)
             if fl:
                 rec["flops_per_dispatch"] = fl

@@ -33,6 +33,7 @@ from flax import struct
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.base import Base, ModelConfig, multihead_loss
+from hydragnn_tpu.telemetry import counters
 from hydragnn_tpu.train.optimizer import (
     OptimizerSpec,
     get_learning_rate,
@@ -236,13 +237,11 @@ def step_telemetry_metrics(g: GraphBatch, grads, new_params,
 
 def model_counters(batch_stats) -> Dict[str, jax.Array]:
     """What the model counted in this step for the step records: the
-    top-level ``moe_*``, ``attn_*``, ``ssm_*``, ``sconv_*`` and ``ffn_*``
-    scalars a stack keeps in ``batch_stats`` (models/laguna.py
-    count_routing, count_blocks, count_kept; models/nemotron_h.py;
-    models/lfm2_moe.py); {} for every other stack."""
+    top-level scalars of ``batch_stats`` that telemetry/counters.py names
+    (a stack keeps them there by its ``keep``); {} for a stack that counts
+    nothing."""
     return {k: v for k, v in batch_stats.items()
-            if k.startswith(("moe_", "attn_", "ssm_", "sconv_", "ffn_"))
-            and getattr(v, "ndim", None) == 0}
+            if counters.rule(k) and getattr(v, "ndim", None) == 0}
 
 
 def make_train_step(
@@ -323,17 +322,11 @@ def make_train_step(
     return train_step
 
 
-# metric keys that are COUNTS over the dispatch (summed across the K
-# scanned steps); every other scalar merges as a graph-weighted mean
-# ("skipped" counts guard-suppressed steps within the dispatch)
-_COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped",
-                      "moe_slots_held", "moe_slots_all", "moe_dense_steps",
-                      "attn_blocks_run", "attn_blocks_band",
-                      "ssm_chunks", "ssm_chunks_padding", "ssm_resets",
-                      "sconv_rows", "sconv_starts", "sconv_taps_cut")
-# a number of the dispatch's shape, the same on each of the K steps: the
-# merge hands it on as it is
-_STATIC_METRIC_KEYS = ("attn_kept_mb", "sconv_kept_mb", "ffn_kept_mb")
+# the trainer's own metric keys that are COUNTS over the dispatch (summed
+# across the K scanned steps; "skipped" counts guard-suppressed steps
+# within the dispatch).  A model counter merges by its rule in
+# telemetry/counters.py; every other scalar as a graph-weighted mean
+_COUNT_METRIC_KEYS = ("num_graphs", "nodes_real", "edges_real", "skipped")
 
 
 def merge_scanned_metrics(ms):
@@ -341,14 +334,16 @@ def merge_scanned_metrics(ms):
     multi-step train step — same epoch-accumulation semantics as K separate
     dispatches (one definition shared by the local and mesh scan paths).
     Counts (graphs/nodes/edges consumed) sum over the K steps; losses and
-    the telemetry norms merge graph-weighted; a static number stays."""
+    the telemetry norms merge graph-weighted; a number of the dispatch's
+    shape, the same on each of the K steps, is handed on as it is."""
     ng = ms["num_graphs"]
     total = jnp.maximum(jnp.sum(ng), 1.0)
     merged = {}
     for k, v in ms.items():
-        if k in _COUNT_METRIC_KEYS:
+        how = counters.SUM if k in _COUNT_METRIC_KEYS else counters.rule(k)
+        if how == counters.SUM:
             merged[k] = jnp.sum(v)
-        elif k in _STATIC_METRIC_KEYS:
+        elif how == counters.SAME:
             merged[k] = v[0]
         else:
             merged[k] = jnp.sum(v * ng) / total

@@ -22,10 +22,8 @@ from hydragnn_tpu.graph.batch import GraphSample, HeadSpec, PadSpec, collate
 from hydragnn_tpu.models import glm_moe_lite_reference as R
 from hydragnn_tpu.models.base import ModelConfig
 from hydragnn_tpu.models.create import create_model
-from hydragnn_tpu.models.glm_moe_lite import (
-    BIAS_UPDATE_SPEED,
-    GlmMoeLiteConfig,
-)
+from hydragnn_tpu.models.glm_moe_lite import GlmMoeLiteConfig
+from hydragnn_tpu.models.sequence import BIAS_UPDATE_SPEED
 from hydragnn_tpu.ops import attention, moe
 from hydragnn_tpu.parallel.share import LayerShare
 from hydragnn_tpu.train.trainer import _loss_and_metrics

@@ -22,7 +22,7 @@ from hydragnn_tpu.graph.batch import GraphSample, HeadSpec, PadSpec, collate
 from hydragnn_tpu.models import nemotron_h_reference as R
 from hydragnn_tpu.models.base import ModelConfig
 from hydragnn_tpu.models.create import create_model
-from hydragnn_tpu.models.glm_moe_lite import BIAS_UPDATE_SPEED
+from hydragnn_tpu.models.sequence import BIAS_UPDATE_SPEED
 from hydragnn_tpu.models.nemotron_h import (
     NemotronHConfig,
     layer_trees,

@@ -1,14 +1,14 @@
 """Wide products run once a step (PR 42): the checkpoint round a short
 convolution keeps its input product (models/lfm2_moe.py ``KEEP_SCONV``) and
 the checkpoint of each slice of a dense feed-forward its two up-products
-(models/laguna.py ``KEEP_FFN``, where the layer hands it over: Laguna's and
+(models/sequence.py ``KEEP_FFN``, where the layer hands it over: Laguna's and
 LFM2's do, latent attention's stack does not), so the half's gradient holds
 ONE such product where the bare checkpoint held two, the residuals grow by
 exactly the named arrays, the loss and every gradient are the bare
 checkpoint's to the last bit, and the step records' ``sconv.kept_mb`` /
 ``ffn.kept_mb`` are the bytes of exactly those arrays.  All of it where
 the products leave the MXU in 2 bytes a value: in float32 the layers keep
-nothing of them (models/laguna.py ``where_narrow``).  CPU, float32 and
+nothing of them (models/sequence.py ``where_narrow``).  CPU, float32 and
 bfloat16: 48 node slots (four slices of 12), three graphs of 20, 17 and 5
 nodes and 6 padding nodes."""
 
@@ -29,7 +29,7 @@ import test_lfm2_moe as lfm2
 from test_laguna import _eqns
 
 from hydragnn_tpu.graph.batch import HeadSpec, PadSpec, collate
-from hydragnn_tpu.models import glm_moe_lite, lfm2_moe
+from hydragnn_tpu.models import glm_moe_lite, lfm2_moe, sequence
 from hydragnn_tpu.models import laguna as laguna_model
 from hydragnn_tpu.models.base import ModelConfig
 from hydragnn_tpu.models.create import create_model
@@ -43,7 +43,7 @@ from hydragnn_tpu.train.trainer import (
 )
 
 GRAPHS, SLOTS, HIDDEN = (20, 17, 5), 48, 32
-CHUNKS = laguna_model.DENSE_CHUNKS
+CHUNKS = sequence.DENSE_CHUNKS
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
 
@@ -180,7 +180,7 @@ def test_the_half_keeps_its_product_and_runs_it_once(layer, site, dtype,
                                                      monkeypatch):
     """One site at a time: its policy taken away, the layer's other
     checkpoints as they are.  In float32 the layer hands the policy to no
-    checkpoint (models/laguna.py where_narrow: 4 bytes a value), and the
+    checkpoint (models/sequence.py where_narrow: 4 bytes a value), and the
     half is the bare checkpoint's."""
     build, sites = LAYERS[layer]
     fn, args, lm = _half(build, dtype)
@@ -218,11 +218,11 @@ def test_the_half_keeps_its_product_and_runs_it_once(layer, site, dtype,
 
 
 def test_where_narrow_hands_the_policy_on_at_two_bytes_a_value():
-    keep = laguna_model.KEEP_FFN
-    assert laguna_model.where_narrow(keep, jnp.bfloat16) is keep
-    assert laguna_model.where_narrow(keep, jnp.float16) is keep
-    assert laguna_model.where_narrow(keep, jnp.float32) is None
-    assert laguna_model.where_narrow(None, jnp.bfloat16) is None
+    keep = sequence.KEEP_FFN
+    assert sequence.where_narrow(keep, jnp.bfloat16) is keep
+    assert sequence.where_narrow(keep, jnp.float16) is keep
+    assert sequence.where_narrow(keep, jnp.float32) is None
+    assert sequence.where_narrow(None, jnp.bfloat16) is None
 
 
 @pytest.mark.parametrize("dtype", list(DTYPES))
@@ -237,34 +237,34 @@ def test_latent_attentions_dense_layer_keeps_nothing(dtype):
     assert not any(shape in arrays for shape, _ in kept), kept
     out = jax.eval_shape(fn, *args)
     assert len(out[1]) == 3         # attention's blocks: no dict of MB
-    assert laguna_model.DenseFFN(lm, DTYPES[dtype]).policy is None
+    assert sequence.DenseFFN(lm, DTYPES[dtype]).policy is None
 
 
 def test_the_policies_keep_the_names_they_say():
     ask = attention._name_primitive()
-    names = (lfm2_moe.SCONV_PROJ, laguna_model.FFN_H1, laguna_model.FFN_H3,
+    names = (lfm2_moe.SCONV_PROJ, sequence.FFN_H1, sequence.FFN_H3,
              ROUTE_LOGITS, ROUTE_IDS, attention.ATTN_Q, attention.ATTN_K,
              attention.ATTN_V, attention.ATTN_OUT)
     kept = {key: {n for n in names if p(ask, name=n)}
             for key, p in (("sconv", lfm2_moe.KEEP_SCONV),
-                           ("ffn", laguna_model.KEEP_FFN),
+                           ("ffn", sequence.KEEP_FFN),
                            ("route", KEEP_ROUTE),
                            ("attn", attention.KEEP_ATTN))}
     assert kept["sconv"] == {"sconv.in.proj"}
     assert kept["ffn"] == {"ffn.dense.h1", "ffn.dense.h3"}
     assert kept["route"] == {ROUTE_LOGITS, ROUTE_IDS}
     assert kept["attn"] == set(names[5:])
-    assert lfm2_moe.KEEP_FFN is laguna_model.KEEP_FFN
+    assert lfm2_moe.KEEP_FFN is laguna_model.KEEP_FFN is sequence.KEEP_FFN
     assert not hasattr(glm_moe_lite, "KEEP_FFN")
 
 
 def test_named_mb_asks_the_policy_for_each_name():
     a = jax.ShapeDtypeStruct((100, 30), jnp.bfloat16)
-    named = {lfm2_moe.SCONV_PROJ: a, laguna_model.FFN_H1: a,
+    named = {lfm2_moe.SCONV_PROJ: a, sequence.FFN_H1: a,
              attention.ATTN_OUT: 1000}
     assert attention.named_mb(None, named) == 0.0
     assert attention.named_mb(lfm2_moe.KEEP_SCONV, named) == 6000 / 1e6
-    assert attention.named_mb(laguna_model.KEEP_FFN, named) == 6000 / 1e6
+    assert attention.named_mb(sequence.KEEP_FFN, named) == 6000 / 1e6
     assert attention.named_mb(attention.KEEP_ATTN_OUT, named) == 1000 / 1e6
     assert attention.named_mb(KEEP_ROUTE, named) == 0.0
 

@@ -349,7 +349,7 @@ def test_step_records_carry_the_attention_schedule_of_a_language_model(
     block (ops/sconv.py conv_counts, summed over the conv layers, and the
     MB those layers' checkpoints keep); no other stack has either.  A stack
     whose dense feed-forward keeps its up-products says how many MB in an
-    ``ffn`` block (models/laguna.py KEEP_FFN)."""
+    ``ffn`` block (models/sequence.py KEEP_FFN)."""
     if stack == "sage":
         cfg, (batch, _pad, _s), layers = _cfg(), _batch(), 0
     else:
