@@ -765,6 +765,22 @@ _SCOPE_LIST = [
         "boundaries and the gate C * v (ops/sconv.py graph_short_conv)"),
     _sc("sconv.out", "hydragnn_tpu/models/lfm2_moe.py",
         "a short-convolution operator's output product"),
+    # Gated DeltaNet (models/qwen3_next.py)
+    _sc("gdn.in", "hydragnn_tpu/models/qwen3_next.py",
+        "a Gated DeltaNet layer's zero-centred norm and its two input "
+        "products to [q | k | v | z] and [b | a]"),
+    _sc("gdn.conv", "hydragnn_tpu/models/qwen3_next.py",
+        "the depthwise causal convolution of [q | k | v] that stops at "
+        "graph boundaries, and its silu"),
+    _sc("gdn.scan", "hydragnn_tpu/ops/gdn.py",
+        "the gated delta rule over each graph's nodes: q's and k's l2 "
+        "norms, g and beta, the chunked form's products, decays and unit "
+        "lower-triangular inverse and the walk over chunk states (or the "
+        "sequential twin)"),
+    _sc("gdn.norm", "hydragnn_tpu/models/qwen3_next.py",
+        "the RMS norm of each head's result under the gate silu(z)"),
+    _sc("gdn.out", "hydragnn_tpu/models/qwen3_next.py",
+        "a Gated DeltaNet layer's output product"),
 ]
 
 SCOPE_NAMES: Dict[str, ScopeName] = {s.name: s for s in _SCOPE_LIST}

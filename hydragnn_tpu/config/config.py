@@ -69,7 +69,8 @@ EDGE_MODELS = ["PNA", "CGCNN", "SchNet", "EGNN"]
 # its module under hydragnn_tpu/models/ (``Config``, ``Stack``;
 # models/sequence.py says what a new one brings)
 SEQUENCE_MODELS = {"Laguna": "laguna", "GlmMoeLite": "glm_moe_lite",
-                   "NemotronH": "nemotron_h", "Lfm2Moe": "lfm2_moe"}
+                   "NemotronH": "nemotron_h", "Lfm2Moe": "lfm2_moe",
+                   "Qwen3Next": "qwen3_next"}
 EQUIVARIANT_MODELS = ["EGNN", "SchNet"]
 ALL_MODEL_TYPES = [
     "SAGE",

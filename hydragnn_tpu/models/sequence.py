@@ -1,6 +1,6 @@
 """What two or more of the sequence stacks share (config/config.py
 ``SEQUENCE_MODELS``: models/laguna.py, glm_moe_lite.py, nemotron_h.py,
-lfm2_moe.py): language models over each graph's nodes.  A document is a
+lfm2_moe.py, qwen3_next.py): language models over each graph's nodes.  A document is a
 graph, a token a node, the nodes of a graph contiguous (graph/batch.py
 collate); node ``i``'s position is its index inside its graph, and the
 node input is an integer id (``g.x[:, 0]``, exact in float32).
@@ -217,13 +217,19 @@ class MoE(nn.Module):
     it reads ``hidden_size``, ``moe_intermediate_size``,
     ``shared_expert_intermediate_size``, ``rms_norm_eps``,
     ``num_experts_per_tok``, ``norm_topk_prob``,
-    ``moe_routed_scaling_factor`` and ``router_scoring``."""
+    ``moe_routed_scaling_factor`` and ``router_scoring``.
+    ``zero_centred``: the norm's parameter is ``w`` of the scale ``1 + w``
+    and starts at 0.  ``shared_gate``: the shared experts' result is
+    multiplied by ``sigmoid(u w_sg)`` of a ``hidden_size -> 1`` product
+    (both: models/qwen3_next.py)."""
 
     lm: Any
     share: LayerShare
     dtype: Any
     backend: Optional[str]
     interpret: bool
+    zero_centred: bool = False
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, h, node_mask, bias=None):
@@ -232,7 +238,8 @@ class MoE(nn.Module):
         lm, share, d = self.lm, self.share, self.lm.hidden_size
         f, fs = lm.moe_intermediate_size, lm.shared_expert_intermediate_size
         e = share.experts_held
-        norm = self.param("norm", nn.initializers.ones, (d,))
+        norm = self.param("norm", nn.initializers.zeros if self.zero_centred
+                          else nn.initializers.ones, (d,))
         router = self.param("router", fan_in(d),
                             (d, share.num_experts_total))
         w1 = self.param("experts_w1", fan_in(d), (e, d, f))
@@ -241,7 +248,8 @@ class MoE(nn.Module):
         s1 = self.param("shared_w1", fan_in(d), (d, fs))
         s3 = self.param("shared_w3", fan_in(d), (d, fs))
         s2 = self.param("shared_w2", fan_in(fs), (fs, d))
-        u = rms_norm(h, norm, lm.rms_norm_eps)
+        u = rms_norm(h, 1.0 + norm if self.zero_centred else norm,
+                     lm.rms_norm_eps)
         y, stats = routed_experts(
             u, router, w1, w3, w2, share, node_mask=node_mask,
             top_k=lm.num_experts_per_tok, norm_topk=lm.norm_topk_prob,
@@ -249,7 +257,11 @@ class MoE(nn.Module):
             bias=bias, compute_dtype=self.dtype, backend=self.backend,
             interpret=self.interpret)
         with phase("moe.shared"):
-            return y + _gated_mlp(u, s1, s3, s2, self.dtype), stats
+            out = _gated_mlp(u, s1, s3, s2, self.dtype)
+            if self.shared_gate:
+                out = out * jax.nn.sigmoid(dot(u, self.param(
+                    "shared_gate", fan_in(d), (d, 1)), self.dtype))
+            return y + out, stats
 
 
 def balance(stack: nn.Module, biases, stats, train):

@@ -86,16 +86,19 @@ def graph_causal_conv(x, w, b, node_gid, node_mask=None):
 
     ``x`` [N, C], ``w`` [K, C] (``w[K - 1]`` multiplies the node itself,
     ``w[0]`` the node K - 1 back: torch's ``Conv1d`` weight ``[C, 1, K]``
-    under left padding K - 1), ``b`` [C].  Float32."""
+    under left padding K - 1), ``b`` [C] or None (a convolution without
+    bias: models/qwen3_next.py).  Float32."""
     n, taps = x.shape[0], w.shape[0]
     real = _real(node_mask, n)
     x = x.astype(jnp.float32)
-    out = jnp.broadcast_to(b.astype(jnp.float32), x.shape)
+    out = None if b is None else jnp.broadcast_to(b.astype(jnp.float32),
+                                                  x.shape)
     for lag in range(taps):
         same = real & jnp.pad(real, (lag, 0))[:n] & (
             jnp.pad(node_gid, (lag, 0), constant_values=-1)[:n] == node_gid)
         back = jnp.pad(x, ((lag, 0), (0, 0)))[:n]
-        out = out + jnp.where(same[:, None], back, 0.0) * w[taps - 1 - lag]
+        tap = jnp.where(same[:, None], back, 0.0) * w[taps - 1 - lag]
+        out = tap if out is None else out + tap
     return out
 
 

@@ -51,6 +51,12 @@ BLOCKS = {
     # step (models/lfm2_moe.py KEEP_SCONV)
     "sconv": Block("sconv_", {"rows": SUM, "starts": SUM, "taps_cut": SUM,
                               "kept_mb": SAME}),
+    # what the gated delta rule walked, summed over the DeltaNet layers
+    # (ops/ssm.py scan_counts at the rule's chunk; ``resets``: graph starts
+    # a layer), and the MB their checkpoints keep in ONE step
+    # (models/qwen3_next.py KEEP_GDN)
+    "gdn": Block("gdn_", {"chunks": SUM, "chunks_padding": SUM,
+                          "resets": SUM, "kept_mb": SAME}),
     # the MB the dense feed-forwards' checkpoints keep in ONE step, summed
     # over the dense layers (models/sequence.py KEEP_FFN)
     "ffn": Block("ffn_", {"kept_mb": SAME}),

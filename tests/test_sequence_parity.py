@@ -1,12 +1,14 @@
-"""The four sequence stacks' programs are the ones of commit 532a60a (PR 42,
-the parent of the PR that moved their shared layers to models/sequence.py
-and their counters to telemetry/counters.py): the traced gradient program
-with its scope names, the seeded initial values and the step record's model
-blocks are pinned by value, so that a move of shared code that changes any
-of them fails here and not as a moved rate in a benchmark cell.
+"""The four older sequence stacks' programs are the ones of commit 532a60a
+(PR 42, the parent of the PR that moved their shared layers to
+models/sequence.py and their counters to telemetry/counters.py), and the
+fifth's (models/qwen3_next.py) the ones of the PR that brought it (44): the
+traced gradient program with its scope names, the seeded initial values and
+the step record's model blocks are pinned by value, so that a move of
+shared code that changes any of them fails here and not as a moved rate in
+a benchmark cell.
 
 The digests were printed by this file (``python tests/test_sequence_parity.py``)
-on 532a60a, on this container's jax 0.9.0.  A jax upgrade changes how jaxprs
+on 532a60a (``qwen3_next``'s on PR 44's tree), on this container's jax 0.9.0.  A jax upgrade changes how jaxprs
 print and may change what the initialisers draw: regenerate from a commit
 whose programs are known to be good, never from the tree under test.
 """
@@ -26,6 +28,7 @@ import test_glm_moe_lite
 import test_laguna
 import test_lfm2_moe
 import test_nemotron_h
+import test_qwen3_next
 from hydragnn_tpu.graph.batch import HeadSpec, PadSpec, collate
 from hydragnn_tpu.models.base import ModelConfig
 from hydragnn_tpu.models.create import create_model
@@ -38,9 +41,10 @@ from hydragnn_tpu.train.trainer import (
 )
 
 STACKS = {"laguna": test_laguna, "glm_moe_lite": test_glm_moe_lite,
-          "nemotron_h": test_nemotron_h, "lfm2_moe": test_lfm2_moe}
+          "nemotron_h": test_nemotron_h, "lfm2_moe": test_lfm2_moe,
+          "qwen3_next": test_qwen3_next}
 DTYPES = ("float32", "bfloat16")
-BLOCKS = ("moe", "attention", "ssm", "sconv", "ffn")
+BLOCKS = ("moe", "attention", "ssm", "sconv", "ffn", "gdn")
 
 
 @functools.lru_cache(maxsize=None)
@@ -117,9 +121,12 @@ GRAD = {("laguna", "float32"): "a87bb8242efc87c5",
         ("nemotron_h", "float32"): "0e3c957341cf5476",
         ("nemotron_h", "bfloat16"): "dee757781689d06a",
         ("lfm2_moe", "float32"): "fff73300fb999c36",
-        ("lfm2_moe", "bfloat16"): "eb51e43b95eea108"}
+        ("lfm2_moe", "bfloat16"): "eb51e43b95eea108",
+        ("qwen3_next", "float32"): "8a819f1d6305feee",
+        ("qwen3_next", "bfloat16"): "9e5377802f9dffb7"}
 INIT = {"laguna": "1a2ad2312401d5e2", "glm_moe_lite": "6af477e66acdf500",
-        "nemotron_h": "76d5a17d33ed9ea1", "lfm2_moe": "bc5b069b5b854333"}
+        "nemotron_h": "76d5a17d33ed9ea1", "lfm2_moe": "bc5b069b5b854333",
+        "qwen3_next": "26ca93c2dc20d91a"}
 
 # the trainer's own metrics of a telemetry step, then what each stack counts
 _STEP = ["edges_real", "grad_norm", "loss", "nodes_real", "num_graphs",
@@ -165,7 +172,16 @@ RECORD = {
                 "load_max_over_mean": 1.5797533988952637,
                 "slots_all": 480.0, "slots_held": 131.0},
         "sconv": {"kept_mb": 0.0, "rows": 160.0, "starts": 16.0,
-                  "taps_cut": 48.0}})}
+                  "taps_cut": 48.0}}),
+    # three DeltaNet layers x (6 chunks of 8, one of them padding, 4
+    # graphs) x the dispatch's 2 steps
+    "qwen3_next": (_STEP + _ATTN + _MOE + [
+        "gdn_chunks", "gdn_chunks_padding", "gdn_kept_mb", "gdn_resets"], {
+        "attention": _attention(2),
+        "gdn": {"chunks": 36.0, "chunks_padding": 6.0, "kept_mb": 0.0,
+                "resets": 24.0},
+        "moe": {"dense_steps": 0.0, "load_max_over_mean": 1.4006855487823486,
+                "slots_all": 960.0, "slots_held": 243.0}})}
 
 
 @pytest.mark.parametrize("dtype", DTYPES)

@@ -2,7 +2,7 @@
 ``config.SEQUENCE_MODELS``, and rows of telemetry/counters.py for what it
 counts.  A toy fifth stack is registered by that row alone and trained for
 a dispatch with models/base.py, models/create.py, train/trainer.py and
-telemetry/logger.py as they are; the four stacks that exist meet the same
+telemetry/logger.py as they are; the five stacks that exist meet the same
 contract, and none reads another."""
 
 import dataclasses
@@ -137,20 +137,21 @@ def test_a_fifth_stack_is_a_module_and_one_row(monkeypatch, tmp_path):
     want = 2 * 48 * 32 * 2 / 1e6
     for r in steps:
         assert r["ffn"] == {"kept_mb": pytest.approx(want)}
-        assert not {"moe", "attention", "ssm", "sconv"} & set(r)
+        assert not {"moe", "attention", "ssm", "sconv", "gdn"} & set(r)
 
 
 # ---------------------------------------------------------------------------
-# (ii) the four rows
+# (ii) the five rows
 # ---------------------------------------------------------------------------
 
 
 def test_the_table_is_the_list_of_sequence_models():
     assert dict(SEQUENCE_MODELS) == {
         "Laguna": "laguna", "GlmMoeLite": "glm_moe_lite",
-        "NemotronH": "nemotron_h", "Lfm2Moe": "lfm2_moe"}
-    assert ALL_MODEL_TYPES[-4:] == list(SEQUENCE_MODELS)
-    assert len(set(ALL_MODEL_TYPES)) == len(ALL_MODEL_TYPES) == 13
+        "NemotronH": "nemotron_h", "Lfm2Moe": "lfm2_moe",
+        "Qwen3Next": "qwen3_next"}
+    assert ALL_MODEL_TYPES[-5:] == list(SEQUENCE_MODELS)
+    assert len(set(ALL_MODEL_TYPES)) == len(ALL_MODEL_TYPES) == 14
 
 
 @pytest.mark.parametrize("model_type", list(SEQUENCE_MODELS))
@@ -193,7 +194,7 @@ def test_what_the_stacks_share_is_written_once():
                  if re.search(rf"^\s*{switch}\s*=", s, re.M)]
         assert homes == ["sequence.py"], (switch, homes)
     prefixes = sorted(block.prefix for block in counters.BLOCKS.values())
-    assert prefixes == ["attn_", "ffn_", "moe_", "sconv_", "ssm_"]
+    assert prefixes == ["attn_", "ffn_", "gdn_", "moe_", "sconv_", "ssm_"]
     for path in ("train/trainer.py", "telemetry/logger.py"):
         with open(os.path.join(REPO, "hydragnn_tpu", path)) as f:
             code = "\n".join(line.split("#")[0] for line in f)
