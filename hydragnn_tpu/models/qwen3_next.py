@@ -49,7 +49,6 @@ from typing import Any, ClassVar, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.graph.batch import GraphBatch
 from hydragnn_tpu.models.qwen3_next_reference import apply_rotary, layer_kinds
@@ -67,7 +66,7 @@ from hydragnn_tpu.models.sequence import (
     where_narrow,
 )
 from hydragnn_tpu.ops.attention import KEEP_ATTN, named_mb
-from hydragnn_tpu.ops.gdn import graph_gated_delta
+from hydragnn_tpu.ops.gdn import GDN_INV, graph_gated_delta, inverse_bytes
 from hydragnn_tpu.ops.moe import KEEP_ROUTE
 from hydragnn_tpu.ops.ssm import graph_causal_conv, scan_counts
 from hydragnn_tpu.parallel.share import LayerShare
@@ -78,17 +77,25 @@ L2_EPS = 1e-6           # under the root of q's and k's l2 norm
 # the published code's draws of A and dt (ASSUMED in the reference)
 A_MAX, DT_MIN, DT_MAX = 16.0, 1e-3, 1e-1
 
-# What the checkpoint round a DeltaNet half keeps: the wide input product's
-# result ``[q | k | v | z]``, [N, 2 key + 2 value] in the compute dtype (319
-# MB a layer at 12,968 nodes in bfloat16).  With it kept the recomputed
-# forward runs the norm, the taps, the rule and the gated norm and NOT the
-# 2048 -> 12288 product (6.5e11 FLOP a layer): on the chip the step is 533.0
-# ms with it and 540.6 without, and needs 14.74 GB with it and 15.45 without
-# (the recomputed product's operands live longer than the kept result;
-# PERF.md section 6, PR 44).  In bfloat16 only (models/sequence.py
-# where_narrow).
-GDN_PROJ = "gdn.in.proj"
-KEEP_GDN = jax.checkpoint_policies.save_only_these_names(GDN_PROJ)
+# What the checkpoint round a DeltaNet half keeps: the delta rule's inverse
+# ``(I + A)^-1`` (ops/gdn.py GDN_INV: float32 [chunks, H_v, C, C] as
+# computed, 106.4 MB a layer at 12,968 nodes, 203 chunks of 64 and 32 heads).
+# With it kept the recomputed forward runs everything up to the rule's ``A``
+# and NOT the inverse's ten HIGHEST [64, 64] products a chunk-head: its
+# backward rule reads the kept array.  In bfloat16 only (models/sequence.py
+# where_narrow), on the ``chunked`` backend only (the ``sequential`` one
+# computes no inverse and names nothing).
+#
+# The wide input product ``[q | k | v | z]`` is NOT kept beside it, though
+# alone it was worth 7.6 ms a step (PR 44 kept it: 319 MB a layer).  With
+# both kept in the first DeltaNet layer and the product in the second, the
+# schedule the TPU compiler finds for the scanned step needs 17.3 GB of the
+# device's 16.9 (its own rematerialisation runs and does not help; the same
+# with the inverse named lane-dense), where the inverse alone needs 14.5,
+# the product alone 14.7 and nothing 15.5 (whole-step AOT compiles for a
+# described v5e: PERF.md section 6, PR 46, has every mix of layers).  Of the
+# two the inverse is worth more.
+KEEP_GDN = jax.checkpoint_policies.save_only_these_names(GDN_INV)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -203,8 +210,7 @@ class GatedDeltaNet(nn.Module):
         w_out = self.param("w_out", fan_in(value), (value, d))
         with phase("gdn.in"):
             u = zrms(x, norm, lm.rms_norm_eps)
-            proj = checkpoint_name(dot(u, w_qkvz, self.dtype, self.dtype),
-                                   GDN_PROJ)
+            proj = dot(u, w_qkvz, self.dtype, self.dtype)
             ba = dot(u, w_ba, self.dtype)
         with phase("gdn.conv"):
             c = jax.nn.silu(graph_causal_conv(
@@ -287,18 +293,17 @@ class Qwen3NextLayer(nn.Module):
         its router's decision (ops/moe.py KEEP_ROUTE); the attention half
         the kernel's result and log-sum-exp and q, k, v, 2 key/value heads
         beside 16 query heads (ops/attention.py KEEP_ATTN); a DeltaNet half
-        its wide input product where the products are narrow (KEEP_GDN,
-        models/sequence.py where_narrow), else nothing."""
+        the chunked rule's float32 inverse where the products are narrow
+        (KEEP_GDN, models/sequence.py where_narrow), else nothing."""
         lm, blocks, kept = self.lm, None, {}
         if lm.layer_types[self.layer] == "linear_attention":
             keep = where_narrow(KEEP_GDN, self.dtype)
             a = nn.remat(GatedDeltaNet, policy=keep)(
                 lm, self.dtype, self.gdn_backend, name="mixer")(
                     x, node_gid, node_mask)
-            wide = (2 * lm.linear_num_key_heads * lm.linear_key_head_dim
-                    + 2 * lm.linear_num_value_heads * lm.linear_value_head_dim)
-            kept["gdn"] = named_mb(keep, {GDN_PROJ: jax.ShapeDtypeStruct(
-                (x.shape[0], wide), self.dtype)})
+            kept["gdn"] = named_mb(keep, {GDN_INV: inverse_bytes(
+                x.shape[0], lm.linear_num_value_heads, lm.linear_chunk_size,
+                self.gdn_backend)})
         else:
             a, blocks = nn.remat(Attention, policy=KEEP_ATTN)(
                 lm, self.dtype, self.attention_backend, self.interpret,

@@ -55,10 +55,15 @@ Precision: the products take operands in ``v``'s dtype (bfloat16 in the
 benchmark's cell; ``T`` and the entering state are rounded to it where a
 product reads them) and accumulate in float32; ``g``, ``beta``, the running
 sums, every decay, the inverse and the carried state are float32.  The
-backward pass is JAX's own of either form but for the inverse; the layer
-that calls this is recomputed from its input (models/qwen3_next.py), so the
-[chunks, heads, C, C] matrices and the per-chunk states live for one
-layer's backward pass at a time.
+backward pass is JAX's own of either form but for the inverse.  The layer
+that calls this is recomputed in the backward pass (models/qwen3_next.py),
+so the [chunks, heads, C, C] matrices and the per-chunk states live for one
+layer's backward pass at a time, but for the inverse, which carries a name
+(``GDN_INV``) that the layer's checkpoint keeps: [chunks, H_v, C, C]
+float32 as computed, 106 MB a layer at 203 chunks of 64 and 32 heads.  Kept,
+the recomputed forward stops at ``A`` and the rounds run once a step; their
+backward rule reads nothing else.  ``T`` is one elementwise pass from the
+inverse and is rebuilt.
 """
 
 from __future__ import annotations
@@ -66,11 +71,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from hydragnn_tpu.ops.ssm import _real, graph_starts
 from hydragnn_tpu.utils.scope import phase
 
 _HIGHEST = lax.Precision.HIGHEST
+
+# What a checkpoint that wraps a call of the ``chunked`` backend may keep by
+# name: the inverse ``(I + A)^-1``, float32 [chunks, H_v, C, C] as computed,
+# named inside ``unit_lower_inverse``'s ``custom_vjp`` on the very array its
+# backward rule reads.  With it kept the backward pass runs that rule's two
+# products and not the rounds again.  ``inverse_bytes`` is its size.
+GDN_INV = "gdn.scan.inverse"
 
 
 def default_backend() -> str:
@@ -121,7 +134,9 @@ def unit_lower_inverse(a):
 
 
 def _inverse_fwd(a):
-    x = unit_lower_inverse(a)
+    # the name on the array that is both result and residual: one put on a
+    # caller's copy would mark another variable, and keep nothing
+    x = checkpoint_name(unit_lower_inverse(a), GDN_INV)
     return x, x
 
 
@@ -132,6 +147,16 @@ def _inverse_bwd(x, dx):
 
 
 unit_lower_inverse.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+def inverse_bytes(n, heads, chunk, backend=None):
+    """Bytes of the array named ``GDN_INV`` in ONE ``graph_gated_delta``
+    call on ``n`` nodes and ``heads`` value heads: a number of the shapes.
+    0 on the ``sequential`` backend, which computes no inverse and names
+    nothing."""
+    if (backend or default_backend()) != "chunked":
+        return 0
+    return -(-n // chunk) * heads * chunk * chunk * 4
 
 
 def _chunked(q, k, v, g, beta, node_gid, real, chunk):

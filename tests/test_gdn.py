@@ -5,14 +5,21 @@ chunk's edge, and a graph longer than three chunks; the recurrence against
 a numpy loop over documents; padding nodes inert; packed graphs equal each
 graph alone; ``beta = 0`` leaves the state decayed only and ``g = 0, beta =
 1`` is the plain delta rule; the unit lower-triangular inverse and its
-written-down backward pass."""
+written-down backward pass; and what a checkpoint round a call keeps by
+name: the inverse, so that the gradient program holds the doubling rounds
+once."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from hydragnn_tpu.ops.gdn import graph_gated_delta, unit_lower_inverse
+from hydragnn_tpu.ops.gdn import (
+    GDN_INV,
+    graph_gated_delta,
+    inverse_bytes,
+    unit_lower_inverse,
+)
 
 HK, HV, DK, DV = 2, 4, 8, 8
 
@@ -178,6 +185,80 @@ def test_unit_lower_inverse_and_its_backward_pass():
         want = jax.grad(lambda a: jnp.sum(
             jnp.linalg.inv(eye + a) * w))(a)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def square_products(jaxpr, c):
+    """The ``dot_general``s of ``jaxpr`` and of every jaxpr inside it whose
+    two operands are both [..., c, c]: the inverse's rounds and its backward
+    rule's pair, and nothing else of the rule while no head size is c."""
+    own = sum(e.primitive.name == "dot_general"
+              and all(v.aval.shape[-2:] == (c, c) for v in e.invars)
+              for e in jaxpr.eqns)
+    return own + sum(square_products(sub, c) for e in jaxpr.eqns
+                     for sub in jax.core.jaxprs_in_params(e.params))
+
+
+def checkpointed(args, chunk, policy=None, checkpoint=True):
+    """(loss and the five gradients) of one chunked call, under
+    ``jax.checkpoint`` with ``policy`` unless ``checkpoint`` is off, as a
+    function of q, k, v, g, beta."""
+    gid, mask = args[5:]
+
+    def rule(q, k, v, g, beta):
+        return graph_gated_delta(q, k, v, g, beta, gid, mask, chunk=chunk,
+                                 backend="chunked")
+
+    if checkpoint:
+        rule = jax.checkpoint(rule, policy=policy)
+
+    def loss(*operands):
+        o = rule(*operands)
+        return jnp.sum(o * jnp.cos(jnp.arange(o.size).reshape(o.shape)))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
+
+
+names = jax.checkpoint_policies.save_only_these_names
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_a_checkpoint_that_keeps_the_inverse_runs_the_rounds_once(chunk):
+    """One run of the rounds is ``2 (log2 C - 1)`` products of [C, C] by
+    [C, C] and the backward rule two more.  A checkpoint recomputes the
+    rounds (two runs) unless its policy keeps ``GDN_INV``, which is named on
+    the array the backward rule reads: a name on another variable would
+    leave the count where a policy without it has it."""
+    assert chunk not in (DK, DV)
+    args = case((24, 40), 0, seed=6)
+    rounds = 2 * (chunk.bit_length() - 2)
+
+    def count(*policy, **how):
+        jaxpr = jax.make_jaxpr(checkpointed(args, chunk, *policy, **how))(
+            *args[:5])
+        return square_products(jaxpr.jaxpr, chunk)
+
+    assert count(checkpoint=False) == rounds + 2
+    assert count() == 2 * rounds + 2
+    assert count(names("some.other.name")) == 2 * rounds + 2
+    assert count(names("some.other.name", GDN_INV)) == rounds + 2
+
+
+def test_keeping_the_inverse_changes_no_value_and_no_gradient():
+    args = case((5, 20, 1, 2, 12, 9), 7, seed=8)
+    with jax.default_matmul_precision("highest"):
+        kept = checkpointed(args, 8, names(GDN_INV))(*args[:5])
+        bare = checkpointed(args, 8)(*args[:5])
+        free = checkpointed(args, 8, checkpoint=False)(*args[:5])
+    for other in (bare, free):
+        for a, b in zip(jax.tree.leaves(kept), jax.tree.leaves(other)):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+
+
+def test_the_inverses_bytes_are_of_the_chunked_shape_alone():
+    # 203 chunks of 64 x 32 heads x [64, 64] float32: the cell's 106.4 MB
+    assert inverse_bytes(12968, 32, 64, "chunked") == 203 * 32 * 64 * 64 * 4
+    assert inverse_bytes(56, 4, 8, "chunked") == 7 * 4 * 8 * 8 * 4
+    assert inverse_bytes(12968, 32, 64, "sequential") == 0
 
 
 def test_bfloat16_operands_stay_near_float32():

@@ -232,28 +232,44 @@ def test_bfloat16_products_stay_near_the_reference(docs, batch):
     assert 1e-4 < dev < 0.08      # rounded, and no more than rounded
 
 
-def test_a_deltanet_half_keeps_its_wide_product_where_narrow(
-        batch, monkeypatch):
-    """The half's checkpoint keeps the [q | k | v | z] product in bfloat16
-    and nothing in float32; the gradients are the bare checkpoint's, and
-    the step record says what is held."""
+@pytest.mark.parametrize("backend", ["sequential", "chunked"])
+def test_a_deltanet_half_keeps_the_rules_inverse_where_narrow(
+        batch, monkeypatch, backend):
+    """On the chunked backend the half's checkpoint keeps the rule's float32
+    inverse in bfloat16 and nothing in float32; on the sequential backend,
+    which computes no inverse, nothing.  The gradients are the bare
+    checkpoint's, the step record says what is held, and the chunked
+    gradient program holds the inverse's rounds once where the bare
+    checkpoint's holds them twice."""
     import hydragnn_tpu.models.qwen3_next as Q
+    from test_gdn import square_products
+
+    # a chunk of 16 where the heads are 8 wide: the [C, C] products are the
+    # inverse's alone
+    lm = dict(ONE_LAYER, linear_chunk_size=16)
+    chunked = backend == "chunked"
 
     def run(dtype):
-        cfg = ModelConfig.from_config(nn_section(dtype, lm=ONE_LAYER))
-        model = create_model(cfg)
+        cfg = ModelConfig.from_config(nn_section(dtype, lm=lm))
+        model = create_model(cfg).clone(gdn_backend=backend)
         params, stats = seeded(model, batch)
         loss, grads, new_stats = loss_and_grads(model, cfg, params, stats,
                                                 batch)
-        return loss, grads, float(new_stats["gdn_kept_mb"])
+        products = square_products(jax.make_jaxpr(jax.grad(
+            lambda p: _loss_and_metrics(model, cfg, p, stats, batch, True)[0]
+        ))(params).jaxpr, 16)
+        return loss, grads, float(new_stats["gdn_kept_mb"]), products
 
-    loss, grads, kept = run("bfloat16")
-    # one layer's [56, 96] in bfloat16
-    assert kept == pytest.approx(56 * 96 * 2 / 1e6)
-    assert run("float32")[2] == 0.0
+    loss, grads, kept, products = run("bfloat16")
+    # one layer's 4 chunks x 4 heads of float32 [16, 16]
+    assert kept == pytest.approx(4 * 4 * 16 * 16 * 4 / 1e6 if chunked else 0)
+    # three rounds of two products and the backward rule's two: once
+    assert products == (8 if chunked else 0)
+    assert run("float32")[2:] == (0.0, 14 if chunked else 0)
     monkeypatch.setattr(Q, "where_narrow", lambda policy, dtype: None)
-    bare_loss, bare_grads, bare_kept = run("bfloat16")
+    bare_loss, bare_grads, bare_kept, bare_products = run("bfloat16")
     assert bare_kept == 0.0
+    assert bare_products == (14 if chunked else 0)
     assert loss == pytest.approx(bare_loss, rel=1e-6)
     (dev, where), _ = worst_leaf(grads, bare_grads)
     assert dev < 1e-5, where

@@ -11,6 +11,18 @@ The digests were printed by this file (``python tests/test_sequence_parity.py``)
 on 532a60a (``qwen3_next``'s on PR 44's tree), on this container's jax 0.9.0.  A jax upgrade changes how jaxprs
 print and may change what the initialisers draw: regenerate from a commit
 whose programs are known to be good, never from the tree under test.
+
+PR 46 moved ``qwen3_next``'s two gradient digests and no other: the
+DeltaNet half's input product lost its name (models/qwen3_next.py: no
+policy asks for it any more) and the delta rule's inverse gained one
+(ops/gdn.py ``GDN_INV``), which the digests above do not see, because on
+the CPU the rule's default backend is the sequential twin and computes no
+inverse.  ``CHUNKED`` pins the fifth stack's program with the chunked rule,
+as the chip runs it.  All three pairs were taken on PR 46's tree, after
+this check, made once by hand: with each tree's own name stubbed out (the
+product's on PR 44's tree, the inverse's on PR 46's) the two trees' float32
+programs print alike, sequential ``895962a7dc36a225`` (so that digest IS
+PR 44's program less a name) and chunked ``b6bf4ec28a35b813``.
 """
 
 import functools
@@ -48,7 +60,7 @@ BLOCKS = ("moe", "attention", "ssm", "sconv", "ffn", "gdn")
 
 
 @functools.lru_cache(maxsize=None)
-def _setup(stack, dtype="float32"):
+def _setup(stack, dtype="float32", **backends):
     T = STACKS[stack]
     cfg = ModelConfig.from_config(T.nn_section(dtype))
     rng = np.random.default_rng(0)
@@ -56,7 +68,7 @@ def _setup(stack, dtype="float32"):
     heads = [HeadSpec(f"next{i}", "node", 1)
              for i in range(len(cfg.output_dim))]
     batch = jax.tree.map(jnp.asarray, collate(docs, PadSpec(48, 8, 5), heads))
-    model = create_model(cfg)
+    model = create_model(cfg).clone(**backends)
     opt = select_optimizer({"type": "AdamW", "learning_rate": 1e-3})
     return cfg, model, opt, batch, create_train_state(model, batch, opt)
 
@@ -65,12 +77,13 @@ def _sha(text):
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-def grad_digest(stack, dtype):
+def grad_digest(stack, dtype, **backends):
     """sha256[:16] of the gradient jaxpr of one train step's loss, printed
     with every equation's scope (``name_stack``: the names the per-layer
     metrics find their operations by); a checkpoint policy prints as a
-    function at an address, which is taken out."""
-    cfg, model, _opt, batch, state = _setup(stack, dtype)
+    function at an address, which is taken out.  ``backends``: fields of
+    the stack set by name where the CPU's default is not the chip's."""
+    cfg, model, _opt, batch, state = _setup(stack, dtype, **backends)
 
     def loss(params):
         return _loss_and_metrics(model, cfg, params, state.batch_stats,
@@ -122,8 +135,12 @@ GRAD = {("laguna", "float32"): "a87bb8242efc87c5",
         ("nemotron_h", "bfloat16"): "dee757781689d06a",
         ("lfm2_moe", "float32"): "fff73300fb999c36",
         ("lfm2_moe", "bfloat16"): "eb51e43b95eea108",
-        ("qwen3_next", "float32"): "8a819f1d6305feee",
-        ("qwen3_next", "bfloat16"): "9e5377802f9dffb7"}
+        ("qwen3_next", "float32"): "895962a7dc36a225",
+        ("qwen3_next", "bfloat16"): "b35b5cd66bec1f68"}
+# The fifth stack's program as the chip runs its rule: the ``chunked``
+# backend asked for by name (the CPU's default, the sequential twin, computes
+# no inverse, so the digests above do not see what ops/gdn.py names).
+CHUNKED = {"float32": "87180c25d2c7cd0d", "bfloat16": "46bcba3261355253"}
 INIT = {"laguna": "1a2ad2312401d5e2", "glm_moe_lite": "6af477e66acdf500",
         "nemotron_h": "76d5a17d33ed9ea1", "lfm2_moe": "bc5b069b5b854333",
         "qwen3_next": "26ca93c2dc20d91a"}
@@ -190,6 +207,12 @@ def test_the_gradient_program_is_the_parents(stack, dtype):
     assert grad_digest(stack, dtype) == GRAD[stack, dtype]
 
 
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_the_chunked_rules_gradient_program_is_pr_46s(dtype):
+    assert grad_digest("qwen3_next", dtype,
+                       gdn_backend="chunked") == CHUNKED[dtype]
+
+
 @pytest.mark.parametrize("stack", list(STACKS))
 def test_the_seeded_initial_values_are_the_parents(stack):
     assert init_digest(stack) == INIT[stack]
@@ -211,6 +234,9 @@ if __name__ == "__main__":
 
     pprint.pprint({"GRAD": {(s, d): grad_digest(s, d)
                             for s in STACKS for d in DTYPES},
+                   "CHUNKED": {d: grad_digest("qwen3_next", d,
+                                              gdn_backend="chunked")
+                               for d in DTYPES},
                    "INIT": {s: init_digest(s) for s in STACKS},
                    "RECORD": {s: record_blocks(s, tempfile.mkdtemp())
                               for s in STACKS}}, width=78)
